@@ -37,7 +37,7 @@ from pytorch_ps_mpi_tpu.parallel.async_train import (
     serve,
     spawn_worker,
 )
-from pytorch_ps_mpi_tpu.utils.backend_guard import enable_compilation_cache
+from pytorch_ps_mpi_tpu.utils.compile_cache import enable_compilation_cache
 
 enable_compilation_cache()
 

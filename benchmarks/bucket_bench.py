@@ -14,9 +14,12 @@ Two measurements per (model, bucket_mb) point:
   per-worker gradients on a CPU host; pass ``--run-bert`` to time it on
   real hardware).
 
+Runs on the devices JAX initialises and fails below two (aggregation
+needs a mesh); for the host-CPU form: ``JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+
 Emits one JSON line per point (benchmarks/results/ schema: metric /
-value / unit / backend + sweep fields), table to stderr-free stdout so
-the TPU watcher (``tools/tpu_watch.py``) can append records verbatim.
+value / unit / backend + sweep fields).
 """
 
 from __future__ import annotations
@@ -29,29 +32,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-)
-
-import subprocess
-
-_ndev = 0
-try:
-    _out = subprocess.run(
-        [sys.executable, "-c",
-         "import jax; print(len(jax.devices()))"],
-        timeout=75, capture_output=True, text=True,
-        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"},
-    )
-    _ndev = int(_out.stdout.strip() or 0) if _out.returncode == 0 else 0
-except (subprocess.TimeoutExpired, ValueError):
-    _ndev = 0
-
 import jax
-
-if _ndev < 2:
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -129,6 +110,10 @@ def main():
     args = ap.parse_args()
     world = len(jax.devices())
     backend = jax.default_backend()
+    if world < 2:
+        raise SystemExit(
+            f"bucket_bench: {world} {backend} device: aggregation needs a "
+            "multi-device mesh (>= 2)")
     modes = args.modes.split(",")
 
     for model, make, execute in (
@@ -164,6 +149,7 @@ def main():
                     ) if base_total else 1.0,
                     "workers": world,
                     "backend": backend,
+                    "device_kind": jax.devices()[0].device_kind,
                 }
                 if execute:
                     row["step_ms"] = round(timed_step_ms(opt, grads), 3)

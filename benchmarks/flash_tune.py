@@ -1,7 +1,7 @@
 """Flash-attention block-size sweep vs the XLA dense path.
 
-Measures attention-only fwd+bwd device time (RTT-corrected scan, see
-``utils/devtime.py``) for BERT-base head geometry (h=12, d=64) across
+Measures attention-only fwd+bwd device time (``utils/devtime.timed``)
+for BERT-base head geometry (h=12, d=64) across
 sequence lengths and (block_q, block_k) choices, against the fused-dense
 einsum oracle XLA compiles for the same shapes. This is the measurement
 behind the ``full``-attention dispatch policy in ``models/bert.py``: the
@@ -10,7 +10,7 @@ and the O(L^2) scores still fit HBM traffic comfortably); the flash
 kernel must EARN the dispatch at the crossover where score
 materialization starts to dominate.
 
-Run on a live TPU: ``python benchmarks/flash_tune.py [--quick]``.
+Run on a TPU: ``python benchmarks/flash_tune.py [--quick]``.
 One JSON line per (seq, config), then a summary line per seq.
 """
 
@@ -27,17 +27,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from pytorch_ps_mpi_tpu.utils.backend_guard import (
-    enable_compilation_cache,
-    ensure_live_backend,
-)
-
-enable_compilation_cache()
-
 from pytorch_ps_mpi_tpu.ops.attention_pallas import (
     _attention_jnp,
     flash_attention,
 )
+from pytorch_ps_mpi_tpu.utils.compile_cache import enable_compilation_cache
 from pytorch_ps_mpi_tpu.utils.devtime import timed
 
 
@@ -46,32 +40,14 @@ def emit(**rec):
     print(json.dumps(rec), flush=True)
 
 
-def bench_one(fn, q, k, v, scan_k: int = 8, reps: int = 5) -> float:
+def bench_one(fn, q, k, v, reps: int = 5) -> float:
     """Device seconds per fwd+bwd of ``fn(q, k, v) -> [b, l, h, d]``."""
 
     def loss(q, k, v):
         return jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
 
     grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-
-    @jax.jit
-    def scanned(q, k, v):
-        def body(c, _):
-            qq, kk, vv = c
-            l, (dq, dk, dv) = grad(qq, kk, vv)
-            # carry-dependence so XLA cannot hoist any round
-            s = jnp.asarray(1e-30, qq.dtype) * l.astype(qq.dtype)
-            return (qq + s * dq, kk + s * dk, vv + s * dv), None
-
-        c, _ = jax.lax.scan(body, (q, k, v), None, length=scan_k)
-        return c
-
-    _, dev_s = timed(
-        lambda: grad(q, k, v),
-        lambda: scanned(q, k, v),
-        scan_k, reps=reps,
-    )
-    return dev_s
+    return timed(lambda: grad(q, k, v), reps=reps)
 
 
 def main():
@@ -81,7 +57,7 @@ def main():
     ap.add_argument("--heads", type=int, default=12)
     ap.add_argument("--head-dim", type=int, default=64)
     args = ap.parse_args()
-    ensure_live_backend()
+    enable_compilation_cache()
 
     h, d = args.heads, args.head_dim
     # token budget ~constant: b*l = 16k; s1024 sits ON the default-tier
@@ -101,8 +77,9 @@ def main():
         q, k, v = mk(0), mk(1), mk(2)
 
         # the dense path can legitimately die at the long end (f32 scores
-        # b*h*l*l ~ 6.4 GB at s8192 + backward): that failure IS a data
-        # point and must not cost the flash half of the sweep
+        # b*h*l*l ~ 6.4 GB at s8192 + backward), and a tile that does
+        # not fit VMEM fails to compile: in a sweep over sizes each such
+        # failure IS a data point, reported in its own row
         try:
             dense_s = bench_one(
                 lambda q, k, v: _attention_jnp(
@@ -137,16 +114,8 @@ def main():
                 best = ((bq, bk), dev_s)
 
         if best:
-            # dense_s == 0.0 is a devtime zero-clamp (RTT jitter
-            # swallowed the k-step signal): distinct from "errored"
-            # (None), but comparing a finite flash time against 0.0 is
-            # meaningless — report it indeterminate, never as a verdict
-            if dense_s is None:
-                verdict = "dense errored"
-            elif dense_s == 0.0:
-                verdict = "dense zero-clamped"
-            else:
-                verdict = bool(best[1] < dense_s)
+            verdict = ("dense errored" if dense_s is None
+                       else bool(best[1] < dense_s))
             emit(metric="attn_crossover_summary", seq=l, batch=b,
                  dense_ms=(round(dense_s * 1e3, 3)
                            if dense_s is not None else None),

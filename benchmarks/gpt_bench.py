@@ -9,11 +9,12 @@ that schedule inside a whole training step rather than a kernel
 microbench. The einsum twin rides alongside at each shape as the A/B.
 
 GPT-2-small geometry (12 layers, 12 heads, 768 hidden, 50257 vocab,
-tied embeddings — ~124M params), Adam, bf16 compute. RTT-corrected
-scan timing (``utils/devtime.py``).
+tied embeddings — ~124M params), Adam, bf16 compute, timed by the
+shared step recipe (``benchmarks/_stepbench.py``).
 
-Run on a live TPU: ``python benchmarks/gpt_bench.py``; off-TPU it runs
-one tiny honest CPU line so the script always proves itself runnable.
+Run on a TPU: ``python benchmarks/gpt_bench.py``; on another backend it
+runs one tiny line, labeled with that backend, so the script proves
+itself runnable.
 """
 
 from __future__ import annotations
@@ -28,17 +29,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pytorch_ps_mpi_tpu.utils.backend_guard import (
-    enable_compilation_cache,
-    ensure_live_backend,
-)
-
-enable_compilation_cache()
-
 from benchmarks._stepbench import step_timing_fields
 from pytorch_ps_mpi_tpu.models.bert import BertConfig
 from pytorch_ps_mpi_tpu.models.gpt import GPTLM, causal_lm_loss
 from pytorch_ps_mpi_tpu.optim import AdamHyper, adam_update, init_adam_state
+from pytorch_ps_mpi_tpu.utils.compile_cache import enable_compilation_cache
 
 
 def emit(**rec):
@@ -54,9 +49,7 @@ def _suffix(attention: str, remat: bool = False) -> str:
 def metric_name(batch: int, seq: int, attention: str, cfg_kw: dict,
                 remat: bool = False) -> str:
     """Metric name derived from the config alone (abstract eval, no
-    device work), so error and success rows for one config share the
-    same name and provenance's newest-per-metric recall sees one series.
-    """
+    device work)."""
     cfg = BertConfig(causal=True, attention=attention, remat=remat,
                      max_position=max(1024, seq), **cfg_kw)
     model = GPTLM(cfg)
@@ -69,8 +62,7 @@ def metric_name(batch: int, seq: int, attention: str, cfg_kw: dict,
 
 
 def bench_line(batch: int, seq: int, attention: str, cfg_kw: dict,
-               metric: str, remat: bool = False,
-               scan_k: int = 8, reps: int = 5) -> None:
+               metric: str, remat: bool = False, reps: int = 5) -> None:
     cfg = BertConfig(causal=True, attention=attention, remat=remat,
                      max_position=max(1024, seq), **cfg_kw)
     model = GPTLM(cfg)
@@ -90,19 +82,18 @@ def bench_line(batch: int, seq: int, attention: str, cfg_kw: dict,
     params = jax.jit(model.init)(jax.random.key(0), tokens[:1])
     state = init_adam_state(params)
     fields = step_timing_fields(train_step, params, state, tokens,
-                                scan_k=scan_k, reps=reps)
+                                reps=reps)
     emit(metric=metric, attention=attention, remat=remat, **fields)
 
 
 def main() -> None:
-    ensure_live_backend()
+    enable_compilation_cache()
     if jax.default_backend() != "tpu":
-        # honest CPU smoke: tiny geometry, one line, runnable anywhere
+        # tiny geometry, one line, runnable anywhere
         tiny = dict(dtype=jnp.float32, num_layers=2, num_heads=2,
                     hidden_size=64, intermediate_size=128, vocab_size=512)
         bench_line(2, 64, "full", tiny,
-                   metric=metric_name(2, 64, "full", tiny),
-                   scan_k=4, reps=2)
+                   metric=metric_name(2, 64, "full", tiny), reps=2)
         return
     gpt2s = dict(dtype=jnp.bfloat16, num_layers=12, num_heads=12,
                  hidden_size=768, intermediate_size=3072, vocab_size=50257)
@@ -120,27 +111,18 @@ def main() -> None:
         (4, 2048, "full", True),    # remat tax on the flash path, same shape
     ]
     for batch, seq, attn, remat in sweep:
-        # name computed BEFORE the try: it re-runs the constructor/trace
-        # steps, so calling it inside the handler would just re-raise
-        # and kill the rest of the sweep with no error row
         name = metric_name(batch, seq, attn, gpt2s, remat)
         names[(batch, seq, attn, remat)] = name
-        try:
-            bench_line(batch, seq, attn, gpt2s, metric=name, remat=remat)
-        except Exception as e:
-            # same config-derived name as the success path, so one
-            # config is one metric series whether the run lives or dies
-            emit(metric=name, attention=attn, remat=remat,
-                 error=f"{type(e).__name__}: {str(e)[:300]}")
+        bench_line(batch, seq, attn, gpt2s, metric=name, remat=remat)
 
     # scan_layers A/B at the headline shape: same math (loop-vs-scan
     # equality tested in tests/test_models.py), different compile
     # economics — compile_s is the column this pair exists for, and
-    # step_ms_device answers whether lax.scan costs any runtime by
-    # inhibiting inter-layer fusion. The persistent compilation cache
-    # would turn compile_s into a cache-load time on warm reruns, so
-    # the PAIR runs with the cache disabled — the loop twin recompiles
-    # cold too (one extra compile is the price of an honest column).
+    # step_ms answers whether lax.scan costs any runtime by inhibiting
+    # inter-layer fusion. The persistent compilation cache would turn
+    # compile_s into a cache-load time on warm reruns, so the PAIR runs
+    # with the cache disabled — the loop twin recompiles cold too (one
+    # extra compile is the price of an honest column).
     base = names[(8, 1024, "full", False)]
     jax.config.update("jax_enable_compilation_cache", False)
     try:
@@ -148,11 +130,7 @@ def main() -> None:
             (gpt2s, "_coldcompile"),
             (dict(gpt2s, scan_layers=True), "_scanlayers"),
         ]:
-            try:
-                bench_line(8, 1024, "full", kw, metric=base + suffix)
-            except Exception as e:
-                emit(metric=base + suffix, attention="full", remat=False,
-                     error=f"{type(e).__name__}: {str(e)[:300]}")
+            bench_line(8, 1024, "full", kw, metric=base + suffix)
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
 
@@ -162,13 +140,8 @@ def main() -> None:
     # einsum twin above under the same metric-series convention
     # (compilation cache back ON — this pair compares step time, not
     # compile time)
-    name_bf = names[(8, 1024, "einsum", False)] + "_bf16logits"
-    try:
-        bench_line(8, 1024, "einsum", dict(gpt2s, f32_logits=False),
-                   metric=name_bf)
-    except Exception as e:
-        emit(metric=name_bf, attention="einsum", remat=False,
-             error=f"{type(e).__name__}: {str(e)[:300]}")
+    bench_line(8, 1024, "einsum", dict(gpt2s, f32_logits=False),
+               metric=names[(8, 1024, "einsum", False)] + "_bf16logits")
 
 
 if __name__ == "__main__":

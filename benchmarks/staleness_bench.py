@@ -1,4 +1,4 @@
-"""Staleness → convergence tradeoff for AsySG-InCon (VERDICT r4 next #4).
+"""Staleness → convergence tradeoff for AsySG-InCon.
 
 The algorithm's literature claim (Lian et al. 2015, cited by the
 reference ``README.md:56-59``) is a CONVERGENCE statement: bounded

@@ -30,7 +30,7 @@ import jax
 jax.config.update("jax_platforms", "cpu")  # protocol bench: host only
 
 from async_bench import run as run_job  # the one server-lifecycle harness
-from pytorch_ps_mpi_tpu.utils.backend_guard import enable_compilation_cache
+from pytorch_ps_mpi_tpu.utils.compile_cache import enable_compilation_cache
 from pytorch_ps_mpi_tpu.utils.devtime import safe_ratio
 
 enable_compilation_cache()

@@ -12,7 +12,7 @@ touching the training fleet.
 With ``--follow`` the tier keeps polling the checkpoint directory and
 republishes whenever the trainer lands a newer step, so readers track a
 LIVE training run through cheap delta reads; the poll backs off
-exponentially while no newer checkpoint appears (tpu_watch-style), so
+exponentially while no newer checkpoint appears, so
 an idle follower stops burning a core.
 
 With ``--follow-endpoint HOST:PORT`` the process is a REPLICA instead:
@@ -227,7 +227,7 @@ def main(argv=None):
             pass
 
     last_step = step
-    # idle-backoff pacing (tpu_watch-style): a fresh checkpoint snaps the
+    # idle-backoff pacing: a fresh checkpoint snaps the
     # poll back to the base cadence; every empty poll doubles it
     base_sleep = min(args.follow, 1.0) if args.follow else 0.25
     max_sleep = max(8.0, 4.0 * base_sleep) if args.follow else base_sleep

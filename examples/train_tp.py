@@ -63,7 +63,7 @@ def main(argv=None):
     args = parse_args(argv)
     n_need = args.dp * args.sp * args.tp
 
-    # fail fast on pure-CLI mistakes BEFORE the backend probe
+    # fail fast on pure-CLI mistakes before the backend initializes
     if args.batch % args.dp:
         print(f"--batch {args.batch} must divide by --dp {args.dp}",
               file=sys.stderr)
@@ -85,21 +85,14 @@ def main(argv=None):
               file=sys.stderr)
         sys.exit(2)
 
-    from pytorch_ps_mpi_tpu.utils.backend_guard import (
+    from pytorch_ps_mpi_tpu.utils.compile_cache import (
         enable_compilation_cache,
-        ensure_live_backend,
-        size_virtual_cpu_mesh,
     )
 
-    live = ensure_live_backend()
     enable_compilation_cache()
 
     import jax
 
-    if not live:
-        # the guard already pinned the platform to the host CPU; size
-        # the virtual mesh before anything initializes the backend
-        size_virtual_cpu_mesh(n_need)
     if len(jax.devices()) < n_need:
         print(
             f"backend {jax.default_backend()!r} has {len(jax.devices())} "
@@ -184,6 +177,7 @@ def main(argv=None):
     w1 = opt.params["mlp"]["w1"]
     assert "model" in str(w1.sharding.spec), w1.sharding
     print(json.dumps({"done": True,
+                      "backend": jax.default_backend(),
                       "tp_leaves_sharded_over": str(w1.sharding.spec)}),
           flush=True)
 

@@ -21,12 +21,6 @@ Everything on-device runs under ``jax.jit``/``shard_map`` over a
 ``ppermute``) instead of MPI over Ethernet.
 """
 
-from pytorch_ps_mpi_tpu.utils.compat import ensure_axis_size, ensure_shard_map
-
-# before any module that references jax.shard_map / lax.axis_size
-ensure_shard_map()
-ensure_axis_size()
-
 from pytorch_ps_mpi_tpu.ps import MPI_PS, Adafactor, Adam, SGD
 
 __all__ = ["MPI_PS", "Adafactor", "Adam", "SGD"]

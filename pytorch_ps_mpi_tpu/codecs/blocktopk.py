@@ -1,9 +1,7 @@
 """Blockwise top-k sparsification: selection tiled for the TPU.
 
 Global top-k over a 132M-element flat gradient is the sparse-codec cost
-problem (VERDICT r3 item 2: ``lax.approx_max_k`` measured 107 ms at 132M
-on v5e — 7x the whole BERT train step it was meant to accelerate). The
-global selection is the expensive part, not the gather: it sorts/scans
+problem. The global selection is the expensive part, not the gather: it sorts/scans
 the full vector with cross-chip-of-the-array data movement.
 
 Blockwise selection removes it. The flat gradient is viewed as

@@ -18,7 +18,7 @@ TWO protocols live here, matching the paper's own split:
   ``~2·(W-1)/W·r·(n+m)`` — **independent of world size** — where the
   gather form ships ``(W-1)·r·(n+m)``. Per-worker error feedback keeps
   exactly what the protocol transmitted on this worker's behalf:
-  ``e_w ← M_w − P̂ P̂ᵀ M_w`` (VERDICT r4 weak #3).
+  ``e_w ← M_w − P̂ P̂ᵀ M_w``.
 - **Per-worker factors (``encode``/``decode_sum``)** — each worker ships
   its own ``(P_w, Q_w)`` and the receiver sums W separate rank-r
   approximations. This is NOT the paper's all-reduced algorithm, but it

@@ -65,12 +65,13 @@ class TernGradCodec(Codec):
         ``scan_threshold`` elements (default ``4 * scan_block``) encode
         through a ``lax.scan`` over ``scan_block``-element chunks so XLA
         never materializes a full-size f32 intermediate — the fix for
-        the 505 MB HLO temp the whole-tensor form allocated on a
-        BERT-base gradient (BENCH_TPU_WATCH: the uniform draw + keep
-        probability both went [132M] f32). Per-chunk PRNG keys derive
-        from the round key by fold-in, so the stream differs from the
-        whole-tensor form — irrelevant for an unbiased stochastic codec
-        — while wire format and size are unchanged.
+        the HLO temps the whole-tensor form allocates on a BERT-base
+        gradient (the uniform draw + keep probability both go [132M]
+        f32; 16 B/element, tests/test_agg.py pins the chunked bound).
+        Per-chunk PRNG keys derive from the round key by fold-in, so the
+        stream differs from the whole-tensor form — irrelevant for an
+        unbiased stochastic codec — while wire format and size are
+        unchanged.
 
         ``use_pallas=True`` routes sizes divisible by 512 through the
         fused ternarize+pack kernel (``ops/tern_pallas.tern_pack``):
@@ -130,8 +131,7 @@ class TernGradCodec(Codec):
             # at a time, so peak temp is a chunk's intermediates (XLA
             # reuses the loop-body buffers), never an n-sized f32 tensor
             # (the whole-tensor form materializes abs|g| + the uniform
-            # draw: 505 MB of HLO temps on a BERT-base gradient,
-            # BENCH_TPU_WATCH). A ragged tail (< scan_block elements)
+            # draw). A ragged tail (< scan_block elements)
             # encodes outside the scan with chunk-sized temps; its digit
             # offset stays 4-aligned because scan_block is.
             blk = self.scan_block

@@ -92,8 +92,8 @@ class ThresholdCodec(Codec):
           chunk: sort-path tensors with at least ``4 * chunk`` elements
             compact CHUNKED: one vectorized per-chunk sort over
             ``[n_chunks, chunk]`` (a bitonic network of depth log²(chunk)
-            instead of log²(n) — the fix for the superlinear 619 ms
-            BERT-flat-grad encode, BENCH_TPU_WATCH) followed by a
+            instead of log²(n) — the fix for the superlinear
+            BERT-flat-grad encode) followed by a
             sequential cursor merge of the per-chunk survivor prefixes
             (``dynamic_update_slice`` per chunk; each write is a full
             static-size chunk and the next chunk's write overlap-

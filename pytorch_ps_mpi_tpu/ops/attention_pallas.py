@@ -2,9 +2,8 @@
 
 The dense-attention path this replaces (``models/bert.py``: plain einsum
 softmax) materializes the [l, l] score matrix in HBM per head — the
-classic O(L²) memory wall, and the reason BERT MFU collapses past s128
-(VERDICT r3 weak #4). This kernel is the standard online-softmax tiling
-(Dao et al.; Milakov & Gimelshein max-shift streaming): q tiles stay
+classic O(L²) memory wall. This kernel is the standard online-softmax
+tiling (Dao et al.; Milakov & Gimelshein max-shift streaming): q tiles stay
 resident in VMEM while k/v tiles stream past; the score block, running
 row-max, exp-sum and output accumulator never leave VMEM; HBM traffic
 drops from O(L²) to O(L·d).
@@ -35,6 +34,8 @@ Design choices:
 Off-TPU the kernel runs in Pallas interpret mode (CPU test meshes);
 ``flash_attention`` falls back to a jnp oracle for shapes the tiling
 cannot serve (sequence not a multiple of the minimal sublane tile).
+On TPU a Mosaic failure at a shape the tiling admits raises: nothing
+here catches a kernel compile error.
 """
 
 from __future__ import annotations
@@ -55,12 +56,12 @@ _MASKED = -1e30        # additive mask value
 _MASK_THRESH = -1e29   # "this score was masked" test (real scores are tiny)
 
 # Minimum sequence length at which 'full' attention auto-dispatches to
-# the kernel. Measured on TPU v5e (tpu_v5e_2026-07-31 sweep +
-# benchmarks/flash_tune.py): XLA's fused dense attention wins short
-# sequences — its matmuls batch across heads on the MXU while the kernel
-# pays a sequential batch*heads grid — and the kernel takes over where
-# O(L^2) score materialization dominates. Overridable for re-measurement
-# on other chip generations (FLASH_MIN_SEQ env var).
+# the kernel. XLA's fused dense attention suits short sequences — its
+# matmuls batch across heads on the MXU while the kernel pays a
+# sequential batch*heads grid — and the kernel takes over where O(L^2)
+# score materialization dominates (benchmarks/flash_tune.py measures the
+# crossover). Overridable for re-measurement on other chip generations
+# (FLASH_MIN_SEQ env var).
 import os as _os
 
 FLASH_MIN_SEQ = int(_os.environ.get("FLASH_MIN_SEQ", "512"))
@@ -133,8 +134,7 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0] = (acc[:] / l_safe).astype(o_ref.dtype)
         # lane-replicated write: lse rides as [bh, lq, LANE] so its block
         # (1, bq, LANE) satisfies Mosaic's (8, 128) tile rule for ANY bh —
-        # a (1, bq) block over [bh, lq] only lowers when bh == 1, which is
-        # exactly the shape the old probe tested (see _lowering_probe)
+        # a (1, bq) block over [bh, lq] only lowers when bh == 1
         lse_ref[0] = jnp.broadcast_to(
             m_sc[:, :1] + jnp.log(l_safe), (lse_ref.shape[1], _LANE)
         )
@@ -388,15 +388,12 @@ def _attention_jnp(q, k, v, q_offset, k_offset, causal, scale):
 
 
 def _default_block_targets(lq: int, lk: int) -> tuple:
-    """Measured block-size policy (flash_tune sweep, v5e 2026-08-01,
-    `BENCH_TPU_WATCH.jsonl`): at s512 the 128x128 tile wins (3.28 ms vs
-    3.55 for 512x512); at s2048 512x1024 wins 4.9x over 128x128 (6.64 vs
-    32.5 ms) and at s8192 7.2x (16.4 vs 117.6 ms) — larger k/v tiles
-    amortize per-grid-step dispatch and keep the MXU fed once the score
-    block is MXU-shaped on both dims, while below ~1k sequence the grid
-    is too small for tile residency to matter and 128's divisibility
-    into short tails wins. Crossover bracketed between 512 and 2048;
-    big tiles engage from 1024 up."""
+    """Block-size policy: 128x128 tiles below sequence 1024, 512x1024
+    from there up — larger k/v tiles amortize per-grid-step dispatch and
+    keep the MXU fed once the score block is MXU-shaped on both dims,
+    while below ~1k sequence the grid is too small for tile residency to
+    matter and 128's divisibility into short tails wins
+    (benchmarks/flash_tune.py sweeps the tiles)."""
     if max(lq, lk) >= 1024:
         return 512, 1024
     return 128, 128
@@ -455,79 +452,15 @@ def flash_supported(lq: int, lk: int, block_q: int = 128,
             and _pick_block(lk, block_k, mb) is not None)
 
 
-def mosaic_lowering_ok(head_dim: int = 64, dtype=jnp.bfloat16,
-                       seq: int = 128, lk: Optional[int] = None) -> bool:
-    """Cached compile probe: does this backend's Mosaic lower the kernel
-    family for THIS head_dim/dtype (the parameters tiling actually
-    depends on)? Probes the CAUSAL forward AND the backward pass (grad
-    compiles all three kernels — dq and dk/dv lower independently and
-    can regress independently). Gates the AUTO dispatches ('full'
-    attention, ring/ulysses defaults) so a lowering regression degrades
-    to the dense path instead of breaking every TPU bench/model; the
-    explicit 'flash' mode stays ungated and fails loudly. Lowering
-    failures are shape-CLASS properties (dtype tiling, lane-dim head
-    size, per-block VMEM footprint) — and since the default block size
-    is a function of sequence length (`_default_block_targets` targets
-    degraded by `_pick_block` divisibility), the probe must compile the
-    SAME (bq, bk) family the dispatch would run: a small-tile probe
-    passing says nothing about 512x1024 VMEM, and a big-tile probe says
-    nothing about the degraded tiles a non-power-of-two-friendly length
-    actually gets. The probe resolves the dispatch's exact blocks, then
-    compiles them at the shortest length that still exercises a
-    MULTI-block grid on both axes (2*max(bq, bk): nq, nk >= 2 — an
-    nk==1 probe is the block-dim-equals-array-dim coincidence class
-    that let a broken lse block through once before, see
-    `_lowering_probe`). ``seq``/``lk`` are the q/k lengths (``lk``
-    defaults to ``seq``) — bq derives from the q length and bk from
-    the k length SEPARATELY, because ring attention's rotating blocks
-    can degrade one axis's tile and not the other's. Cached per
-    (head_dim, dtype, bq, bk)."""
-    lk = seq if lk is None else lk
-    mb = _min_block_for(dtype)
-    dbq, dbk = _default_block_targets(seq, lk)
-    bq = _pick_block(seq, dbq, mb)
-    bk = _pick_block(lk, dbk, mb)
-    if bq is None or bk is None:
-        return False  # dispatch would fall back to dense anyway
-    return _lowering_probe(int(head_dim), jnp.dtype(dtype).name, bq, bk)
-
-
-@functools.lru_cache(maxsize=16)
-def _lowering_probe(head_dim: int, dtype_name: str, bq: int, bk: int) -> bool:
-    if jax.default_backend() != "tpu":
-        return False
-    try:
-        # 2 heads, NOT 1: with a single head the flattened batch*heads dim
-        # is 1, and a block dim of 1 trivially "equals the array dim" —
-        # Mosaic's tile rule then passes shapes it rejects for every real
-        # model (this exact coincidence let a (1, bq) lse block through
-        # the probe and then broke BERT on the first live TPU window).
-        # The probe length keeps BOTH grid axes multi-block (2*max of
-        # two powers of two is divisible by each, so nq, nk >= 2) — an
-        # nk==1 probe is the same coincidence class on the k axis.
-        seq = 2 * max(bq, bk)
-        q = jnp.zeros((1, seq, 2, head_dim), dtype_name)
-
-        def loss(x):
-            return jnp.sum(
-                flash_attention(x, x, x, causal=True,
-                                block_q=bq, block_k=bk).astype(jnp.float32)
-            )
-
-        jax.jit(jax.grad(loss)).lower(q).compile()
-        return True
-    except Exception:
-        return False
-
-
-def flash_auto_ok(lq: int, lk: int, head_dim: int, dtype) -> bool:
+def flash_auto_ok(lq: int, lk: int, dtype) -> bool:
     """The ONE auto-dispatch gate every attention entry point (BERT
-    'full', ring, ulysses) consults: the sequence is long enough that
-    the kernel measured FASTER than XLA's fused dense attention
-    (``FLASH_MIN_SEQ``), shapes tile at this dtype, AND the Mosaic probe
-    (fwd+bwd, causal) compiles. Off-TPU the probe is False, so no
-    separate backend check is needed. The explicit ``attention='flash'``
-    mode bypasses this gate entirely."""
-    return (max(lq, lk) >= FLASH_MIN_SEQ
-            and flash_supported(lq, lk, dtype=dtype)
-            and mosaic_lowering_ok(head_dim, dtype, lq, lk))
+    'full', ring, ulysses) consults, decided from what can be observed
+    without compiling: the backend is a TPU (off-TPU the kernel would
+    run interpreted), the longer side reaches ``FLASH_MIN_SEQ``, and
+    both lengths tile at this dtype. A Mosaic failure at a shape this
+    gate admits raises at compile time instead of silently handing the
+    model the dense path. The explicit ``attention='flash'`` mode
+    bypasses this gate entirely."""
+    return (jax.default_backend() == "tpu"
+            and max(lq, lk) >= FLASH_MIN_SEQ
+            and flash_supported(lq, lk, dtype=dtype))

@@ -1,10 +1,9 @@
 """Exact top-k selection via per-block threshold refine — Pallas TPU.
 
 ``lax.top_k`` on a multi-million-element flat gradient lowers to a full
-bitonic sort: 17.76 ms at 8M elements on v5e vs 3.25 ms for
-``lax.approx_max_k`` (BENCH_TPU_WATCH) — a 5.5× tax for exactness. This
-module closes the gap without giving up exactness by splitting selection
-into the two parts with very different costs:
+bitonic sort, several times the cost of ``lax.approx_max_k`` — a tax for
+exactness. This module aims to close the gap without giving up exactness
+by splitting selection into the two parts with very different costs:
 
 1. **Threshold refine (Pallas count kernel).** The k-th largest |x| is
    found WITHOUT sorting: |x| is viewed as its int32 bit pattern (for
@@ -13,8 +12,7 @@ into the two parts with very different costs:
    count(key >= candidate) still reach k?", each round one gridded
    Pallas pass that accumulates per-block counts into an SMEM scalar
    (sequential TPU grid, race-free — the per-block threshold refine).
-   Each pass is a memory-bound read of n int32s; 31 of them cost a few
-   ms at 8M where one full sort costs ~18.
+   Each pass is a memory-bound read of n int32s.
 
 2. **Chunked compaction.** With the exact threshold in hand, survivor
    indices are compacted by per-chunk biased-key sorts — ONE vectorized

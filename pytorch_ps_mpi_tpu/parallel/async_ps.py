@@ -91,7 +91,7 @@ class AsyncPS:
         schedule, for tests/repro). When omitted, lags are SAMPLED fresh
         each round inside the jitted program — AsySG-InCon's
         inconsistent reads are stochastic arrival effects, not a
-        round-robin (VERDICT r3 item 7).
+        round-robin.
       staleness_probs: distribution over lags ``0..max_staleness`` the
         per-round sampling draws from; default uniform. Feed it a
         *measured* arrival histogram (e.g. a ShmPSServer/TcpPSServer

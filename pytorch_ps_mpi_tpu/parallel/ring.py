@@ -61,7 +61,7 @@ def ring_attention(
     if use_flash is None:
         from pytorch_ps_mpi_tpu.ops.attention_pallas import flash_auto_ok
 
-        use_flash = flash_auto_ok(l_q, l_k, d, q.dtype)
+        use_flash = flash_auto_ok(l_q, l_k, q.dtype)
 
     q_pos = my_idx * l_q + jnp.arange(l_q)            # global query positions
 

@@ -101,6 +101,11 @@ def server_main(shard_id: int, n_shards: int, port: int,
     from pytorch_ps_mpi_tpu.optim import OPTIMIZERS
     from pytorch_ps_mpi_tpu.parallel.async_train import make_problem
     from pytorch_ps_mpi_tpu.parallel.tcp import TcpPSServer
+    from pytorch_ps_mpi_tpu.utils.compile_cache import (
+        enable_compilation_cache,
+    )
+
+    enable_compilation_cache()
 
     code = None
     if cfg.get("codec"):
@@ -377,7 +382,11 @@ def worker_main_sharded(addrs: Sequence[str], worker_id: int,
 
     from pytorch_ps_mpi_tpu.parallel.async_train import make_problem
     from pytorch_ps_mpi_tpu.parallel.tcp import TcpPSWorker
+    from pytorch_ps_mpi_tpu.utils.compile_cache import (
+        enable_compilation_cache,
+    )
 
+    enable_compilation_cache()
     code = None
     if cfg.get("codec"):
         from pytorch_ps_mpi_tpu.codecs import get_codec
@@ -474,8 +483,8 @@ def spawn_shard_server(shard_id: int, n_shards: int, cfg: Dict[str, Any],
                        env: Optional[Dict[str, str]] = None):
     """Launch ``server_main`` in a fresh OS process (port auto-assigned;
     the child prints ``{"shard": i, "port": p}`` on stdout — use
-    :func:`read_server_port`). Pinned to the host backend like
-    ``async_train.spawn_worker``."""
+    :func:`read_server_port`). A shard server is a host process: pinned
+    to the CPU backend, it never takes a chip from a worker."""
     src = (
         "import json,sys\n"
         "import jax; jax.config.update('jax_platforms', 'cpu')\n"
@@ -513,7 +522,9 @@ def read_server_port(proc, timeout: float = 120.0) -> int:
 def spawn_sharded_worker(addrs: Sequence[str], worker_id: int,
                          cfg: Dict[str, Any], out_path: str,
                          env: Optional[Dict[str, str]] = None):
-    """Launch ``worker_main_sharded`` in a fresh OS process."""
+    """Launch ``worker_main_sharded`` in a fresh OS process (host
+    backend; the chip-backed worker is ``async_train.spawn_worker``'s
+    ``env`` path)."""
     src = (
         "import json,sys\n"
         "import jax; jax.config.update('jax_platforms', 'cpu')\n"

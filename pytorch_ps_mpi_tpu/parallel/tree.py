@@ -180,7 +180,11 @@ def leader_main(upstream: Sequence[str], group_id: int,
         shard_plan,
     )
     from pytorch_ps_mpi_tpu.parallel.tcp import TcpPSServer, TcpPSWorker
+    from pytorch_ps_mpi_tpu.utils.compile_cache import (
+        enable_compilation_cache,
+    )
 
+    enable_compilation_cache()
     kw = _leader_knobs(cfg)
     group = [int(w) for w in group]
     n_workers = int(cfg["n_workers"])
@@ -934,9 +938,10 @@ class TreeWorkerConn:
 def spawn_leader(upstream: Sequence[str], group_id: int,
                  group: Sequence[int], cfg: Dict[str, Any], port: int = 0,
                  env: Optional[Dict[str, str]] = None):
-    """Launch ``leader_main`` in a fresh OS process (host backend pinned
-    like every other fleet process); the child prints a one-line hello
-    with its group-facing address."""
+    """Launch ``leader_main`` in a fresh OS process (a leader is a host
+    process: pinned to the CPU backend, it never takes a chip from a
+    worker); the child prints a one-line hello with its group-facing
+    address."""
     src = (
         "import json,sys\n"
         "import jax; jax.config.update('jax_platforms', 'cpu')\n"

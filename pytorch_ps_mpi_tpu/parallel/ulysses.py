@@ -105,7 +105,7 @@ def ulysses_attention(
     if use_flash is None:
         from pytorch_ps_mpi_tpu.ops.attention_pallas import flash_auto_ok
 
-        use_flash = flash_auto_ok(l_full, l_full, d, qh.dtype)
+        use_flash = flash_auto_ok(l_full, l_full, qh.dtype)
     if use_flash:
         from pytorch_ps_mpi_tpu.ops.attention_pallas import flash_attention
 

@@ -11,7 +11,7 @@ follower → follower builds the tree: the trainer-side core serves N
 replicas instead of N×10⁴ readers, and every hop re-serves deltas from
 its own ring, so "I have v → latest" stays cheap at every level.
 
-Pacing is demand-driven, tpu_watch-style: each ``not_modified`` poll
+Pacing is demand-driven: each ``not_modified`` poll
 doubles the sleep up to ``max_poll_s`` (an idle follower stops burning
 a core); any new version snaps it back to ``poll_s``.  Upstream loss
 (root restart, network partition) is survived by the resilient

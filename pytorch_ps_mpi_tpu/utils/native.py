@@ -14,9 +14,11 @@ Wire format of :func:`compress` (little-endian):
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import struct
 import subprocess
+import sys
 import tempfile
 import zlib
 from typing import Optional
@@ -81,10 +83,14 @@ def _repo_root() -> str:
 
 
 def build_and_load(src_name: str, extra_flags=()) -> Optional[ctypes.CDLL]:
-    """Compile ``native/<src_name>`` with g++ (cached by mtime under
-    ``native/_build``) and dlopen it. Returns None — once, latched — if the
-    source is missing or the toolchain fails, so callers fall back to pure
-    Python. Shared by every native component (wirecodec, psqueue).
+    """Compile ``native/<src_name>`` with g++ into ``native/_build`` and
+    dlopen it. The build is keyed on content: a hash of the source and
+    the compile command is recorded beside the ``.so``, and the library
+    is rebuilt whenever that hash differs or is missing — file times say
+    nothing (a copy or a checkout does not preserve them). Returns None
+    — latched, and said once on stderr — if the source is missing or the
+    toolchain fails, so callers fall back to pure Python. Shared by
+    every native component (wirecodec, psqueue, tcpps).
 
     With ``PS_NATIVE_SANITIZE=asan|ubsan|tsan`` the library is built
     with the matching sanitizer into a mode-specific cache directory."""
@@ -96,28 +102,27 @@ def build_and_load(src_name: str, extra_flags=()) -> Optional[ctypes.CDLL]:
     build_dir = os.path.join(_repo_root(), "native", "_build",
                              *([mode] if mode else []))
     so_path = os.path.join(build_dir, f"lib{stem}.so")
+    key_path = so_path + ".key"
     if mode:
         extra_flags = (*extra_flags, *SANITIZE_FLAGS[mode])
+    # -lrt AFTER the source (link order): shm_open lives in librt on
+    # pre-2.34 glibc; newer glibc ships a no-op librt. Linux only —
+    # other platforms have no librt and the flag would fail the build
+    libs = ["-lrt"] if sys.platform.startswith("linux") else []
+    # -ffp-contract=off: the wc_fold_* kernels must not contract
+    # multiply+add into an FMA — the numpy fallback computes them as
+    # separate f32 ops and the native==numpy bit-exact parity contract
+    # (tests/test_native_fold.py) pins that
+    flags = ["g++", "-O3", "-std=c++17", "-ffp-contract=off",
+             "-shared", "-fPIC", *extra_flags]
     try:
-        if not os.path.exists(src):
-            raise FileNotFoundError(src)
+        with open(src, "rb") as f:
+            key = hashlib.sha256(
+                f.read() + "\0".join(flags + libs).encode()).hexdigest()
         os.makedirs(build_dir, exist_ok=True)
-        if (not os.path.exists(so_path)
-                or os.path.getmtime(so_path) < os.path.getmtime(src)):
+        if not os.path.exists(so_path) or _read_text(key_path) != key:
             tmp = tempfile.mktemp(suffix=".so", dir=build_dir)
-            # -lrt AFTER the source (link order): shm_open lives in librt
-            # on pre-2.34 glibc; newer glibc ships a no-op librt. Linux
-            # only — other platforms have no librt and the flag would
-            # fail the whole build into the silent fallback
-            import sys as _sys
-
-            libs = ["-lrt"] if _sys.platform.startswith("linux") else []
-            # -ffp-contract=off: the wc_fold_* kernels must not contract
-            # multiply+add into an FMA — the numpy fallback computes them
-            # as separate f32 ops and the native==numpy bit-exact parity
-            # contract (tests/test_native_fold.py) pins that
-            cmd = ["g++", "-O3", "-std=c++17", "-ffp-contract=off",
-                   "-shared", "-fPIC", *extra_flags, "-o", tmp, src, *libs]
+            cmd = [*flags, "-o", tmp, src, *libs]
             # scrubbed env: under `make native-asan` the PYTHON process
             # runs with the ASan runtime LD_PRELOADed and leak-checking
             # armed — inherited into g++ that flags the compiler's own
@@ -129,9 +134,27 @@ def build_and_load(src_name: str, extra_flags=()) -> Optional[ctypes.CDLL]:
             subprocess.run(cmd, check=True, capture_output=True,
                            timeout=120, env=env)
             os.replace(tmp, so_path)
+            # the key lands after the library: a crash between the two
+            # leaves a mismatch, which rebuilds
+            with open(tmp + ".key", "w") as f:
+                f.write(key)
+            os.replace(tmp + ".key", key_path)
         return ctypes.CDLL(so_path)
-    except Exception:
+    except Exception as e:
         _BUILD_FAILURES.add((src_name, mode))
+        # g++'s own words, when it was g++ that failed
+        said = (getattr(e, "stderr", None) or b"").decode(errors="replace")
+        print(f"native: lib{stem}.so unavailable ({type(e).__name__}: {e}) "
+              f"{said[-400:].strip()}\nnative: the pure-Python path takes "
+              "over", file=sys.stderr, flush=True)
+        return None
+
+
+def _read_text(path: str) -> Optional[str]:
+    try:
+        with open(path) as f:
+            return f.read()
+    except OSError:
         return None
 
 
@@ -150,7 +173,7 @@ def _build_lib() -> Optional[ctypes.CDLL]:
     lib.wc_rle0_decode.restype = ctypes.c_size_t
     # fold kernels (absent from a stale cached .so built before they
     # existed — probe one symbol and leave the rest unbound then; the
-    # mtime check above rebuilds on any source change, so this only
+    # content key above rebuilds on any source change, so this only
     # guards a hand-copied old library)
     try:
         f32p = ctypes.POINTER(ctypes.c_float)

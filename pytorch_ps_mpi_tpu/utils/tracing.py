@@ -76,21 +76,8 @@ def _interval_intersection_len(a, b):
 
 def _iter_hlo_events(trace_dir: str):
     """Yield ``(device, name, start_ns, dur_ns)`` for every device op
-    execution (events carrying an ``hlo_op`` stat) in a trace dir.
-
-    Reader selection: ``jax.profiler.ProfileData`` where the jax build
-    ships it; otherwise the dependency-free wire-format fallback in
-    ``utils/xplane.py`` (older jax writes the same ``xplane.pb`` files
-    but provides no reader)."""
+    execution (events carrying an ``hlo_op`` stat) in a trace dir."""
     for f in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True):
-        if not hasattr(jax.profiler, "ProfileData"):
-            from pytorch_ps_mpi_tpu.utils import xplane
-
-            try:
-                yield from xplane.iter_hlo_events(f)
-            except Exception:
-                pass
-            continue
         try:
             pd = jax.profiler.ProfileData.from_file(f)
         except Exception:
@@ -169,7 +156,7 @@ def profiled_overlap(thunk: Callable[[], Any]) -> Tuple[Any, Dict[str, Any]]:
     cannot see, and the reference's signature design claim (encode/comm
     overlapped with backprop via hooks + a 200-thread pool,
     ``/root/reference/ps.py:65-66,85``) that this framework delegates to
-    XLA's scheduler (VERDICT r3 item 3).
+    XLA's scheduler.
 
     Per device: union the [start, end) intervals of collective ops
     (``_COMM_SUBSTRINGS``) and of every other device op, then intersect.
@@ -249,13 +236,12 @@ def profiled_device_split(
     Returns ``(thunk result, split)`` where split has per-device *mean*
     seconds: ``device_busy_s``, ``comm_s``, ``compute_s``, plus
     ``devices`` and the ``top_ops`` time sinks. Empty split (zeros,
-    ``devices=0``) when the backend emits no device events (some
-    remote/tunneled backends do not support tracing).
+    ``devices=0``) when the backend emits no device events.
 
     ``devices`` is the measured PARTICIPANT count: the lanes that
     executed the program's collectives (per-device planes on real
-    backends, per-executor-thread lines on XLA:CPU where jax 0.4.x
-    attributes no ``device_ordinal``).  ``lowered`` — the lowered
+    backends, per-executor-thread lines on XLA:CPU, which attributes no
+    ``device_ordinal``).  ``lowered`` — the lowered
     program text, or a zero-arg callable producing it — arms the
     launch-counter fallback: on a build whose trace carries NO per-lane
     attribution at all, the participant count is derived as collective

@@ -92,7 +92,7 @@ def test_staleness_validation():
                 staleness=[0, 0, 2, 0])
 
 
-# -- arrival-driven staleness (VERDICT r3 item 7) -----------------------
+# -- arrival-driven staleness -----------------------
 
 def test_sampled_staleness_matches_given_distribution():
     """Default mode samples lags per round; over many rounds the used-lag

@@ -452,7 +452,7 @@ def test_qsgd_levels_bounded():
         QSGDCodec(levels=200)  # would overflow the int8 payload
 
 
-# -- blocktopk (VERDICT r3 item 2: selection without a global sort) -----
+# -- blocktopk: selection without a global sort -----
 
 def test_blocktopk_keeps_each_blocks_largest():
     from pytorch_ps_mpi_tpu.codecs import BlockTopKCodec

@@ -86,8 +86,7 @@ def test_ring_permute(mesh8):
 def test_ragged_all_gather_with_threshold_codec(mesh8):
     """Real variable-length payloads through the ragged protocol: each rank
     threshold-encodes a different gradient, so true lengths genuinely
-    differ per rank (VERDICT r1 item 6 — previously nothing real flowed
-    through ragged_all_gather). The receive side reconstructs the summed
+    differ per rank. The receive side reconstructs the summed
     gradient using the gathered length sidecars for masking."""
     from pytorch_ps_mpi_tpu.codecs import ThresholdCodec
 

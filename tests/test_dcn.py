@@ -175,7 +175,7 @@ def test_pending_grad_counts_as_alive():
         server.close()
 
 
-# -- codecs on the async wire (VERDICT r1 item 5) --------------------------
+# -- codecs on the async wire --------------------------
 
 def _codec_worker_loop(name, worker_id, n_pushes, code):
     w = dcn.ShmPSWorker(name, worker_id, TEMPLATE, code=code)

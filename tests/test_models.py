@@ -147,7 +147,7 @@ def test_resnet_batchnorm_aux_state_distributed(mesh8):
 
 
 def test_syncbn_matches_global_batch_oracle(mesh8):
-    """TRUE SyncBatchNorm (VERDICT r2 item 9): with ``bn_axis='data'``,
+    """TRUE SyncBatchNorm: with ``bn_axis='data'``,
     a data-sharded forward inside shard_map must produce exactly the
     logits and updated running stats of one device seeing the global
     batch — torch DDP SyncBatchNorm semantics, realized as a psum in the

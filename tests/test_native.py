@@ -14,6 +14,37 @@ def test_native_lib_builds():
     assert native.get_lib() is not None
 
 
+def test_native_build_keyed_on_content_not_mtime(tmp_path, monkeypatch,
+                                                capsys):
+    """Staleness is a hash of source + flags recorded beside the .so: an
+    old file time does not rebuild, a changed source does, and a failed
+    build says so on stderr once (then stays latched)."""
+    import os
+
+    (tmp_path / "native").mkdir()
+    src = tmp_path / "native" / "probe.cpp"
+    src.write_text('extern "C" int probe() { return 1; }\n')
+    monkeypatch.setattr(native, "_repo_root", lambda: str(tmp_path))
+    monkeypatch.setattr(native, "_BUILD_FAILURES", set())
+    so = tmp_path / "native" / "_build" / "libprobe.so"
+
+    assert native.build_and_load("probe.cpp").probe() == 1
+    built = so.stat().st_ino
+    os.utime(so, (1, 1))  # older than the source: mtimes say "stale"
+    assert native.build_and_load("probe.cpp").probe() == 1
+    assert so.stat().st_ino == built  # not rebuilt
+
+    src.write_text('extern "C" int probe() { return 2; }\n')
+    native.build_and_load("probe.cpp")
+    assert so.stat().st_ino != built  # the content changed: rebuilt
+
+    src.write_text("this is not C++\n")
+    assert native.build_and_load("probe.cpp") is None
+    assert native.build_and_load("probe.cpp") is None
+    err = capsys.readouterr().err
+    assert err.count("libprobe.so unavailable") == 1
+
+
 def test_shuffle_roundtrip_native_and_fallback():
     rng = np.random.RandomState(0)
     data = rng.bytes(4 * 100)

@@ -1,4 +1,4 @@
-"""Timeline-level comm/compute overlap measurement (VERDICT r3 item 3).
+"""Timeline-level comm/compute overlap measurement.
 
 Pure interval math is tested exactly; the trace-driven path is tested on
 the 8-device CPU mesh with a real psum program, asserting the

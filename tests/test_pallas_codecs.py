@@ -1,13 +1,11 @@
 """Pallas codec kernels (interpret mode on CPU): the exact top-k
 selection kernel and the fused sign / terngrad encode paths.
 
-The committed TPU sweeps motivated all three (BENCH_TPU_WATCH /
-tpu_v5e_2026-07-31_sweep.jsonl): exact ``lax.top_k`` at 17.76 ms vs
-3.25 ms approx at 8M elements, and the sign/terngrad kernels at only
-1.04–1.07× over jnp because nothing was fused. Interpret mode runs the
-same kernel logic element-for-element, so these tests pin correctness;
-the speed claims live in ``benchmarks/codec_bench.py`` behind
-``bench_gate``.
+Interpret mode runs the same kernel logic element-for-element, so
+these tests pin correctness; that the kernels compile for a TPU is
+``tests/test_kernels_compile_tpu.py``, that they execute there against
+their references is ``chip_smoke.py`` phase (c), and their speed is
+``benchmarks/codec_bench.py``'s to measure on a chip.
 """
 
 from __future__ import annotations

@@ -1,4 +1,4 @@
-"""All-reducible PowerSGD (VERDICT r4 weak #3 / next #3): the two-psum
+"""All-reducible PowerSGD: the two-psum
 shared-Q protocol (Vogels et al. 2019 Alg. 1) as the fused-path lowering.
 
 ``P = psum(M_w Q)`` → QR → ``Q = psum(M_wᵀ P̂)`` produces the rank-r

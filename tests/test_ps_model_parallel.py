@@ -1,4 +1,4 @@
-"""MPI_PS driving model-parallel meshes (VERDICT r4 weak #4 / next #2).
+"""MPI_PS driving model-parallel meshes.
 
 The drop-in optimizer (reference role ``ps.py:54-59``) composed with
 Megatron TP (``parallel/tp.py``) and GPipe PP (``parallel/pp.py``):
@@ -132,7 +132,7 @@ def test_mpips_dp_tp_matches_dense_oracle(mesh_dp_tp):
 
 
 def test_mpips_step_equals_hand_rolled_vma_step(mesh_dp_tp):
-    """The exact VERDICT r4 next-#2 'done' criterion: MPI_PS's fused
+    """MPI_PS's fused
     vma-unchecked step == the hand-rolled check_vma=True DP x TP step
     (the formulation test_tp.py::test_dp_tp_train_step_matches_single_device
     uses), leaf for leaf, over 2 steps."""

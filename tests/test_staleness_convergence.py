@@ -1,4 +1,4 @@
-"""Staleness→convergence curve semantics (VERDICT r4 next #4): the
+"""Staleness→convergence curve semantics: the
 in-XLA bounded-staleness sweep must reproduce the committed artifact's
 shape — no tax at small bounds, a real tax at large ones — and the
 bench's updates-to-target machinery must be correct.

@@ -1,5 +1,4 @@
-"""Analytic MFU ceiling for the transformer train steps (VERDICT r4
-next #5's written-roofline half).
+"""Analytic MFU ceiling for the transformer train steps.
 
 Decomposes a BERT/GPT train step's FLOPs by matmul class and assigns
 each class an MXU ceiling from its contraction geometry (a v5e MXU tile
