@@ -1,0 +1,9 @@
+"""Idle ms a step of the first device while the host was in
+``trainer.step`` itself, outside its phases: ``metrics.add``, the loop's
+bookkeeping."""
+
+from chipbench.host_phases import idle_ms
+
+
+def read(trace, spans, counters, cell):
+    return idle_ms(trace, cell, "trainer.step")

@@ -42,6 +42,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from pytorch_ps_mpi_tpu import telemetry
+from pytorch_ps_mpi_tpu.telemetry import span
 
 PyTree = Any
 
@@ -425,58 +426,53 @@ def worker_main(name: str, worker_id: int, cfg: Dict[str, Any]) -> int:
                     w.set_wire_delay(0.0)
                 else:
                     w._wire_delay_s = 0.0
-            # one measured path for recorder spans AND health beacons:
-            # durations are taken once and shared (explicit ts/dur events
-            # are exactly what rec.span records)
-            t0 = time.monotonic()
-            params, version = w.read_params()
-            if rec is not None:
-                rec.event("worker.read_params", kind="span", ts=t0,
-                          dur=time.monotonic() - t0, step=step)
-            t0 = time.monotonic()
-            loss, grads = grad_fn(params, batch_fn(step, worker_id))
-            jax.block_until_ready(grads)
-            compute_s = time.monotonic() - t0
-            if first_grad_s is None:
-                first_grad_s = compute_s  # compile (or cache load) included
-            if rec is not None:
-                rec.event("worker.grad", kind="span", ts=t0, dur=compute_s,
-                          step=step, version=version)
-            if poison:
-                import jax.numpy as jnp
+            # one cycle, read to push, as spans (telemetry.span does
+            # nothing while the recorder is off); the health beacon's
+            # shared durations keep their own clock readings
+            with span("worker.step", step=step):
+                with span("worker.read_params"):
+                    params, version = w.read_params()
+                t0 = time.monotonic()
+                with span("worker.grad", version=version):
+                    with span("worker.batch"):
+                        batch = batch_fn(step, worker_id)
+                    with span("worker.grad_dispatch"):
+                        loss, grads = grad_fn(params, batch)
+                    with span("worker.grad_wait"):
+                        jax.block_until_ready(grads)
+                compute_s = time.monotonic() - t0
+                if first_grad_s is None:
+                    # compile (or cache load) included
+                    first_grad_s = compute_s
+                if poison:
+                    import jax.numpy as jnp
 
-                grads = jax.tree.map(
-                    lambda g: jnp.full_like(g, jnp.nan), grads
-                )
-            if prober is not None and step % probe_every == 0:
-                try:
-                    prober.write(step, w.wire.probe_fidelity(grads))
-                except Exception:
-                    pass  # a probe must never take a worker down
-            straggle_s = 0.0
-            if slow_ms:
-                t0 = time.monotonic()
-                time.sleep(slow_ms / 1e3)  # deliberate straggler
-                straggle_s = time.monotonic() - t0
-                if rec is not None:
-                    rec.event("worker.straggle", kind="span", ts=t0,
-                              dur=straggle_s, step=step)
-            if not drop:
-                t0 = time.monotonic()
-                seq0 = push_seq
-                w.push_grad(grads, version, timeout=push_timeout,
-                            lineage=(step, push_seq))
-                push_seq += 1
-                if duplicate:
-                    w.push_grad(grads, version, timeout=push_timeout,
-                                lineage=(step, push_seq))
-                    push_seq += 1
-                if rec is not None:
+                    grads = jax.tree.map(
+                        lambda g: jnp.full_like(g, jnp.nan), grads
+                    )
+                if prober is not None and step % probe_every == 0:
+                    try:
+                        prober.write(step, w.wire.probe_fidelity(grads))
+                    except Exception:
+                        pass  # a probe must never take a worker down
+                straggle_s = 0.0
+                if slow_ms:
+                    t0 = time.monotonic()
+                    with span("worker.straggle"):
+                        time.sleep(slow_ms / 1e3)  # deliberate straggler
+                    straggle_s = time.monotonic() - t0
+                if not drop:
                     # seq joins the span so trace export can tie this
                     # push span to the server's consume span (flow arrow)
-                    rec.event("worker.push_grad", kind="span", ts=t0,
-                              dur=time.monotonic() - t0, step=step,
-                              version=version, seq=seq0)
+                    with span("worker.push_grad", version=version,
+                              seq=push_seq):
+                        w.push_grad(grads, version, timeout=push_timeout,
+                                    lineage=(step, push_seq))
+                        push_seq += 1
+                        if duplicate:
+                            w.push_grad(grads, version, timeout=push_timeout,
+                                        lineage=(step, push_seq))
+                            push_seq += 1
             pushed += 1
             if beacon is not None:
                 # step accounting for straggler ATTRIBUTION: the

@@ -23,7 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from pytorch_ps_mpi_tpu.telemetry import PSServerTelemetry
+from pytorch_ps_mpi_tpu.telemetry import PSServerTelemetry, span
 
 PyTree = Any
 
@@ -882,16 +882,18 @@ class ShmPSWorker:
         unframed wire — there is nowhere to carry it. On a tree wire,
         ``composed`` lists the constituent trace IDs for the lineage
         trailer (default: this worker itself)."""
-        if self.wire:
-            # encode-before-send (reference ps.py:94): only payload bytes
-            # ever enter the mailbox. encode_to_bytes hands back its
-            # preallocated ping-pong buffer — valid through this push's
-            # retry loop, no defensive copy needed.
-            flat = self.wire.encode_to_bytes(grad)
-        else:
-            flat = _flatten(grad)
-        self.push_payload(flat, version, timeout=timeout, lineage=lineage,
-                          composed=composed)
+        with span("wire.encode"):
+            if self.wire:
+                # encode-before-send (reference ps.py:94): only payload
+                # bytes ever enter the mailbox. encode_to_bytes hands back
+                # its preallocated ping-pong buffer — valid through this
+                # push's retry loop, no defensive copy needed.
+                flat = self.wire.encode_to_bytes(grad)
+            else:
+                flat = _flatten(grad)
+        with span("wire.send"):
+            self.push_payload(flat, version, timeout=timeout,
+                              lineage=lineage, composed=composed)
 
     def push_payload(self, flat: np.ndarray, version: int,
                      timeout: float = 30.0,

@@ -49,7 +49,7 @@ from pytorch_ps_mpi_tpu.parallel.dcn import (
     _u8,
     _unflatten,
 )
-from pytorch_ps_mpi_tpu.telemetry import PSServerTelemetry
+from pytorch_ps_mpi_tpu.telemetry import PSServerTelemetry, span
 
 _lib: Optional[ctypes.CDLL] = None
 
@@ -799,15 +799,17 @@ class TcpPSWorker:
         constituent ``(worker, step, seq, send_wall)`` trace IDs for the
         lineage trailer; default is this worker's own trace ID (the
         direct-push / fallback case)."""
-        if self.wire:
-            # encode_to_bytes returns its preallocated ping-pong wire
-            # buffer (one contiguous bucket payload per push) — the native
-            # send consumes it synchronously, no defensive copy
-            flat = self.wire.encode_to_bytes(grad)
-        else:
-            flat = _flatten(grad)
-        self.push_payload(flat, version, timeout=timeout, lineage=lineage,
-                          composed=composed)
+        with span("wire.encode"):
+            if self.wire:
+                # encode_to_bytes returns its preallocated ping-pong wire
+                # buffer (one contiguous bucket payload per push) — the
+                # native send consumes it synchronously, no defensive copy
+                flat = self.wire.encode_to_bytes(grad)
+            else:
+                flat = _flatten(grad)
+        with span("wire.send"):
+            self.push_payload(flat, version, timeout=timeout,
+                              lineage=lineage, composed=composed)
 
     def push_payload(self, flat: np.ndarray, version: int,
                      timeout: float = 30.0,

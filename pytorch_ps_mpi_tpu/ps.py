@@ -61,7 +61,7 @@ from pytorch_ps_mpi_tpu.bucketing import (
     unflatten_from_buckets,
 )
 from pytorch_ps_mpi_tpu.codecs import Codec, ErrorFeedback, IdentityCodec
-from pytorch_ps_mpi_tpu.telemetry import get_recorder
+from pytorch_ps_mpi_tpu.telemetry import get_recorder, span
 from pytorch_ps_mpi_tpu.mesh import DATA_AXIS, make_mesh
 from pytorch_ps_mpi_tpu.optim import (
     OPTIMIZERS,
@@ -1970,11 +1970,11 @@ class MPI_PS:
         walls, use ``instrument=True`` instead.
         """
         t0 = time.perf_counter()
-        data = self._schema_dict()
         loss = None
-        self._rng, rng = jax.random.split(self._rng)
 
         if self.instrument:
+            data = self._schema_dict()
+            self._rng, rng = jax.random.split(self._rng)
             if profile:
                 raise ValueError(
                     "profile=True and instrument=True are mutually "
@@ -2003,82 +2003,82 @@ class MPI_PS:
             self._record_step("ps.step", data)
             return loss, data
 
-        if loss_fn is not None:
-            if batch is None:
-                raise ValueError("loss_fn requires batch")
-            has_aux = aux_state is not None
-            key = ("grad", _fn_cache_key(loss_fn), has_aux)
-            if key not in self._compiled:
-                self._compiled[key] = self._build_grad_step(loss_fn, has_aux)
-            fn = self._compiled[key]
-            extra = (aux_state,) if has_aux else ()
-            call = lambda: fn(
-                self.params, self.opt_state, self.codec_state, batch, rng, *extra
-            )
-            if profile:
-                out, split = self._profiled_call(
-                    call, data,
-                    lowered=lambda: fn.lower(
-                        self.params, self.opt_state, self.codec_state,
-                        batch, rng, *extra).as_text())
-            else:
-                out = call()
+        # The fused program: one span around the step and one around each
+        # host phase of it (telemetry.span does nothing while the recorder
+        # is off), so that a device trace says what the host was in
+        # whenever the chip waited.
+        with span("ps.step", step=self._step_count + 1) as attrs:
+            with span("ps.prepare"):
+                data = self._schema_dict()
+                self._rng, rng = jax.random.split(self._rng)
+                has_aux = aux_state is not None
+                if loss_fn is not None:
+                    if batch is None:
+                        raise ValueError("loss_fn requires batch")
+                    key = ("grad", _fn_cache_key(loss_fn), has_aux)
+                    if key not in self._compiled:
+                        self._compiled[key] = self._build_grad_step(
+                            loss_fn, has_aux)
+                    args = (self.params, self.opt_state, self.codec_state,
+                            batch, rng) + ((aux_state,) if has_aux else ())
+                elif grads is not None:
+                    if has_aux:
+                        raise NotImplementedError(
+                            "aux_state requires the loss_fn path (grads-only "
+                            "steps have no forward pass to produce new aux "
+                            "state)"
+                        )
+                    if self._model_parallel:
+                        raise NotImplementedError(
+                            "grads-only steps are not supported with "
+                            "param_specs: a host-side [world, ...] gradient "
+                            "stack is ambiguous for model-sharded leaves — "
+                            "use the loss_fn path"
+                        )
+                    key = ("grads-only",)
+                    if key not in self._compiled:
+                        self._compiled[key] = self._build_grads_only_step()
+                    args = (self.params, self.opt_state, self.codec_state,
+                            grads, rng)
+                else:
+                    raise ValueError("pass grads or loss_fn+batch")
+                fn = self._compiled[key]
+            with span("ps.dispatch"):
+                if profile:
+                    out, _ = self._profiled_call(
+                        lambda: fn(*args), data,
+                        lowered=lambda: fn.lower(*args).as_text())
+                else:
+                    out = fn(*args)
+            # the donated buffers die with their last reference: here,
+            # while the device runs, and not as this frame is left
+            del args
             if self.numerics:
-                (self.params, self.opt_state, self.codec_state, loss,
-                 new_aux, nvec) = out
+                *out, nvec = out
                 self._fill_numerics(data, nvec)
-            else:
+            if loss_fn is not None:
                 (self.params, self.opt_state, self.codec_state, loss,
                  new_aux) = out
-            if has_aux:
-                self.aux_state = new_aux
-        elif grads is not None:
-            if aux_state is not None:
-                raise NotImplementedError(
-                    "aux_state requires the loss_fn path (grads-only steps "
-                    "have no forward pass to produce new aux state)"
-                )
-            if self._model_parallel:
-                raise NotImplementedError(
-                    "grads-only steps are not supported with param_specs: "
-                    "a host-side [world, ...] gradient stack is ambiguous "
-                    "for model-sharded leaves — use the loss_fn path"
-                )
-            key = ("grads-only",)
-            if key not in self._compiled:
-                self._compiled[key] = self._build_grads_only_step()
-            fn = self._compiled[key]
-            call = lambda: fn(
-                self.params, self.opt_state, self.codec_state, grads, rng
-            )
-            if profile:
-                out, split = self._profiled_call(
-                    call, data,
-                    lowered=lambda: fn.lower(
-                        self.params, self.opt_state, self.codec_state,
-                        grads, rng).as_text())
-            else:
-                out = call()
-            if self.numerics:
-                (self.params, self.opt_state, self.codec_state,
-                 nvec) = out
-                self._fill_numerics(data, nvec)
+                if has_aux:
+                    self.aux_state = new_aux
             else:
                 self.params, self.opt_state, self.codec_state = out
-        else:
-            raise ValueError("pass grads or loss_fn+batch")
 
-        if closure is not None:
-            loss = closure()
+            if closure is not None:
+                loss = closure()
 
-        jax.block_until_ready(self.params)
-        # The fused program has no separable comm/decode/update stages —
-        # step_time is always a real measurement; profile=True adds the
-        # trace-derived comm/compute split, and instrument=True (separate
-        # mode) fills the remaining per-stage keys with host wall times.
-        data["step_time"] = time.perf_counter() - t0
-        self._step_count += 1
-        self._record_step("ps.step", data)
+            with span("ps.wait"):
+                jax.block_until_ready(self.params)
+            # The fused program has no separable comm/decode/update stages
+            # — step_time is always a real measurement; profile=True adds
+            # the trace-derived comm/compute split, and instrument=True
+            # (separate mode) fills the remaining per-stage keys with host
+            # wall times.
+            data["step_time"] = time.perf_counter() - t0
+            self._step_count += 1
+            if attrs is not None:
+                attrs.update((k, v) for k, v in data.items()
+                             if isinstance(v, (int, float, str)))
         return loss, data
 
     def _profiled_call(self, call, data: Dict[str, float], lowered=None):

@@ -12,6 +12,8 @@ Every record is one flat dict (the JSONL row):
 ``worker``     worker id (recorder default, overridable per record)
 ``step``       training/serve step the record belongs to
 ``staleness``  gradient staleness, when the record is about one gradient
+``parent``     name of the span that was open on the recording thread when
+               this one began (module-level :func:`span` only)
 ``attrs``      everything else (free-form, JSON-serializable)
 
 The buffer is a ``deque(maxlen=capacity)``: recording never blocks on
@@ -23,6 +25,12 @@ A process-global recorder is installed with :func:`configure`; call
 sites guard on :func:`get_recorder` returning ``None`` — the disabled
 cost is one module attribute read, which is what lets the recorder ride
 inside every training mode unconditionally.
+
+The hot paths (``Trainer.fit``, ``MPI_PS.step``, ``worker_main``, the
+transports' ``push_grad``) open their spans with the module-level
+:func:`span`: besides the row it enters a ``jax.profiler`` annotation of
+the same name, so that under a profiler session the span also sits on
+the ``/host:CPU`` plane of the device trace, on the profiler's clock.
 """
 
 from __future__ import annotations
@@ -33,6 +41,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
+
+import jax
 
 _HEADER_KIND = "recorder_meta"
 
@@ -63,6 +73,7 @@ class FlightRecorder:
         step: Optional[int] = None,
         worker: Optional[Any] = None,
         staleness: Optional[int] = None,
+        parent: Optional[str] = None,
         **attrs: Any,
     ) -> None:
         """Append one record. ``ts`` defaults to now (monotonic); pass an
@@ -87,6 +98,8 @@ class FlightRecorder:
             rec["worker"] = w
         if staleness is not None:
             rec["staleness"] = int(staleness)
+        if parent is not None:
+            rec["parent"] = parent
         if attrs:
             rec["attrs"] = attrs
         with self._lock:
@@ -224,13 +237,80 @@ def record_event(name: str, **kw: Any) -> None:
         rec.event(name, **kw)
 
 
-@contextlib.contextmanager
-def span(name: str, **kw: Any) -> Iterator[None]:
-    """Module-level span on the global recorder; a plain (cheap) yield
-    when disabled."""
+# the spans open on each thread, innermost last
+_open_spans = threading.local()
+# the per-step parents: a StepTraceAnnotation, so the trace knows the step
+_STEP_SPANS = frozenset(("trainer.step", "worker.step"))
+
+
+class _NoSpan:
+    """What :func:`span` hands out while the recorder is off."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc: Any) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One open span of the global recorder: a ``jax.profiler``
+    annotation around the body, one recorder row on exit."""
+
+    __slots__ = ("rec", "name", "step", "attrs", "parent", "stack",
+                 "annotation", "t0")
+
+    def __init__(self, rec: FlightRecorder, name: str, step: Optional[int],
+                 attrs: Dict[str, Any]) -> None:
+        self.rec, self.name, self.step, self.attrs = rec, name, step, attrs
+
+    def __enter__(self) -> Dict[str, Any]:
+        try:
+            stack = _open_spans.stack
+        except AttributeError:
+            stack = _open_spans.stack = []
+        self.stack = stack
+        self.parent = None
+        if stack:
+            self.parent = stack[-1].name
+            if self.step is None:
+                self.step = stack[-1].step
+        if self.name in _STEP_SPANS and self.step is not None:
+            self.annotation = jax.profiler.StepTraceAnnotation(
+                self.name, step_num=self.step)
+        else:
+            self.annotation = jax.profiler.TraceAnnotation(self.name)
+        stack.append(self)
+        self.t0 = time.monotonic()
+        self.annotation.__enter__()
+        return self.attrs
+
+    def __exit__(self, *exc: Any) -> None:
+        self.annotation.__exit__(*exc)
+        dur = time.monotonic() - self.t0
+        self.stack.pop()
+        self.rec.event(self.name, kind="span", ts=self.t0, dur=dur,
+                       step=self.step, parent=self.parent, **self.attrs)
+
+
+def span(name: str, *, step: Optional[int] = None, **attrs: Any):
+    """The one span primitive of the hot paths, on two clocks.
+
+    Recorder off: a shared do-nothing context (``as`` gives None), no
+    annotation object made. Recorder on: a ``jax.profiler`` annotation
+    named ``name`` is open around the body — on the device trace's clock
+    whenever a profiler session runs — and on exit (also when the body
+    raises) one ``kind="span"`` row is recorded with ``parent``, the span
+    open on this thread when this one began, and with ``step`` inherited
+    from it when not given. ``as`` gives the row's attribute dict, to
+    which the body may add what is known only at the end (``loss``, a
+    step's ``data``)."""
     rec = _recorder
     if rec is None:
-        yield
-    else:
-        with rec.span(name, **kw):
-            yield
+        return _NO_SPAN
+    return _Span(rec, name, step, attrs)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorch_ps_mpi_tpu.ps import MPI_PS
-from pytorch_ps_mpi_tpu.telemetry import get_recorder
+from pytorch_ps_mpi_tpu.telemetry import span
 from pytorch_ps_mpi_tpu.utils.checkpoint import CheckpointManager
 from pytorch_ps_mpi_tpu.utils.metrics import MetricsAccumulator
 
@@ -73,12 +73,8 @@ class Trainer:
     def save(self) -> None:
         if self.ckpt is None:
             raise RuntimeError("no checkpoint_dir configured")
-        rec = get_recorder()
-        if rec is None:
+        with span("trainer.checkpoint", step=self.step_count):
             self.ckpt.save(self.step_count, self._state())
-        else:
-            with rec.span("trainer.checkpoint", step=self.step_count):
-                self.ckpt.save(self.step_count, self._state())
         self._last_saved_step = self.step_count
 
     def maybe_restore(self) -> bool:
@@ -145,32 +141,33 @@ class Trainer:
         last_loss = None
         done = 0
         while done < num_steps:
-            rec = get_recorder()  # one attr read/step when disabled
+            # telemetry.span: does nothing while the recorder is off
             if self.scan_chunk > 1 and num_steps - done >= self.scan_chunk:
-                span_t0 = time.monotonic()
-                chunk = [next(batches) for _ in range(self.scan_chunk)]
-                stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *chunk)
-                losses, data = self.opt.run_steps(self.loss_fn, stacked)
-                last_loss = float(losses[-1])
-                self.metrics.add(data)
-                done += self.scan_chunk
-                self.step_count += self.scan_chunk
-                if rec is not None:
-                    rec.event("trainer.step_chunk", kind="span", ts=span_t0,
-                              dur=time.monotonic() - span_t0,
-                              step=self.step_count, loss=last_loss,
-                              n_steps=self.scan_chunk)
+                with span("trainer.step_chunk",
+                          step=self.step_count + self.scan_chunk,
+                          n_steps=self.scan_chunk) as attrs:
+                    chunk = [next(batches) for _ in range(self.scan_chunk)]
+                    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *chunk)
+                    losses, data = self.opt.run_steps(self.loss_fn, stacked)
+                    last_loss = float(losses[-1])
+                    self.metrics.add(data)
+                    done += self.scan_chunk
+                    self.step_count += self.scan_chunk
+                    if attrs is not None:
+                        attrs["loss"] = last_loss
             else:
-                span_t0 = time.monotonic()
-                loss, data = self.opt.step(loss_fn=self.loss_fn, batch=next(batches))
-                last_loss = float(loss)
-                self.metrics.add(data)
-                done += 1
-                self.step_count += 1
-                if rec is not None:
-                    rec.event("trainer.step", kind="span", ts=span_t0,
-                              dur=time.monotonic() - span_t0,
-                              step=self.step_count, loss=last_loss)
+                with span("trainer.step", step=self.step_count + 1) as attrs:
+                    with span("trainer.data"):
+                        batch = next(batches)
+                    loss, data = self.opt.step(loss_fn=self.loss_fn,
+                                               batch=batch)
+                    with span("trainer.loss_fetch"):
+                        last_loss = float(loss)
+                    self.metrics.add(data)
+                    done += 1
+                    self.step_count += 1
+                    if attrs is not None:
+                        attrs["loss"] = last_loss
             if log_every and done % log_every == 0:
                 rate = done / (time.perf_counter() - t0)
                 print(f"step {self.step_count}: loss={last_loss:.4f} "

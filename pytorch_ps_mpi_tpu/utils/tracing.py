@@ -6,35 +6,13 @@ inside a fused XLA program)."""
 from __future__ import annotations
 
 import collections
-import contextlib
 import glob
 import os
 import shutil
 import tempfile
-from typing import Any, Callable, Dict, Iterator, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Capture a device trace viewable in TensorBoard/Perfetto:
-
-    >>> with trace('/tmp/jax-trace'):
-    ...     opt.step(loss_fn=loss_fn, batch=batch)
-    """
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region inside a trace (shows up on the timeline)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 # HLO/primitive names that are interconnect work. Covers both the jax

@@ -84,3 +84,19 @@ def mesh4x2():
     from pytorch_ps_mpi_tpu.mesh import make_mesh
 
     return make_mesh(shape=(4, 2), axis_names=("data", "seq"))
+
+
+@pytest.fixture
+def annotations_made(monkeypatch):
+    """``jax.profiler``'s two annotation classes, patched to count: the
+    list of ``(class, name, kwargs)`` of every one constructed."""
+    made = []
+    for cls in ("TraceAnnotation", "StepTraceAnnotation"):
+        real = getattr(jax.profiler, cls)
+
+        def counted(name, *a, _real=real, _cls=cls, **kw):
+            made.append((_cls, name, kw))
+            return _real(name, *a, **kw)
+
+        monkeypatch.setattr(jax.profiler, cls, counted)
+    return made

@@ -402,3 +402,93 @@ def test_inxla_sampled_staleness_matches_shm_arrival_histogram():
                      for i in range(max_staleness + 1)])
     tv = 0.5 * np.abs(shm_p - ps_p).sum()
     assert tv < 0.15, (shm_p.tolist(), ps_p.tolist(), tv)
+
+
+# -- the host phase spans of the asynchronous worker --------------------------
+
+ASYNC_SPANS = {  # span -> parent: the table of worker_main and push_grad
+    "worker.step": None, "worker.read_params": "worker.step",
+    "worker.grad": "worker.step", "worker.batch": "worker.grad",
+    "worker.grad_dispatch": "worker.grad", "worker.grad_wait": "worker.grad",
+    "worker.push_grad": "worker.step", "wire.encode": "worker.push_grad",
+    "wire.send": "worker.push_grad"}
+
+SPAN_CFG = {"model": "mlp", "model_kw": {"features": (16, 4)},
+            "in_shape": (8,), "batch": 16, "seed": 5, "codec": "sign",
+            "codec_kw": {"use_pallas": False}, "optim": "sgd",
+            "hyper": {"lr": 0.02}}
+
+
+def test_two_workers_record_the_phase_spans_of_every_cycle(tmp_path):
+    from pytorch_ps_mpi_tpu.codecs import get_codec
+    from pytorch_ps_mpi_tpu.telemetry import load_jsonl
+
+    steps = 4
+    cfg = dict(SPAN_CFG, steps=steps, telemetry_dir=str(tmp_path))
+    _, params0, _, _ = make_problem(cfg)
+    name = f"/psq_spans_{os.getpid()}"
+    server = dcn.ShmPSServer(
+        name, num_workers=2, template=params0, max_staleness=8,
+        code=get_codec(cfg["codec"], **cfg["codec_kw"]))
+    try:
+        procs = [spawn_worker(name, i, cfg) for i in range(2)]
+        serve(server, dict(cfg, telemetry_dir=None), total_grads=0,
+              total_received=2 * steps, timeout=240.0)
+        assert join_workers(procs, timeout=120) == [0, 0]
+    finally:
+        server.close()
+    for wid in range(2):
+        _, rows = load_jsonl(str(tmp_path / f"worker-{wid}.jsonl"))
+        spans = [e for e in rows if e["kind"] == "span"]
+        assert sorted(e["name"] for e in spans) == sorted(
+            steps * list(ASYNC_SPANS))
+        for e in spans:
+            assert e.get("parent") == ASYNC_SPANS[e["name"]], e
+            assert e["worker"] == wid
+        for step in range(steps):
+            mine = {e["name"]: e for e in spans if e["step"] == step}
+            assert set(mine) == set(ASYNC_SPANS)
+            for child, parent in ASYNC_SPANS.items():  # parents cover children
+                if parent:
+                    c, p = mine[child], mine[parent]
+                    assert p["ts"] <= c["ts"]
+                    assert c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-9
+            # what the two old rows carried, they still carry
+            assert mine["worker.grad"]["attrs"]["version"] >= 1
+            assert mine["worker.push_grad"]["attrs"]["seq"] == step
+            assert (mine["worker.push_grad"]["attrs"]["version"]
+                    == mine["worker.grad"]["attrs"]["version"])
+
+
+def test_recorder_off_worker_makes_no_annotation_and_no_row(
+        monkeypatch, annotations_made):
+    """``worker_main`` in this process (a thread beside the server) with
+    the recorder off: no annotation object made, no recorder row."""
+    import threading
+
+    from pytorch_ps_mpi_tpu import telemetry
+    from pytorch_ps_mpi_tpu.codecs import get_codec
+    from pytorch_ps_mpi_tpu.parallel.async_train import worker_main
+
+    telemetry.disable()
+    rows = []
+    monkeypatch.setattr(telemetry.FlightRecorder, "event",
+                        lambda self, name, **kw: rows.append(name))
+    cfg = dict(SPAN_CFG, steps=3)
+    _, params0, _, _ = make_problem(cfg)
+    name = f"/psq_off_{os.getpid()}"
+    server = dcn.ShmPSServer(
+        name, num_workers=1, template=params0, max_staleness=8,
+        code=get_codec(cfg["codec"], **cfg["codec_kw"]))
+    pushed = []
+    worker = threading.Thread(
+        target=lambda: pushed.append(worker_main(name, 0, cfg)))
+    try:
+        worker.start()
+        _, m = serve(server, cfg, total_grads=0, total_received=3,
+                     timeout=120.0)
+        worker.join(60)
+    finally:
+        server.close()
+    assert pushed == [3] and m["grads_received"] == 3
+    assert annotations_made == [] and rows == []

@@ -186,6 +186,148 @@ def test_global_recorder_zero_cost_guard():
     assert telemetry.get_recorder() is None
 
 
+# -- the span primitive of the hot paths: two clocks --------------------------
+
+def test_span_off_does_nothing(annotations_made):
+    made = annotations_made
+    with telemetry.span("trainer.step", step=3, loss=1.0) as attrs:
+        assert attrs is None
+    assert made == [] and telemetry.get_recorder() is None
+
+
+def test_span_on_records_parent_and_inherited_step(annotations_made):
+    made = annotations_made
+    rec = telemetry.configure(capacity=64, worker="t")
+    with telemetry.span("trainer.step", step=7) as attrs:
+        with telemetry.span("ps.step"):
+            with telemetry.span("ps.wait"):
+                pass
+            with telemetry.span("ps.late", step=9, seq=4):
+                pass
+        attrs["loss"] = 0.25  # known only at the end
+    with telemetry.span("alone"):
+        pass
+    rows = {e["name"]: e for e in rec.events()}
+    assert list(rows) == ["ps.wait", "ps.late", "ps.step", "trainer.step",
+                          "alone"]  # written on exit, innermost first
+    assert "parent" not in rows["trainer.step"]
+    assert "parent" not in rows["alone"]
+    assert rows["ps.step"]["parent"] == "trainer.step"
+    assert rows["ps.wait"]["parent"] == rows["ps.late"]["parent"] == "ps.step"
+    assert [rows[n]["step"] for n in ("trainer.step", "ps.step", "ps.wait",
+                                      "ps.late")] == [7, 7, 7, 9]
+    assert "step" not in rows["alone"]
+    assert rows["trainer.step"]["attrs"] == {"loss": 0.25}
+    assert rows["ps.late"]["attrs"] == {"seq": 4}
+    assert all(e["kind"] == "span" and e["dur"] >= 0 and e["worker"] == "t"
+               for e in rows.values())
+    # a child lies inside its parent on the recorder's clock too
+    for child, parent in (("ps.wait", "ps.step"), ("ps.step", "trainer.step")):
+        c, p_ = rows[child], rows[parent]
+        assert p_["ts"] <= c["ts"]
+        assert c["ts"] + c["dur"] <= p_["ts"] + p_["dur"]
+    # one annotation a span; the per-step parent is a StepTraceAnnotation
+    assert [(c, n) for c, n, _ in made] == [
+        ("StepTraceAnnotation", "trainer.step"),
+        ("TraceAnnotation", "ps.step"), ("TraceAnnotation", "ps.wait"),
+        ("TraceAnnotation", "ps.late"), ("TraceAnnotation", "alone")]
+    assert made[0][2] == {"step_num": 7}
+
+
+def test_span_stack_is_per_thread():
+    import threading
+
+    rec = telemetry.configure(capacity=64)
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        inside.wait(5)
+        with telemetry.span("worker.step", step=0):
+            with telemetry.span("worker.grad"):
+                pass
+        release.set()
+
+    t = threading.Thread(target=other)
+    t.start()
+    with telemetry.span("trainer.step", step=1):
+        inside.set()
+        assert release.wait(5)
+        with telemetry.span("trainer.data"):
+            pass
+    t.join()
+    rows = {e["name"]: e for e in rec.events()}
+    assert "parent" not in rows["worker.step"]  # not the main thread's span
+    assert rows["worker.grad"]["parent"] == "worker.step"
+    assert rows["worker.grad"]["step"] == 0
+    assert rows["trainer.data"]["parent"] == "trainer.step"
+
+
+def test_span_still_records_when_the_body_raises():
+    rec = telemetry.configure(capacity=64)
+    with pytest.raises(ZeroDivisionError):
+        with telemetry.span("trainer.step", step=2):
+            with telemetry.span("ps.step") as attrs:
+                attrs["reached"] = True
+                1 / 0
+    with telemetry.span("after"):
+        pass
+    rows = {e["name"]: e for e in rec.events()}
+    assert rows["ps.step"]["parent"] == "trainer.step"
+    assert rows["ps.step"]["attrs"] == {"reached": True}
+    assert rows["trainer.step"]["step"] == 2
+    assert "parent" not in rows["after"]  # the stack unwound
+
+
+def test_fit_spans_sit_on_the_profilers_host_plane(tmp_path):
+    """Under a profiler session the spans of ``Trainer.fit`` are events
+    of ``/host:CPU`` in the written trace — the plane a chip's trace
+    keeps its host threads on, on the device operations' clock."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_ps_mpi_tpu import SGD
+    from pytorch_ps_mpi_tpu.trainer import Trainer
+
+    def batches():
+        while True:
+            yield jnp.ones((8, 4)), jnp.zeros((8, 2))
+
+    loss_fn = lambda p, b: jnp.mean((b[0] @ p["w"] - b[1]) ** 2)
+    trainer = Trainer(SGD({"w": jnp.ones((4, 2))}, lr=0.1, average=True),
+                      loss_fn)
+    trainer.fit(batches(), 1)  # compiled outside the trace
+    telemetry.configure()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # annotations only, as the benchmark
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        trainer.fit(batches(), 3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+              for line in host.lines for e in line.events
+              if e.name.startswith(("trainer.", "ps."))]
+    names = sorted(e[0] for e in events)
+    assert names == sorted(3 * ["trainer.step", "trainer.data", "ps.step",
+                                "ps.prepare", "ps.dispatch", "ps.wait",
+                                "trainer.loss_fetch"])
+    steps = sorted(e for e in events if e[0] == "trainer.step")
+    assert [e[3]["step_num"] for e in steps] == [2, 3, 4]
+    parents = {"trainer.data": "trainer.step", "ps.step": "trainer.step",
+               "trainer.loss_fetch": "trainer.step", "ps.prepare": "ps.step",
+               "ps.dispatch": "ps.step", "ps.wait": "ps.step"}
+    for name, lo, hi, _ in events:
+        if name in parents:  # inside exactly one event of its parent
+            assert sum(p[1] <= lo and hi <= p[2] for p in events
+                       if p[0] == parents[name]) == 1, name
+
+
 # -- registry primitives ----------------------------------------------------
 
 def test_registry_prometheus_text_and_types():

@@ -224,3 +224,90 @@ def test_adafactor_checkpoint_resume_bitexact(mesh8, tmp_path):
     t2.fit(data2, num_steps=2)
     t3.fit(data3, num_steps=2)
     assert_trees_equal(t2.opt.params, t3.opt.params)
+
+
+# -- the host phase spans of the synchronous loop -----------------------------
+
+SYNC_SPANS = {  # span -> parent: the table of Trainer.fit and MPI_PS.step
+    "trainer.step": None, "trainer.data": "trainer.step",
+    "ps.step": "trainer.step", "ps.prepare": "ps.step",
+    "ps.dispatch": "ps.step", "ps.wait": "ps.step",
+    "trainer.loss_fetch": "trainer.step"}
+
+
+def test_fit_records_the_phase_spans_of_every_step(mesh8, annotations_made):
+    from pytorch_ps_mpi_tpu import telemetry
+
+    params, data = make_data()
+    t = Trainer(SGD(params, mesh=mesh8, lr=0.1, average=True), quad_loss)
+    t.fit(data, 2)
+    rec = telemetry.configure()
+    try:
+        out = t.fit(data, 3)
+    finally:
+        telemetry.disable()
+    rows = rec.events()
+    assert sorted(n for _, n, _ in annotations_made) == sorted(
+        e["name"] for e in rows)
+    assert sorted(e["name"] for e in rows) == sorted(3 * list(SYNC_SPANS))
+    for e in rows:
+        assert e.get("parent") == SYNC_SPANS[e["name"]], e
+    assert sorted({e["step"] for e in rows}) == [3, 4, 5]
+    for step in (3, 4, 5):
+        mine = {e["name"]: e for e in rows if e["step"] == step}
+        assert set(mine) == set(SYNC_SPANS)
+        for name, parent in SYNC_SPANS.items():  # a parent covers its children
+            if parent:
+                c, p = mine[name], mine[parent]
+                assert p["ts"] <= c["ts"]
+                assert c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-9
+        # the phases of ps.step follow one another
+        assert (mine["ps.prepare"]["ts"] <= mine["ps.dispatch"]["ts"]
+                <= mine["ps.wait"]["ts"] <= mine["trainer.loss_fetch"]["ts"])
+        # what a row carried before, it still carries
+        assert np.isfinite(mine["trainer.step"]["attrs"]["loss"])
+        assert mine["ps.step"]["attrs"]["step_time"] > 0
+        assert "msg_bytes" in mine["ps.step"]["attrs"]
+    last = max((e for e in rows if e["name"] == "trainer.step"),
+               key=lambda e: e["step"])
+    assert last["attrs"]["loss"] == out["final_loss"]
+
+
+@pytest.mark.parametrize("path", ["loss_fn", "grads"])
+def test_step_spans_on_both_fused_paths(mesh8, path):
+    from pytorch_ps_mpi_tpu import telemetry
+
+    params, data = make_data()
+    opt = SGD(params, mesh=mesh8, lr=0.1, average=True)
+    if path == "loss_fn":
+        step = lambda: opt.step(loss_fn=quad_loss, batch=next(data))
+    else:
+        g = {"w": jnp.ones((8, 4, 2))}
+        step = lambda: opt.step(grads=g)
+    step()
+    rec = telemetry.configure()
+    try:
+        step()
+    finally:
+        telemetry.disable()
+    rows = rec.events()
+    assert [e["name"] for e in rows] == ["ps.prepare", "ps.dispatch",
+                                         "ps.wait", "ps.step"]
+    assert all(e["step"] == 2 for e in rows)  # the optimizer's own count
+    assert [e.get("parent") for e in rows] == ["ps.step"] * 3 + [None]
+
+
+def test_recorder_off_fit_makes_no_annotation_and_no_row(
+        mesh8, monkeypatch, annotations_made):
+    from pytorch_ps_mpi_tpu import telemetry
+
+    telemetry.disable()
+    rows = []
+    monkeypatch.setattr(telemetry.FlightRecorder, "event",
+                        lambda self, name, **kw: rows.append(name))
+    params, data = make_data()
+    opt = SGD(params, mesh=mesh8, lr=0.1, average=True)
+    Trainer(opt, quad_loss).fit(data, 3)
+    opt.step(loss_fn=quad_loss, batch=next(data))
+    assert annotations_made == [] and rows == []
+    assert telemetry.get_recorder() is None
