@@ -923,6 +923,8 @@ class MPI_PS:
         self.aux_state = None  # mutable model state (e.g. BN batch_stats)
         self._compiled: Dict[Any, Callable] = {}
         self._step_count = 0
+        # loss of the fused step that was launched and not waited for
+        self._in_flight: Optional[jax.Array] = None
         self._payload_bytes_per_leaf = float(sum(
             self.code.payload_bits(
                 _local_shape(p.shape, sp, self.mesh), p.dtype
@@ -1968,6 +1970,25 @@ class MPI_PS:
         ``profile_device_busy``/``profile_compute``/``profile_devices``
         carry the rest of the split. For per-stage encode/decode/update
         walls, use ``instrument=True`` instead.
+
+        **One step in flight** (fused ``loss_fn`` + ``batch`` path). The
+        call launches step n and returns without waiting for it: ``loss``
+        is the ``jax.Array`` the program will fill, usually not ready
+        yet, and ``params`` / ``opt_state`` / ``codec_state`` are assigned
+        at once — whoever reads them (``state_dict``, a checkpoint,
+        ``float(loss)``) waits through JAX as with any jitted function.
+        What the call does wait for is step n-1, so at most one step is
+        queued behind the running one and the host prepares step n+1
+        while the device works. ``data["step_time"]`` is entry to return
+        of the call: in a loop the device's period; the first call after
+        the device has drained reads short (the launch alone).
+        ``data["host_ahead"]`` is 1.0 when step n-1 was still running as
+        the host came to wait for it (the device had its next program
+        queued and never waited for the host), else 0.0.
+        A call that needs THIS step's values on the host still waits for
+        this step: ``profile=True``, a ``numerics`` monitor, a
+        ``closure``; so does the ``grads=`` path, ``instrument=True``,
+        :meth:`step_accumulate` and :meth:`run_steps`.
         """
         t0 = time.perf_counter()
         loss = None
@@ -2067,13 +2088,26 @@ class MPI_PS:
             if closure is not None:
                 loss = closure()
 
-            with span("ps.wait"):
-                jax.block_until_ready(self.params)
+            # Keep one step in flight: wait for the step BEFORE this one
+            # (its loss is the output this step did not donate), unless
+            # the call asked for this step's values on the host.
+            own = (loss_fn is None or profile or self.numerics
+                   or closure is not None)
+            waits_for, self._in_flight = (
+                (self.params, None) if own else (self._in_flight, loss))
+            with span("ps.wait") as waited:
+                # still running as the host arrives: the host kept ahead
+                data["host_ahead"] = float(
+                    not own and waits_for is not None
+                    and not waits_for.is_ready())
+                if waited is not None:
+                    waited["host_ahead"] = data["host_ahead"]
+                jax.block_until_ready(waits_for)
             # The fused program has no separable comm/decode/update stages
-            # — step_time is always a real measurement; profile=True adds
-            # the trace-derived comm/compute split, and instrument=True
-            # (separate mode) fills the remaining per-stage keys with host
-            # wall times.
+            # — step_time is entry to return of this call (see the
+            # docstring); profile=True adds the trace-derived comm/compute
+            # split, and instrument=True (separate mode) fills the
+            # remaining per-stage keys with host wall times.
             data["step_time"] = time.perf_counter() - t0
             self._step_count += 1
             if attrs is not None:

@@ -129,6 +129,16 @@ class Trainer:
         return total / max(1, num_batches)
 
     # -- training -----------------------------------------------------------
+    @staticmethod
+    def _fetch_loss(step: int, loss: jax.Array) -> float:
+        """``float(loss)`` of step ``step``: the row of the span that
+        waits for the value is the one that carries it."""
+        with span("trainer.loss_fetch", step=step) as attrs:
+            value = float(loss)
+            if attrs is not None:
+                attrs["loss"] = value
+        return value
+
     def fit(
         self,
         batches: Iterator[PyTree],
@@ -136,9 +146,21 @@ class Trainer:
         log_every: int = 0,
     ) -> Dict[str, float]:
         """Train for ``num_steps`` batches; returns mean metrics (the
-        reference's returned-timings contract, aggregated)."""
+        reference's returned-timings contract, aggregated).
+
+        The per-step loop keeps one step in flight (see
+        :meth:`MPI_PS.step`): ``opt.step`` launches step n and waits for
+        step n-1, whose loss is then fetched while the device runs step
+        n. The loop drains at return: ``final_loss`` is the LAST step's
+        loss, a float, so a call is that many completed steps.
+        ``log_every`` prints the loss of the step it names, one wait for
+        the device every ``log_every`` steps; a checkpoint waits through
+        ``state_dict``. The mean of ``host_ahead`` among the returned
+        metrics is the share of steps that found the device still busy
+        when the next program was already queued."""
         t0 = time.perf_counter()
         last_loss = None
+        launched = None  # (step, loss) of the step whose loss is not fetched
         done = 0
         while done < num_steps:
             # telemetry.span: does nothing while the recorder is off
@@ -156,19 +178,23 @@ class Trainer:
                     if attrs is not None:
                         attrs["loss"] = last_loss
             else:
-                with span("trainer.step", step=self.step_count + 1) as attrs:
+                with span("trainer.step", step=self.step_count + 1):
                     with span("trainer.data"):
                         batch = next(batches)
                     loss, data = self.opt.step(loss_fn=self.loss_fn,
                                                batch=batch)
-                    with span("trainer.loss_fetch"):
-                        last_loss = float(loss)
+                    # step has waited for the step before: its loss is ready
+                    if launched is not None:
+                        last_loss = self._fetch_loss(*launched)
                     self.metrics.add(data)
                     done += 1
                     self.step_count += 1
-                    if attrs is not None:
-                        attrs["loss"] = last_loss
-            if log_every and done % log_every == 0:
+                    launched = (self.step_count, loss)
+            log_now = log_every and done % log_every == 0
+            if launched is not None and (log_now or done == num_steps):
+                # what the caller asked for now: wait for this step
+                last_loss, launched = self._fetch_loss(*launched), None
+            if log_now:
                 rate = done / (time.perf_counter() - t0)
                 print(f"step {self.step_count}: loss={last_loss:.4f} "
                       f"({rate:.1f} steps/s)")

@@ -823,3 +823,141 @@ def test_run_steps_composes_with_lowerings(mesh8, mode, codec, kw,
         ),
         a.params, b.params,
     )
+
+
+# -- one step in flight --------------------------------------------------------
+
+def batches_for(n, seed=7):
+    return [batch_for(None, seed=seed + i) for i in range(n)]
+
+
+def assert_bit_equal(a, b):
+    jax.tree.map(lambda x, y: np.testing.assert_array_equal(
+        np.asarray(x), np.asarray(y)), a, b)
+
+
+@pytest.fixture
+def waits(monkeypatch):
+    """What ``MPI_PS.step`` blocked on, in order: ``waits.seen`` holds
+    every argument of ``jax.block_until_ready`` but None, beside whether
+    it was ready already; the functions of ``waits.before`` are called
+    with it ahead of the wait."""
+    real = jax.block_until_ready
+
+    def watched(x):
+        if x is not None:
+            watched.seen.append(
+                (x, all(leaf.is_ready() for leaf in jax.tree.leaves(x))))
+            for hook in watched.before:
+                hook(x)
+        return real(x)
+
+    watched.seen, watched.before = [], []
+    monkeypatch.setattr(jax, "block_until_ready", watched)
+    return watched
+
+
+@pytest.mark.parametrize("donate", [False, True])
+def test_steps_in_flight_bit_identical_to_waiting_after_each(mesh8, donate):
+    """The same program on the same inputs in the same order: N steps
+    that each wait only for the step before give the losses and the
+    state of N steps that each wait for themselves."""
+    batches = batches_for(6)
+
+    def run(wait_after_each):
+        opt = Adam(make_params(), mesh=mesh8, lr=0.01, average=True,
+                   code=get_codec("topk", fraction=0.5),
+                   donate_buffers=donate)
+        losses = []
+        for b in batches:
+            loss, _ = opt.step(loss_fn=quad_loss, batch=b)
+            if wait_after_each:
+                jax.block_until_ready((loss, opt.params, opt.opt_state))
+            losses.append(loss)
+        return ([np.asarray(l) for l in losses], opt.params,
+                tuple(opt.opt_state), opt.codec_state,
+                jax.random.key_data(opt._rng))
+
+    assert_bit_equal(run(False), run(True))
+
+
+def test_step_returns_while_it_runs_and_waits_for_the_step_before(waits):
+    """The program of a step is held inside a host callback: ``step``
+    returns all the same, and the NEXT ``step`` returns only once the
+    held one has finished — it blocks on that step's loss, and the
+    callback is let go the moment it does."""
+    import threading
+
+    from pytorch_ps_mpi_tpu.mesh import make_mesh
+
+    gate = threading.Event()
+
+    def hold(_):
+        assert gate.wait(timeout=60), "nobody waited for the held step"
+        return np.zeros((), np.float32)
+
+    def held_loss(params, batch):
+        loss = quad_loss(params, batch)
+        return loss + jax.pure_callback(
+            hold, jax.ShapeDtypeStruct((), jnp.float32),
+            jax.lax.stop_gradient(loss))
+
+    # two devices: each blocks one of the CPU client's threads in `hold`
+    opt = SGD(make_params(), mesh=make_mesh(devices=jax.devices()[:2]),
+              lr=0.1, average=True)
+    b1, b2 = batches_for(2)
+    waits.before.append(lambda x: gate.set())
+
+    loss1, data1 = opt.step(loss_fn=held_loss, batch=b1)
+    assert not loss1.is_ready() and not gate.is_set()
+    assert waits.seen == [] and data1["host_ahead"] == 0.0
+
+    loss2, data2 = opt.step(loss_fn=held_loss, batch=b2)
+    assert loss1.is_ready()
+    [(waited_for, was_ready)] = waits.seen
+    assert waited_for is loss1 and not was_ready
+    assert data2["host_ahead"] == 1.0  # step 1 still ran: the host led
+
+    plain = SGD(make_params(), mesh=opt.mesh, lr=0.1, average=True)
+    for b, loss in ((b1, loss1), (b2, loss2)):
+        expected, _ = plain.step(loss_fn=quad_loss, batch=b)
+        assert float(loss) == float(expected)
+    assert_bit_equal(opt.params, plain.params)
+
+
+@pytest.mark.parametrize("asks", ["profile", "numerics", "closure", "grads"])
+def test_a_call_that_needs_its_own_step_waits_for_it(mesh8, waits, asks):
+    """``profile=True``, a numerics monitor and a ``closure`` read this
+    step's values on the host, and the ``grads=`` path returns nothing to
+    hold: each waits for its own outputs and leaves nothing in flight."""
+    batch = batch_for(mesh8)
+    opt = SGD(make_params(), mesh=mesh8, lr=0.1, average=True,
+              numerics=asks == "numerics")
+    twin = SGD(make_params(), mesh=mesh8, lr=0.1, average=True)
+    twin.step(loss_fn=quad_loss, batch=batch)
+    opt.step(loss_fn=quad_loss, batch=batch)  # leaves a step in flight
+    expected, _ = twin.step(loss_fn=quad_loss, batch=batch)
+
+    if asks == "grads":
+        g = jax.tree.map(lambda p: jnp.ones((8,) + p.shape), opt.params)
+        loss, data = opt.step(grads=g)
+        assert loss is None
+    elif asks == "closure":
+        seen = []
+        loss, data = opt.step(
+            loss_fn=quad_loss, batch=batch,
+            closure=lambda: seen.append(np.asarray(opt.params["w"])) or 7.0)
+        assert loss == 7.0  # the closure's value, as before
+        np.testing.assert_array_equal(seen[0], np.asarray(twin.params["w"]))
+    else:
+        loss, data = opt.step(loss_fn=quad_loss, batch=batch,
+                              profile=asks == "profile")
+        assert float(loss) == float(expected)
+        if asks == "profile":
+            assert data["profile_devices"] == 8
+        else:
+            assert np.isfinite(data["grad_norm"]) and data["grad_norm"] > 0
+    assert waits.seen[-1][0] is opt.params
+    assert data["host_ahead"] == 0.0 and opt._in_flight is None
+    if asks != "grads":
+        assert_bit_equal(opt.params, twin.params)

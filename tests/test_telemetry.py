@@ -322,10 +322,15 @@ def test_fit_spans_sit_on_the_profilers_host_plane(tmp_path):
     parents = {"trainer.data": "trainer.step", "ps.step": "trainer.step",
                "trainer.loss_fetch": "trainer.step", "ps.prepare": "ps.step",
                "ps.dispatch": "ps.step", "ps.wait": "ps.step"}
+    inside = lambda lo, hi, parent: sum(
+        p[1] <= lo and hi <= p[2] for p in events if p[0] == parent)
+    fetches = sorted(e for e in events if e[0] == "trainer.loss_fetch")
     for name, lo, hi, _ in events:
-        if name in parents:  # inside exactly one event of its parent
-            assert sum(p[1] <= lo and hi <= p[2] for p in events
-                       if p[0] == parents[name]) == 1, name
+        if name in parents and (name, lo) != fetches[-1][:2]:
+            assert inside(lo, hi, parents[name]) == 1, name
+    # the loss of the call's last step is fetched after the loop
+    assert inside(*fetches[-1][1:3], "trainer.step") == 0
+    assert fetches[-1][1] >= steps[-1][2]
 
 
 # -- registry primitives ----------------------------------------------------

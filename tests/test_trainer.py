@@ -250,27 +250,45 @@ def test_fit_records_the_phase_spans_of_every_step(mesh8, annotations_made):
     assert sorted(n for _, n, _ in annotations_made) == sorted(
         e["name"] for e in rows)
     assert sorted(e["name"] for e in rows) == sorted(3 * list(SYNC_SPANS))
-    for e in rows:
-        assert e.get("parent") == SYNC_SPANS[e["name"]], e
     assert sorted({e["step"] for e in rows}) == [3, 4, 5]
-    for step in (3, 4, 5):
-        mine = {e["name"]: e for e in rows if e["step"] == step}
+    by_step = {s: {e["name"]: e for e in rows if e["step"] == s}
+               for s in (3, 4, 5)}
+    end = lambda e: e["ts"] + e["dur"]
+    for step, mine in by_step.items():
         assert set(mine) == set(SYNC_SPANS)
-        for name, parent in SYNC_SPANS.items():  # a parent covers its children
+        fetch = mine.pop("trainer.loss_fetch")
+        for name, e in mine.items():  # a parent covers its children
+            parent = SYNC_SPANS[name]
+            assert e.get("parent") == parent, e
             if parent:
-                c, p = mine[name], mine[parent]
-                assert p["ts"] <= c["ts"]
-                assert c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-9
+                p = mine[parent]
+                assert p["ts"] <= e["ts"] and end(e) <= end(p) + 1e-9
         # the phases of ps.step follow one another
         assert (mine["ps.prepare"]["ts"] <= mine["ps.dispatch"]["ts"]
-                <= mine["ps.wait"]["ts"] <= mine["trainer.loss_fetch"]["ts"])
-        # what a row carried before, it still carries
-        assert np.isfinite(mine["trainer.step"]["attrs"]["loss"])
+                <= mine["ps.wait"]["ts"])
+        # the loss is on the row that fetched it, under the step it is of:
+        # one step late, once the next step is launched and has waited for
+        # this one; the last of the call after the loop, under no step
+        assert np.isfinite(fetch["attrs"]["loss"])
+        assert "loss" not in mine["trainer.step"].get("attrs", {})
+        if step < 5:
+            later = by_step[step + 1]
+            assert fetch.get("parent") == "trainer.step"
+            assert end(later["ps.step"]) <= fetch["ts"]
+            assert end(fetch) <= end(later["trainer.step"]) + 1e-9
+        else:
+            assert fetch.get("parent") is None
+            assert end(mine["trainer.step"]) <= fetch["ts"]
+            assert fetch["attrs"]["loss"] == out["final_loss"]
+        # what a row carried before, it still carries; and who was ahead
         assert mine["ps.step"]["attrs"]["step_time"] > 0
         assert "msg_bytes" in mine["ps.step"]["attrs"]
-    last = max((e for e in rows if e["name"] == "trainer.step"),
-               key=lambda e: e["step"])
-    assert last["attrs"]["loss"] == out["final_loss"]
+        ahead = mine["ps.wait"]["attrs"]["host_ahead"]
+        assert ahead in (0.0, 1.0)
+        assert mine["ps.step"]["attrs"]["host_ahead"] == ahead
+    # the fit before this one drained: its last step was over
+    assert by_step[3]["ps.wait"]["attrs"]["host_ahead"] == 0.0
+    assert 0.0 <= out["host_ahead"] <= 1.0
 
 
 @pytest.mark.parametrize("path", ["loss_fn", "grads"])
@@ -287,7 +305,7 @@ def test_step_spans_on_both_fused_paths(mesh8, path):
     step()
     rec = telemetry.configure()
     try:
-        step()
+        _, out = step()
     finally:
         telemetry.disable()
     rows = rec.events()
@@ -295,6 +313,108 @@ def test_step_spans_on_both_fused_paths(mesh8, path):
                                          "ps.wait", "ps.step"]
     assert all(e["step"] == 2 for e in rows)  # the optimizer's own count
     assert [e.get("parent") for e in rows] == ["ps.step"] * 3 + [None]
+    wait, whole = rows[2]["attrs"], rows[3]["attrs"]
+    assert wait["host_ahead"] == whole["host_ahead"] == out["host_ahead"]
+    assert out["host_ahead"] in ((0.0, 1.0) if path == "loss_fn" else (0.0,))
+
+
+# -- one step in flight --------------------------------------------------------
+
+def waited_losses(mesh8, n):
+    """Losses and end state of ``n`` steps that each wait for themselves."""
+    params, data = make_data()
+    opt = SGD(params, mesh=mesh8, lr=0.05, momentum=0.9, average=True)
+    losses = []
+    for _ in range(n):
+        loss, _ = opt.step(loss_fn=quad_loss, batch=next(data))
+        losses.append(float(loss))
+        jax.block_until_ready(opt.params)
+    return losses, opt
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_fit_returns_the_loss_of_its_last_step(mesh8, n):
+    """``fit`` drains: ``final_loss`` is step n's loss exactly, a float,
+    and the next call goes on along the same trajectory."""
+    losses, twin = waited_losses(mesh8, n + 3)
+    params, data = make_data()
+    t = Trainer(SGD(params, mesh=mesh8, lr=0.05, momentum=0.9, average=True),
+                quad_loss)
+    out = t.fit(data, n)
+    assert type(out["final_loss"]) is float
+    assert out["final_loss"] == losses[n - 1]
+    assert t.fit(data, 3)["final_loss"] == losses[n + 2]
+    assert_trees_equal((t.opt.params, tuple(t.opt.opt_state)),
+                       (twin.params, tuple(twin.opt_state)))
+
+
+def test_checkpoint_taken_mid_fit_holds_that_steps_state(mesh8, tmp_path):
+    """A checkpoint inside ``fit`` is taken with its step in flight: it
+    waits through ``state_dict`` and holds what a loop that waits after
+    every step had at that step."""
+    params, data = make_data()
+    t = Trainer(SGD(params, mesh=mesh8, lr=0.05, momentum=0.9, average=True,
+                    donate_buffers=True),
+                quad_loss, checkpoint_dir=str(tmp_path / "ck"),
+                checkpoint_every=2)
+    del params
+    t.fit(data, 5)
+    _, twin = waited_losses(mesh8, 2)
+    saved = t.ckpt.restore(t._state(), step=2)
+    assert int(saved["trainer_step"]) == saved["step_count"] == 2
+    assert_trees_equal(
+        (saved["params"], tuple(saved["opt_state"]), saved["rng_data"]),
+        (twin.params, tuple(twin.opt_state), twin.state_dict()["rng_data"]))
+
+
+@pytest.mark.parametrize("recorder", ["off", "on"])
+def test_fit_waits_the_same_with_the_recorder_on_and_off(
+        mesh8, monkeypatch, recorder):
+    """The loop is one loop: ``step`` n blocks on step n-1's loss, ``fit``
+    fetches a loss one step late and the last one at return, whether or
+    not a recorder is there to take the rows."""
+    from pytorch_ps_mpi_tpu import telemetry
+
+    params, data = make_data()
+    opt = SGD(params, mesh=mesh8, lr=0.1, average=True)
+    t = Trainer(opt, quad_loss)
+    log, launched = [], []
+    real_wait, real_step = jax.block_until_ready, opt.step
+
+    class Loss:  # stands in for the array `step` returns
+        def __init__(self, step):
+            self.step = step
+
+        def __float__(self):
+            log.append(("fetch", self.step))
+            return float(launched[self.step - 1])
+
+    def wait(x):
+        if x is not None:
+            log.append(("wait", 1 + next(
+                i for i, loss in enumerate(launched) if loss is x)))
+        return real_wait(x)
+
+    def step(**kw):
+        loss, out = real_step(**kw)
+        launched.append(loss)
+        log.append(("returned", len(launched)))
+        return Loss(len(launched)), out
+
+    monkeypatch.setattr(jax, "block_until_ready", wait)
+    monkeypatch.setattr(opt, "step", step)
+    if recorder == "on":
+        telemetry.configure()
+    try:
+        t.fit(data, 2)
+        t.fit(data, 3, log_every=2)
+    finally:
+        telemetry.disable()
+    R, W, F = "returned", "wait", "fetch"  # `step` n returned, ...
+    assert log == [
+        (R, 1), (W, 1), (R, 2), (F, 1), (F, 2),       # fit(2): drained
+        (W, 2), (R, 3), (W, 3), (R, 4), (F, 3), (F, 4),  # log_every: step 4
+        (W, 4), (R, 5), (F, 5)]
 
 
 def test_recorder_off_fit_makes_no_annotation_and_no_row(
