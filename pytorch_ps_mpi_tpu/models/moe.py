@@ -17,6 +17,11 @@ Two execution modes, same parameters:
   weights are stacked on a leading ``[E]`` axis either way — shard them
   ``P(expert_axis)`` host-side (see :func:`moe_param_spec`).
 
+This capacity-drop path is where the expert layer's exchange across
+chips starts from; a configuration of the benchmark uses the dropless
+layer instead (``parallel/dropless.py`` under ``models/sdar_moe.py``:
+the experts one chip holds, no pair dropped, no exchange on one chip).
+
 Load balancing: set ``aux_loss_weight`` and apply with
 ``mutable=["aux_loss"]`` — each MoE layer sows its weighted
 Switch/GShard balance loss (``parallel/ep.load_balance_loss``); add the

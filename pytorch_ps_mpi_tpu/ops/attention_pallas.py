@@ -31,6 +31,25 @@ Design choices:
   (``preferred_element_type``); the p@v contraction runs in the input
   dtype (bf16 on TPU) like standard flash implementations.
 
+- **Masks and head counts.** ``mask`` is ``None``, ``'causal'`` or
+  ``'block_diffusion'`` (the training mask of block-diffusion language
+  models over a doubled sequence: a noised copy at positions
+  ``0..half-1`` and the clean copy at ``half..2*half-1``; with
+  ``beta(i) = (i mod half) // block``, noised sees noised of its own
+  block and clean of earlier blocks, clean sees clean up to its own
+  block, clean never sees noised). All three kernels test a tile's
+  liveness from its corner positions and skip dead tiles; under the
+  block-diffusion mask the index maps also clamp a dead tile onto the
+  nearest live one, so that it costs no DMA either (three quarters of
+  the tiles are dead). ``k``/``v`` may carry fewer heads than ``q``
+  (grouped-query attention): query head ``h`` reads key-value head
+  ``h // (heads // kv_heads)``, and the dk/dv kernel sums over the
+  group. The block-diffusion kernels carry a stable ``name`` each
+  (``flash_bd_fwd`` / ``flash_bd_dq`` / ``flash_bd_dkv``): it is the HLO
+  instruction's name in a device trace. The unmasked and causal
+  kernels keep the name XLA gives them (the calling module's), which
+  the benchmark's accepted readers match.
+
 Off-TPU the kernel runs in Pallas interpret mode (CPU test meshes);
 ``flash_attention`` falls back to a jnp oracle for shapes the tiling
 cannot serve (sequence not a multiple of the minimal sublane tile).
@@ -82,12 +101,110 @@ def _min_block_for(dtype) -> int:
     return 16 if jnp.dtype(dtype).itemsize < 4 else 8
 
 
+
+# ---------------------------------------------------------------------------
+# masks: a static tuple ("none",) | ("causal",) | ("bd", block, half)
+# ---------------------------------------------------------------------------
+
+def _mask_spec(causal: bool, mask: Optional[str], block, half) -> tuple:
+    if mask is None:
+        mask = "causal" if causal else "none"
+    elif causal and mask != "causal":
+        raise ValueError(f"causal=True contradicts mask={mask!r}")
+    if mask in ("none", "causal"):
+        return (mask,)
+    if mask != "block_diffusion":
+        raise ValueError(f"unknown mask={mask!r}: expected None, 'causal' "
+                         "or 'block_diffusion'")
+    if not block or not half or block & (block - 1) or half % block:
+        raise ValueError("mask='block_diffusion' needs block (a power of "
+                         f"two) dividing half; got block={block} half={half}")
+    return ("bd", int(block), int(half))
+
+
+def _tile_live(mask, q_start, k_start, bq, bk):
+    """Does the tile with these corner positions hold an allowed pair?"""
+    if mask[0] == "none":
+        return True
+    causal = k_start <= q_start + bq - 1
+    if mask[0] == "causal":
+        return causal
+    _, block, half = mask
+    q_clean, k_clean = q_start >= half, k_start >= half
+    same = (k_start < q_start + bq) & (q_start < k_start + bk)   # noised/noised
+    earlier = k_start - half < q_start + bq - block              # noised/clean
+    return jnp.where(q_clean, k_clean & causal,
+                     jnp.where(k_clean, earlier, same))
+
+
+def _mask_scores(mask, s, q_start, k_start, bq, bk):
+    """Scores with the disallowed pairs of this tile at ``_MASKED``. Under
+    the block-diffusion mask a tile lies within one half (the tiles divide
+    ``half``), so which rule applies is a scalar of the tile."""
+    if mask[0] == "none":
+        return s
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    if mask[0] == "causal":
+        return jnp.where(cols <= rows, s, _MASKED)
+    _, block, half = mask
+    shift = block.bit_length() - 1
+    q_clean = (q_start >= half).astype(jnp.int32)
+    k_clean = (k_start >= half).astype(jnp.int32)
+    qb = jax.lax.shift_right_logical(rows - q_clean * half, shift)
+    kb = jax.lax.shift_right_logical(cols - k_clean * half, shift)
+    # noised/noised: kb == qb; noised/clean: kb < qb; clean/clean: kb <= qb
+    # (clean/noised tiles are never live)
+    hi = qb - k_clean * (1 - q_clean)
+    lo = qb * ((1 - k_clean) * (1 - q_clean))
+    return jnp.where((kb <= hi) & (kb >= lo), s, _MASKED)
+
+
+def _bd_k_tile(mask, j, kk, bq, bk):
+    """The k tile to fetch for grid step (q tile ``j``, k tile ``kk``):
+    ``kk`` itself where the tile is live, else the nearest live one
+    (a block index that does not change costs no DMA)."""
+    if mask[0] != "bd":
+        return kk
+    _, block, half = mask
+    qs = j * bq
+    noised = qs < half
+    lo1 = jnp.where(noised, qs // bk, -1)
+    hi1 = jnp.where(noised, (qs + bq - 1) // bk, -1)
+    lo2 = half // bk
+    hi2 = jnp.where(noised, (half + qs + bq - block - 1) // bk,
+                    (qs + bq - 1) // bk)
+    return jnp.where(kk <= hi1, jnp.maximum(kk, lo1),
+                     jnp.minimum(jnp.maximum(kk, lo2), jnp.maximum(hi2, lo2)))
+
+
+def _bd_q_tile(mask, jk, jq, bq, bk, nq):
+    """The q tile to fetch for (k tile ``jk``, q tile ``jq``), as above."""
+    if mask[0] != "bd":
+        return jq
+    _, block, half = mask
+    ks = jk * bk
+    noised = ks < half
+    lo1 = jnp.where(noised, ks // bq, (ks - half + block) // bq)
+    hi1 = jnp.where(noised, (ks + bk - 1) // bq, half // bq - 1)
+    lo2 = jnp.where(noised, hi1, ks // bq)
+    hi2 = jnp.where(noised, hi1, nq - 1)
+    return jnp.where(jq <= hi1, jnp.maximum(jq, lo1),
+                     jnp.minimum(jnp.maximum(jq, lo2), hi2))
+
+
+def _kernel_name(mask, which):
+    """``flash_bd_fwd`` / ``flash_bd_dq`` / ``flash_bd_dkv``; the other
+    masks' kernels keep the name XLA gives them (module docstring)."""
+    return f"flash_bd_{which}" if mask[0] == "bd" else None
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc, m_sc, l_sc, *, causal, scale, bq, bk, nk):
+                acc, m_sc, l_sc, *, mask, scale, bq, bk, nk):
     j = pl.program_id(1)
     kk = pl.program_id(2)
 
@@ -99,10 +216,9 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     q_start = qo_ref[0] + j * bq
     k_start = ko_ref[0] + kk * bk
-    # causal: skip tiles that lie entirely in the masked future
-    live = (k_start <= q_start + bq - 1) if causal else True
 
-    @pl.when(live)
+    # skip tiles the mask empties (causal: the future half)
+    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
     def _():
         q = q_ref[0]
         k = k_ref[0]
@@ -110,10 +226,7 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(cols <= rows, s, _MASKED)
+        s = _mask_scores(mask, s, q_start, k_start, bq, bk)
         m_prev = m_sc[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a row with no visible key keeps m == _MASKED; exp(s - m) would
@@ -140,13 +253,18 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         )
 
 
-def _fwd(q3, k3, v3, q_off, k_off, causal, scale, bq, bk):
+def _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk):
     bh, lq, d = q3.shape
     lk = k3.shape[1]
+    group = bh // k3.shape[0]       # query heads per key-value head
     nq, nk = lq // bq, lk // bk
     kern = functools.partial(
-        _fwd_kernel, causal=causal, scale=scale, bq=bq, bk=bk, nk=nk
+        _fwd_kernel, mask=mask, scale=scale, bq=bq, bk=bk, nk=nk
     )
+
+    def kv_map(i, j, kk):
+        return (i // group, _bd_k_tile(mask, j, kk, bq, bk), 0)
+
     return pl.pallas_call(
         kern,
         grid=(bh, nq, nk),
@@ -154,8 +272,8 @@ def _fwd(q3, k3, v3, q_off, k_off, causal, scale, bq, bk):
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
@@ -171,6 +289,7 @@ def _fwd(q3, k3, v3, q_off, k_off, causal, scale, bq, bk):
             pltpu.VMEM((bq, _LANE), jnp.float32),
         ],
         interpret=_interpret(),
+        name=_kernel_name(mask, "fwd"),
     )(q_off, k_off, q3, k3, v3)
 
 
@@ -178,21 +297,18 @@ def _fwd(q3, k3, v3, q_off, k_off, causal, scale, bq, bk):
 # backward
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q, k, lse_tile, q_start, k_start, causal, scale, bq, bk):
+def _recompute_p(q, k, lse_tile, q_start, k_start, mask, scale, bq, bk):
     """p = exp(s - lse) with masked entries exactly zero.
     ``lse_tile`` is a [bq, 1] column (lane 0 of the replicated ride)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
-    if causal:
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(cols <= rows, s, _MASKED)
+    s = _mask_scores(mask, s, q_start, k_start, bq, bk)
     return jnp.where(s > _MASK_THRESH, jnp.exp(s - lse_tile), 0.0)
 
 
 def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   dm_ref, dq_ref, dq_acc, *, causal, scale, bq, bk, nk):
+                   dm_ref, dq_ref, dq_acc, *, mask, scale, bq, bk, nk):
     j = pl.program_id(1)
     kk = pl.program_id(2)
 
@@ -202,12 +318,11 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     q_start = qo_ref[0] + j * bq
     k_start = ko_ref[0] + kk * bk
-    live = (k_start <= q_start + bq - 1) if causal else True
 
-    @pl.when(live)
+    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
     def _():
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p = _recompute_p(q, k, lse_ref[0][:, :1], q_start, k_start, causal,
+        p = _recompute_p(q, k, lse_ref[0][:, :1], q_start, k_start, mask,
                          scale, bq, bk)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -226,23 +341,23 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     dm_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, causal, scale, bq, bk, nq):
+                    *, mask, scale, bq, bk, nq, steps):
     jk = pl.program_id(1)
-    jq = pl.program_id(2)
+    t = pl.program_id(2)       # (query head of the group, q tile), flattened
+    jq = t % nq
 
-    @pl.when(jq == 0)
+    @pl.when(t == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     q_start = qo_ref[0] + jq * bq
     k_start = ko_ref[0] + jk * bk
-    live = (k_start <= q_start + bq - 1) if causal else True
 
-    @pl.when(live)
+    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
     def _():
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p = _recompute_p(q, k, lse_ref[0][:, :1], q_start, k_start, causal,
+        p = _recompute_p(q, k, lse_ref[0][:, :1], q_start, k_start, mask,
                          scale, bq, bk)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -258,16 +373,17 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         ) * scale
 
-    @pl.when(jq == nq - 1)
+    @pl.when(t == steps - 1)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
-         causal, scale, bq, bk):
+         mask, scale, bq, bk):
     bh, lq, d = q3.shape
-    lk = k3.shape[1]
+    bkv, lk = k3.shape[:2]
+    group = bh // bkv
     nq, nk = lq // bq, lk // bk
     # D folds the out-cotangent; the lse-cotangent enters with opposite
     # sign in ds = p * (dp - (D - g_lse)). lse arrives lane-replicated
@@ -278,16 +394,19 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
     dm = jnp.broadcast_to(dm[..., None], (bh, lq, _LANE))
     lse = jnp.broadcast_to(lse[..., None], (bh, lq, _LANE))
 
+    def kv_map(i, j, kk):
+        return (i // group, _bd_k_tile(mask, j, kk, bq, bk), 0)
+
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
+        functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
                           bq=bq, bk=bk, nk=nk),
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bq, _LANE), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bq, _LANE), lambda i, j, kk: (i, j, 0)),
@@ -296,58 +415,66 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_interpret(),
+        name=_kernel_name(mask, "dq"),
     )(q_off, k_off, q3, k3, v3, g_out, lse, dm)
 
+    # one key-value head gathers from the `group` query heads that read
+    # it: the innermost grid axis runs over (head of the group, q tile)
+    def q_map(i, jk, t):
+        return (i * group + t // nq,
+                _bd_q_tile(mask, jk, t % nq, bq, bk, nq), 0)
+
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
-                          bq=bq, bk=bk, nq=nq),
-        grid=(bh, nk, nq),
+        functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
+                          bq=bq, bk=bk, nq=nq, steps=group * nq),
+        grid=(bkv, nk, group * nq),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda i, jk, jq: (i, jq, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, jk, jq: (i, jk, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, jk, jq: (i, jk, 0)),
-            pl.BlockSpec((1, bq, d), lambda i, jk, jq: (i, jq, 0)),
-            pl.BlockSpec((1, bq, _LANE), lambda i, jk, jq: (i, jq, 0)),
-            pl.BlockSpec((1, bq, _LANE), lambda i, jk, jq: (i, jq, 0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bq, _LANE), q_map),
+            pl.BlockSpec((1, bq, _LANE), q_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, jk, jq: (i, jk, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, jk, jq: (i, jk, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lk, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, lk, d), v3.dtype),
+            jax.ShapeDtypeStruct((bkv, lk, d), k3.dtype),
+            jax.ShapeDtypeStruct((bkv, lk, d), v3.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name=_kernel_name(mask, "dkv"),
     )(q_off, k_off, q3, k3, v3, g_out, lse, dm)
     return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
-# custom-vjp core on [bh, l, d] arrays
+# custom-vjp core on [b*heads, l, d] arrays (k, v: [b*kv_heads, l, d])
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash(q3, k3, v3, q_off, k_off, causal, scale, bq, bk):
-    out, lse = _fwd(q3, k3, v3, q_off, k_off, causal, scale, bq, bk)
+def _flash(q3, k3, v3, q_off, k_off, mask, scale, bq, bk):
+    out, lse = _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk)
     return out, lse
 
 
-def _flash_fwd(q3, k3, v3, q_off, k_off, causal, scale, bq, bk):
-    out, lse = _fwd(q3, k3, v3, q_off, k_off, causal, scale, bq, bk)
+def _flash_fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk):
+    out, lse = _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk)
     # residual keeps lane 0 only — every lane is identical, and holding
     # the [bh, lq, LANE] ride through the whole model backward would cost
     # 128x the memory; _bwd re-broadcasts (same pattern as dm)
     return (out, lse), (q3, k3, v3, q_off, k_off, out, lse[..., 0])
 
 
-def _flash_bwd(causal, scale, bq, bk, res, g):
+def _flash_bwd(mask, scale, bq, bk, res, g):
     q3, k3, v3, q_off, k_off, out, lse = res
     g_out, g_lse = g
     # lse is returned lane-replicated [bh, lq, LANE]; the adjoint of that
@@ -355,7 +482,7 @@ def _flash_bwd(causal, scale, bq, bk, res, g):
     # so in practice only that column is nonzero)
     g_lse = g_lse.sum(axis=-1)
     dq, dk, dv = _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
-                      causal, scale, bq, bk)
+                      mask, scale, bq, bk)
     zero_off = np.zeros((1,), jax.dtypes.float0)  # int inputs: no tangent
     return dq, dk, dv, zero_off, zero_off
 
@@ -367,16 +494,37 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # public API on [b, l, h, d] arrays (the models' layout)
 # ---------------------------------------------------------------------------
 
-def _attention_jnp(q, k, v, q_offset, k_offset, causal, scale):
-    """Dense oracle with identical semantics (global-coordinate causal
-    mask, masked-row-safe, returns lse). Differentiable; used as the
-    fallback for untileable shapes and as the test oracle."""
+def allowed_pairs(mask: tuple, rows, cols):
+    """The dense boolean mask [len(rows), len(cols)] of a mask spec over
+    global positions: what the kernels' tile logic must equal."""
+    rows, cols = rows[:, None], cols[None, :]
+    if mask[0] == "none":
+        return jnp.ones((rows.shape[0], cols.shape[1]), bool)
+    if mask[0] == "causal":
+        return cols <= rows
+    _, block, half = mask
+    q_clean, k_clean = rows >= half, cols >= half
+    qb, kb = (rows % half) // block, (cols % half) // block
+    return jnp.where(q_clean, k_clean & (kb <= qb),
+                     jnp.where(k_clean, kb < qb, kb == qb))
+
+
+def _attention_jnp(q, k, v, q_offset, k_offset, mask, scale):
+    """Dense oracle with identical semantics (global-coordinate mask,
+    masked-row-safe, grouped heads, returns lse). Differentiable; used as
+    the fallback for untileable shapes and as the test oracle. ``mask``
+    is a mask spec, or a bool meaning causal."""
+    if isinstance(mask, bool):
+        mask = ("causal",) if mask else ("none",)
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    if causal:
-        rows = q_offset + jnp.arange(q.shape[1])
-        cols = k_offset + jnp.arange(k.shape[1])
-        s = jnp.where(cols[None, :] <= rows[:, None], s, _MASKED)
+    if mask[0] != "none":
+        ok = allowed_pairs(mask, q_offset + jnp.arange(q.shape[1]),
+                           k_offset + jnp.arange(k.shape[1]))
+        s = jnp.where(ok, s, _MASKED)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.where(s > _MASK_THRESH, jnp.exp(s - m), 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -399,44 +547,75 @@ def _default_block_targets(lq: int, lk: int) -> tuple:
     return 128, 128
 
 
+def _bd_block_targets(half: int) -> tuple:
+    """Tiles under the block-diffusion mask. At 2 x 4096 positions, 32
+    query over 4 key-value heads of 128, bf16, on a v5e the three kernels
+    took 30.5 ms forward + backward at 1024x1024, 32.4 at 512x1024, 36.9
+    at 512x512, 37.8 at 1024x512, 47.2 at 256x512 (PERF.md section 6,
+    PR 27): the larger tile wins although fewer of its tiles are dead,
+    because a live tile's cost is dominated by its grid step."""
+    return (1024, 1024) if half >= 1024 else _default_block_targets(half, half)
+
+
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = False,
+    mask: Optional[str] = None,
+    block: Optional[int] = None, half: Optional[int] = None,
     scale: Optional[float] = None,
     q_offset=None, k_offset=None,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
     return_lse: bool = False,
 ):
-    """Tiled attention over ``[batch, seq, heads, head_dim]`` tensors.
+    """Tiled attention over ``q`` ``[batch, seq, heads, head_dim]`` and
+    ``k``/``v`` ``[batch, seq, kv_heads, head_dim]`` (``kv_heads``
+    divides ``heads``; query head ``h`` reads key-value head
+    ``h // (heads // kv_heads)``).
 
-    ``q_offset``/``k_offset`` (int scalars, may be traced) place the q/k
-    blocks in global sequence coordinates for the causal mask — ring
-    attention passes its rotating block offsets here. With
-    ``return_lse=True`` also returns the per-row logsumexp ``[b, h, q]``
-    (differentiable), which is what block-combining needs.
+    ``mask`` is ``None`` (all pairs, or causal with ``causal=True``),
+    ``'causal'`` or ``'block_diffusion'`` with static ``block`` and
+    ``half`` (see the module docstring). ``q_offset``/``k_offset`` (int
+    scalars, may be traced) place the q/k blocks in global sequence
+    coordinates for the causal mask — ring attention passes its rotating
+    block offsets here. With ``return_lse=True`` also returns the per-row
+    logsumexp ``[b, h, q]`` (differentiable), which is what
+    block-combining needs.
     """
     b, lq, h, d = q.shape
-    lk = k.shape[1]
+    lk, kvh = k.shape[1], k.shape[2]
+    if h % kvh or v.shape[2] != kvh:
+        raise ValueError(f"{h} query heads over {kvh} / {v.shape[2]} "
+                         "key / value heads")
+    spec = _mask_spec(causal, mask, block, half)
+    if spec[0] == "bd" and (q_offset is not None or k_offset is not None
+                            or lq != 2 * spec[2] or lk != lq):
+        raise ValueError("mask='block_diffusion' covers the whole doubled "
+                         f"sequence: 2 * half = {2 * spec[2]} positions of "
+                         f"q and k, no offsets; got {lq} and {lk}")
     if scale is None:
         scale = d ** -0.5
     q_offset = jnp.zeros((), jnp.int32) if q_offset is None else q_offset
     k_offset = jnp.zeros((), jnp.int32) if k_offset is None else k_offset
 
     mb = _min_block_for(q.dtype)
-    dbq, dbk = _default_block_targets(lq, lk)
-    bq = _pick_block(lq, block_q if block_q is not None else dbq, mb)
-    bk = _pick_block(lk, block_k if block_k is not None else dbk, mb)
-    if bq is None or bk is None:
-        out, lse = _attention_jnp(q, k, v, q_offset, k_offset, causal, scale)
+    dbq, dbk = (_bd_block_targets(spec[2]) if spec[0] == "bd"
+                else _default_block_targets(lq, lk))
+    # a tile of the block-diffusion mask lies within one half
+    tile_q, tile_k = (spec[2], spec[2]) if spec[0] == "bd" else (lq, lk)
+    bq = _pick_block(tile_q, block_q if block_q is not None else dbq, mb)
+    bk = _pick_block(tile_k, block_k if block_k is not None else dbk, mb)
+    if bq is None or bk is None or (spec[0] == "bd"
+                                    and min(bq, bk) <= spec[1]):
+        out, lse = _attention_jnp(q, k, v, q_offset, k_offset, spec, scale)
         return (out, lse) if return_lse else out
 
     def to3(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], d)
 
     q_off = jnp.broadcast_to(q_offset, (1,)).astype(jnp.int32)
     k_off = jnp.broadcast_to(k_offset, (1,)).astype(jnp.int32)
     out3, lse3 = _flash(to3(q), to3(k), to3(v), q_off, k_off,
-                        causal, float(scale), bq, bk)
+                        spec, float(scale), bq, bk)
     out = out3.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
     if not return_lse:
         return out

@@ -1,0 +1,181 @@
+"""A dropless expert layer for the experts ONE chip holds.
+
+What expert parallelism asks of a layer, without its exchange: the layer
+is told which experts live here (``experts_held=(first, count)``), routes
+every position over ALL experts (the router keeps its published width
+and its experts per token), and computes its own experts' part of the
+result for every (position, expert) pair routed to them. What the absent
+experts would add is left out; the gate weights stay normalised over all
+chosen experts, held or not. Across chips the same function runs on each
+share and the parts add up (``tests/test_sdar_moe.py`` ties the shares to
+the uncut layer); the all-to-all that would carry tokens between chips is
+``parallel/ep.py``'s, and is not here.
+
+The grouped products' work is proportional to the pairs HELD (positions
+x top_k x count / n_experts in expectation), never to positions x
+top_k; the moves' work to the buffer's rows (a stated multiple of that
+expectation) and, for the sum back, to the positions' slots:
+
+1. ``route``: float32 softmax over all experts, top-k, renormalised.
+2. ``dispatch_plan``: the held pairs sorted by expert (two argsorts of
+   the positions x top_k expert ids; no scatter), the first ``capacity``
+   rows of that order being the buffer. ``capacity`` is a stated multiple
+   (``capacity_factor``) of the expected number of held pairs, capped at
+   the worst case (every position choosing ``min(top_k, count)`` held
+   experts). The buffer is shared by the experts, so it overflows only
+   when the TOTAL over the held experts exceeds it; then the layer's
+   output is NaN (the step's loss is non-finite and the caller counts a
+   failed step) — never a silently smaller sum — and the groups handed
+   to the products are cut at the buffer's end. At ``capacity_factor >=
+   n_experts * min(top_k, count) / (top_k * count)`` it cannot overflow.
+3. Grouped matrix products over the sorted rows: ``jax.lax.ragged_dot``
+   with the per-expert group sizes. XLA:TPU lowers it to its own Mosaic
+   kernels (forward, and both transposes for the backward pass) whose
+   work follows the group sizes, not the buffer. Chosen by measurement
+   on a v5e at the SDAR widths (16 experts, 2048 x 768, 16,384 rows;
+   PERF.md section 6, PR 27): one forward product 1.10 ms against 0.84 ms
+   for a tile-aligned Pallas kernel with no row to skip, the whole SwiGLU
+   forward and backward (9 products) 5.75 ms = 41 % of the bf16 peak;
+   the hand-written kernel would need its own transposes, tile-padded
+   groups and a custom VJP to win a quarter of a tenth of the step.
+4. ``combine``: every position sums the rows of its held pairs.
+
+Moving rows is gathers in both directions: ``gather_rows`` (buffer row
+<- position) and ``slot_sum`` (position <- its pairs' rows) are each
+other's transpose, and each one's VJP is the other — a scatter-add never
+runs (at the cell's size the sum back is 8 row gathers of 16,384 rows,
+6.2 ms, and independent of the buffer; a scatter-add of the buffer's
+rows is 3.65 ms at 32,768 rows and grows with them).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+class Plan(NamedTuple):
+    src: jax.Array       # [capacity] position of each buffer row (P: none)
+    pair: jax.Array      # [capacity] flat (position, slot) of each row
+    dest: jax.Array      # [P, top_k] buffer row of each pair (capacity: none)
+    sizes: jax.Array     # [count] pairs per held expert, within the buffer
+    loads: jax.Array     # [count] pairs per held expert, all of them
+    overflow: jax.Array  # [] bool: the held pairs exceed the buffer
+
+
+def capacity_rows(positions: int, top_k: int, n_experts: int, count: int,
+                  capacity_factor: float) -> int:
+    """Buffer rows for ``count`` of ``n_experts`` experts held here."""
+    expected = positions * top_k * count / n_experts
+    worst = positions * min(top_k, count)
+    return min(worst, 8 * math.ceil(capacity_factor * expected / 8))
+
+
+def route(x, w_router, top_k: int, norm_topk_prob: bool = True):
+    """``x [P, d]`` -> gate weights ``[P, top_k]`` float32 and expert ids
+    ``[P, top_k]``. The router runs in float32 at full precision: a bf16
+    product moves which expert is 8th and which 9th."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, top_k)
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return weights, experts.astype(jnp.int32)
+
+
+def dispatch_plan(experts, experts_held: Tuple[int, int],
+                  capacity: int) -> Plan:
+    first, count = experts_held
+    p, k = experts.shape
+    local = experts.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)   # held pairs first
+    rank = jnp.argsort(order).astype(jnp.int32)               # its inverse
+    bounds = jnp.searchsorted(key[order], jnp.arange(count + 1, dtype=key.dtype))
+    total = bounds[count]
+    rows = jnp.arange(capacity, dtype=jnp.int32)
+    pair = jnp.where(rows < total, order[:capacity], p * k)
+    dest = jnp.where(rank < jnp.minimum(total, capacity), rank, capacity)
+    # under an overflow the groups are cut at the buffer's end (a grouped
+    # product must not read past it); the layer's output is NaN anyway
+    return Plan(src=pair // k, pair=pair, dest=dest.reshape(p, k),
+                sizes=jnp.diff(jnp.minimum(bounds, capacity)).astype(jnp.int32),
+                loads=jnp.diff(bounds).astype(jnp.int32),
+                overflow=total > capacity)
+
+
+def _gather(x, idx):
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _slot_sum(y, slots):
+    out = _gather(y, slots[:, 0])
+    for s in range(1, slots.shape[1]):
+        out = out + _gather(y, slots[:, s])
+    return out
+
+
+@jax.custom_vjp
+def gather_rows(x, idx, back):
+    """``out[r] = x[idx[r]]`` (zero where ``idx[r] == len(x)``). ``back
+    [len(x), S]`` lists for every row of ``x`` the rows of ``out`` that
+    read it (``len(out)`` for none): the transpose as gathers."""
+    return _gather(x, idx)
+
+
+gather_rows.defvjp(lambda x, idx, back: (_gather(x, idx), (idx, back)),
+                   lambda res, g: (_slot_sum(g, res[1]), None, None))
+
+
+@jax.custom_vjp
+def slot_sum(y, slots, back):
+    """``out[p] = sum_s y[slots[p, s]]`` (zero where ``slots[p, s] ==
+    len(y)``); ``back [len(y)]`` is the row of ``out`` each row of ``y``
+    is summed into."""
+    return _slot_sum(y, slots)
+
+
+slot_sum.defvjp(lambda y, slots, back: (_slot_sum(y, slots), (slots, back)),
+                lambda res, g: (_gather(g, res[1]), None, None))
+
+
+def swiglu_experts(xs, sizes, gate_proj, up_proj, down_proj):
+    """``down(silu(gate(x)) * up(x))`` of each sorted row under its own
+    expert's matrices ``[count, d, f]``, ``[count, d, f]``, ``[count, f,
+    d]``: three grouped products forward, six backward."""
+    with jax.named_scope("moe.experts"):
+        gate = jax.lax.ragged_dot(xs, gate_proj, sizes)
+        up = jax.lax.ragged_dot(xs, up_proj, sizes)
+        return jax.lax.ragged_dot(jax.nn.silu(gate) * up, down_proj, sizes)
+
+
+def dropless_moe(x, w_router, gate_proj, up_proj, down_proj, *,
+                 top_k: int, experts_held: Tuple[int, int],
+                 capacity_factor: float, norm_topk_prob: bool = True):
+    """``x [P, d]`` -> (this share's part of the layer ``[P, d]``, pairs
+    per held expert ``[count]``). The expert matrices are the held ones,
+    in the compute dtype; ``w_router [d, n_experts]`` is whole."""
+    p, _ = x.shape
+    n_experts = w_router.shape[1]
+    first, count = experts_held
+    if gate_proj.shape[0] != count or first + count > n_experts:
+        raise ValueError(f"experts_held={experts_held} against "
+                         f"{gate_proj.shape[0]} expert matrices and a router "
+                         f"over {n_experts}")
+    weights, experts = route(x, w_router, top_k, norm_topk_prob)
+    with jax.named_scope("moe.dispatch"):
+        plan = dispatch_plan(experts, experts_held, capacity_rows(
+            p, top_k, n_experts, count, capacity_factor))
+        xs = gather_rows(x, plan.src, plan.dest)
+        ws = gather_rows(weights.reshape(-1, 1), plan.pair,
+                         plan.dest.reshape(-1, 1))
+    ys = swiglu_experts(xs, plan.sizes, gate_proj, up_proj, down_proj)
+    with jax.named_scope("moe.combine"):
+        y = slot_sum(ys * ws.astype(ys.dtype), plan.dest, plan.src)
+        y = jnp.where(plan.overflow, jnp.asarray(jnp.nan, y.dtype), y)
+    return y, plan.loads

@@ -30,6 +30,15 @@ GShard-style global per-expert budget B means ``capacity = B / n_dev``.
 Overflowing tokens are dropped (output 0 for them — GShard semantics);
 size C generously in tests to compare exactly against the dense oracle.
 
+What a benchmark configuration uses is NOT this path but the dropless
+layer of ``parallel/dropless.py`` (a chip is told which experts it holds,
+routes over all of them, computes its own experts' part for every pair
+routed to them through grouped products, and never drops one); it runs
+on one chip without its exchange. This capacity-drop Switch/GShard path
+is the starting point of that exchange across four chips (its
+``all_to_all`` pair is the only collective either needs), and has never
+run on the chip.
+
 Like ``parallel/pp.py``: wrap in a vma-checked ``shard_map`` (the default
 ``check_vma=True``) when differentiating, so the collective transposes
 are exact; shard tokens over the expert axis (or jointly over data ×

@@ -1779,7 +1779,8 @@ class MPI_PS:
                 self.params, self.opt_state, self.codec_state, batch, rng,
                 *extra
             ).compile()
-        ma = self._compiled[ma_key].memory_analysis()
+        self._analysed = self._compiled[ma_key]
+        ma = self._analysed.memory_analysis()
         out = {
             k: int(getattr(ma, k))
             for k in ("argument_size_in_bytes", "output_size_in_bytes",
@@ -1794,6 +1795,16 @@ class MPI_PS:
                 + out["temp_size_in_bytes"] - out.get("alias_size_in_bytes", 0)
             )
         return out
+
+    def step_program_text(self) -> Optional[str]:
+        """The optimized HLO text of the step program
+        ``step_memory_analysis`` last compiled (None before its first
+        call). A device trace names an operation by its instruction and
+        nothing else; this text gives every instruction's ``op_name``,
+        which carries the ``jax.named_scope`` it was traced under —
+        the way from a trace event to a scope of the model."""
+        compiled = getattr(self, "_analysed", None)
+        return None if compiled is None else compiled.as_text()
 
     def step_accumulate(
         self, loss_fn: Callable, microbatches: PyTree, *,

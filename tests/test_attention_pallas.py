@@ -274,3 +274,100 @@ def test_flash_auto_ok_false_off_tpu():
     assert not flash_auto_ok(512, 512, jnp.bfloat16)
     assert not flash_auto_ok(2048, 2048, jnp.bfloat16)
     assert not flash_auto_ok(8192, 8192, jnp.float32)
+
+
+# -- masks beyond causal, and grouped heads (PR 27) ---------------------------
+
+def grouped_qkv(l, heads, kv_heads, d=16, b=2, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (b, l, heads, d)),
+            jax.random.normal(ks[1], (b, l, kv_heads, d)),
+            jax.random.normal(ks[2], (b, l, kv_heads, d)),
+            jax.random.normal(ks[3], (b, l, heads, d)))
+
+
+def dense_block_diffusion(q, k, v, half, block):
+    """The dense mask written out position by position, softmax and
+    all: nothing of the kernel module but its layout."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    ok = np.zeros((2 * half, 2 * half), bool)
+    for i in range(2 * half):
+        for j in range(2 * half):
+            bi, bj = (i % half) // block, (j % half) // block
+            if i < half:
+                ok[i, j] = (bi == bj) if j < half else (bj < bi)
+            else:
+                ok[i, j] = j >= half and bj <= bi
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where(ok[None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("half, block, heads, kv_heads, bq, bk", [
+    (32, 4, 8, 1, 8, 16),      # 32-over-4-style grouping: 8 query heads a kv head
+    (32, 4, 4, 2, 16, 8),
+    (64, 8, 2, 2, 16, 64),     # equal head counts, one k tile a half
+    (64, 4, 4, 2, 32, 16),
+])
+def test_block_diffusion_kernels_match_dense(half, block, heads, kv_heads,
+                                             bq, bk):
+    """Forward, dq and dk/dv kernels under the block-diffusion mask, with
+    grouped heads, tile skipping and the dead tiles' index clamp, against
+    a dense mask built position by position."""
+    q, k, v, w = grouped_qkv(2 * half, heads, kv_heads)
+
+    def kernel(q, k, v):
+        return jnp.sum(w * flash_attention(
+            q, k, v, mask="block_diffusion", block=block, half=half,
+            block_q=bq, block_k=bk))
+
+    def dense(q, k, v):
+        return jnp.sum(w * dense_block_diffusion(q, k, v, half, block))
+
+    got, got_grads = jax.value_and_grad(kernel, (0, 1, 2))(q, k, v)
+    want, want_grads = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, wnt in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(wnt),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_block_diffusion_allows_a_quarter_of_the_pairs():
+    from pytorch_ps_mpi_tpu.ops.attention_pallas import allowed_pairs
+
+    half, block = 64, 4
+    pos = jnp.arange(2 * half)
+    ok = allowed_pairs(("bd", block, half), pos, pos)
+    assert int(ok.sum()) == half * half + half * block
+    assert not bool(ok[half:, :half].any())      # clean never sees noised
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_grouped_heads_under_the_old_masks(causal):
+    q, k, v, w = grouped_qkv(64, 6, 2)
+    sc = q.shape[-1] ** -0.5
+
+    def kernel(q, k, v):
+        return jnp.sum(w * flash_attention(q, k, v, causal=causal,
+                                           block_q=16, block_k=32))
+
+    def dense(q, k, v):
+        return jnp.sum(w * _attention_jnp(q, k, v, 0, 0, causal, sc)[0])
+
+    got, want = (jax.grad(f, (0, 1, 2))(q, k, v) for f in (kernel, dense))
+    for g, wnt in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(wnt),
+                                   rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("kw, match", [
+    (dict(mask="block_diffusion", block=3, half=32), "power of"),
+    (dict(mask="block_diffusion", block=4, half=16), "whole doubled"),
+    (dict(mask="window"), "unknown mask"),
+    (dict(mask="block_diffusion", block=4, half=32, causal=True), "contradicts"),
+])
+def test_mask_arguments_are_checked(kw, match):
+    q, k, v, _ = grouped_qkv(64, 2, 2)
+    with pytest.raises(ValueError, match=match):
+        flash_attention(q, k, v, **kw)

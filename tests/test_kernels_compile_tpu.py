@@ -106,6 +106,43 @@ def test_flash_forward_and_backward_compile(v5e, seq, dtype, d, causal):
     assert text.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("half, heads, kv_heads, d", [
+    (4096, 32, 4, 128),    # sdar-30b-a3b.bd4k: 8,192 positions, 512x1024 tiles
+    (512, 4, 2, 128),      # one q tile and one k tile a half
+])
+def test_block_diffusion_kernels_compile(v5e, half, heads, kv_heads, d):
+    """The three masked kernels with grouped heads at the cell's widths,
+    each under its own name."""
+    def loss(q, k, v):
+        out = attention_pallas.flash_attention(
+            q, k, v, mask="block_diffusion", block=4, half=half)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = compile_for(v5e, jax.grad(loss, (0, 1, 2)),
+                       ((1, 2 * half, heads, d), jnp.bfloat16),
+                       ((1, 2 * half, kv_heads, d), jnp.bfloat16),
+                       ((1, 2 * half, kv_heads, d), jnp.bfloat16))
+    for name in ("flash_bd_fwd", "flash_bd_dq", "flash_bd_dkv"):
+        assert name in text, name
+
+
+def test_grouped_products_compile_to_kernels(v5e):
+    """``jax.lax.ragged_dot`` forward and both transposes at the cell's
+    expert widths: XLA:TPU's own Mosaic kernels, no dense fallback."""
+    from pytorch_ps_mpi_tpu.parallel import dropless
+
+    def loss(x, gate, up, down, sizes):
+        return jnp.sum(dropless.swiglu_experts(
+            x, sizes, gate, up, down).astype(jnp.float32))
+
+    text = compile_for(v5e, jax.grad(loss, (0, 1, 2, 3)),
+                       ((4096, 2048), jnp.bfloat16),
+                       ((16, 2048, 768), jnp.bfloat16),
+                       ((16, 2048, 768), jnp.bfloat16),
+                       ((16, 768, 2048), jnp.bfloat16), ((16,), jnp.int32))
+    assert text.count("%ragged-dot") >= 9      # 3 matrices x 3 passes
+
+
 def test_vmem_overflow_is_a_compile_error(v5e):
     """Negative control: the compiler really runs — tiles that cannot
     fit VMEM fail here instead of compiling to something else."""
