@@ -659,6 +659,13 @@ def phase_multichip(dry_run: bool) -> dict:
     config, batch, steps = (("mlp_mnist", 64, 3) if dry_run
                             else ("bert_mlm", 64, 4))
     shard_rows = []
+    t0 = time.perf_counter()
+
+    def note(done: str) -> None:
+        # the report is one line at the very end, four compiles of a
+        # BERT step away: a run cut before it still says how far it got
+        print(f"chip_smoke: multichip: {done} at "
+              f"{time.perf_counter() - t0:.0f} s", file=sys.stderr, flush=True)
 
     def place(b):
         b = jax.device_put(b, NamedSharding(mesh4, jax.sharding.PartitionSpec(
@@ -673,6 +680,7 @@ def phase_multichip(dry_run: bool) -> dict:
                       mesh=make_mesh(devices=devices[:1])).rows
     check_losses(one, None)
     out["one_chip"] = one
+    note("one chip")
     for mode in ("allgather", "leader"):
         job = trainer_job(config, batch, steps, mesh=mesh4, mode=mode,
                           place=place)
@@ -710,6 +718,7 @@ def phase_multichip(dry_run: bool) -> dict:
         out[mode] = rows
         shard_rows.clear()
         del job, opt
+        note(mode)
 
     # one int8 step: payloads travel by all_gather
     from examples.train import build

@@ -920,6 +920,7 @@ class MPI_PS:
         self._rng = jax.random.key(seed)
         self.codec_state = self._init_codec_state()
         self._codec_spec = self._codec_state_spec()
+        self._place_state()
         self.aux_state = None  # mutable model state (e.g. BN batch_stats)
         self._compiled: Dict[Any, Callable] = {}
         self._step_count = 0
@@ -1393,7 +1394,61 @@ class MPI_PS:
 
         return type(self.opt_state)(*[field_spec(v) for v in self.opt_state])
 
+    def _place_state(self) -> None:
+        """Put params, optimizer state and codec state on the mesh with the
+        shardings every step program returns them in, so that the first
+        step is the program of every later one. Left as the caller made
+        them (on one device, or on the host) they trace the step with
+        another input type than its own outputs have, and the step
+        compiled twice: ~30 s each for BERT-base on one v5e chip, ~72 s
+        each over four (my chip runs, PRs 22 and 28). An array that
+        already lies so is re-used, not copied. A mesh that spans
+        processes is left alone: there each process hands jit its own
+        host-local copy."""
+        if self.mesh.is_multi_process:
+            return
+        from jax.sharding import NamedSharding
+
+        def put(tree, specs):
+            shardings = jax.tree.map(
+                lambda sp: NamedSharding(self.mesh, sp), specs,
+                is_leaf=lambda x: isinstance(x, P))
+            return jax.device_put(tree, shardings)
+
+        self.params = put(
+            self.params, self.param_specs if self._model_parallel else P())
+        self.opt_state = put(self.opt_state, self._opt_state_spec())
+        self.codec_state = put(self.codec_state, self._codec_spec)
+
     # -- compiled step builders -------------------------------------------
+    def _jit_spmd(self, fn, in_specs, out_specs, donate: bool = False):
+        """``jax.jit(jax.shard_map(fn))`` over this optimizer's mesh: the
+        ONE place a step program (fused, accumulating, grads-only,
+        scanned, or an instrumented stage) is handed to the compiler, so
+        that every path gets the same ``compiler_options`` — those of
+        ``comms.async_allreduce_options`` on more than one TPU, none
+        anywhere else (the program and its compile-cache entry are then
+        what they were without this helper), and none where the exchange
+        is already in flat buckets of this optimizer's own making
+        (``bucket_mb`` > 0): there the options were a loss, the
+        ``bucket_mb`` 16 step of BERT-base on four v5e chips taking
+        62.18 ms with them and 60.06 without (by the wall, my chip runs
+        X3 and R1, PR 28; 48.0 per leaf), so a bucketed program is
+        compiled as it always was. ``.lower(...).compile()``
+        of the result carries the options too, so
+        :meth:`step_memory_analysis` describes the program that runs.
+        ``donate`` donates params / optimizer state / codec state
+        (arguments 0-2) where ``donate_buffers`` is set: the outputs
+        re-use their buffers, cutting peak HBM by one params+opt-state
+        copy (see ``donate_buffers`` in ``__init__``)."""
+        return jax.jit(
+            jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False),
+            donate_argnums=(0, 1, 2) if donate and self.donate_buffers else (),
+            compiler_options=None if self.bucket_mb > 0 else
+            comms.async_allreduce_options(self.mesh, self._agg_axes),
+        )
+
     def _build_instrumented_stages(self, loss_fn, has_aux: bool = False,
                                    accum_steps: int = 0):
         """Pipeline as four separately-dispatched programs so host timers
@@ -1443,12 +1498,8 @@ class MPI_PS:
 
             grad_in, grad_out = (P(), P(axis)), (P(), grads_spec)
 
-        grad_fn = jax.jit(
-            jax.shard_map(
-                grad_spmd, mesh=self.mesh, in_specs=grad_in,
-                out_specs=grad_out, check_vma=False,
-            )
-        ) if loss_fn is not None else None
+        grad_fn = self._jit_spmd(
+            grad_spmd, grad_in, grad_out) if loss_fn is not None else None
 
         def encode_spmd(grads_stacked, codec_state, rng):
             grads = jax.tree.map(lambda x: x[0], grads_stacked)
@@ -1456,14 +1507,9 @@ class MPI_PS:
             return jax.tree.map(lambda x: x[None], payloads), new_state
 
         payload_spec = jax.tree.map(lambda _: P(axis), self._payload_struct())
-        encode_fn = jax.jit(
-            jax.shard_map(
-                encode_spmd, mesh=self.mesh,
-                in_specs=(grads_spec, state_spec, P()),
-                out_specs=(payload_spec, state_spec),
-                check_vma=False,
-            )
-        )
+        encode_fn = self._jit_spmd(
+            encode_spmd, (grads_spec, state_spec, P()),
+            (payload_spec, state_spec))
 
         def gather_spmd(payloads_stacked):
             local = jax.tree.map(lambda x: x[0], payloads_stacked)
@@ -1491,27 +1537,12 @@ class MPI_PS:
             return self._update(params, opt_state, summed)
 
         opt_spec = self._opt_state_spec()
-        update_fn_impl = jax.shard_map(
-            update_spmd, mesh=self.mesh, in_specs=(P(), opt_spec, P()),
-            out_specs=(P(), opt_spec), check_vma=False,
-        )
 
         return {
             "grad": grad_fn,
             "encode": encode_fn,
-            "gather": jax.jit(
-                jax.shard_map(
-                    gather_spmd, mesh=self.mesh,
-                    in_specs=(payload_spec,),
-                    out_specs=P(), check_vma=False,
-                )
-            ),
-            "psum": jax.jit(
-                jax.shard_map(
-                    sum_spmd, mesh=self.mesh, in_specs=(grads_spec,),
-                    out_specs=P(), check_vma=False,
-                )
-            ),
+            "gather": self._jit_spmd(gather_spmd, (payload_spec,), P()),
+            "psum": self._jit_spmd(sum_spmd, (grads_spec,), P()),
             "decode": jax.jit(
                 lambda gathered: jax.tree.unflatten(
                     jax.tree.structure(self.params),
@@ -1524,7 +1555,8 @@ class MPI_PS:
                     ],
                 )
             ),
-            "update": jax.jit(update_fn_impl),
+            "update": self._jit_spmd(update_spmd, (P(), opt_spec, P()),
+                                     (P(), opt_spec)),
         }
 
     def _payload_struct(self):
@@ -1689,19 +1721,7 @@ class MPI_PS:
         out_specs = (pspec, opt_spec, state_spec, P(), P()) + (
             (P(),) if self.numerics else ()
         )
-        return jax.jit(
-            jax.shard_map(
-                spmd,
-                mesh=self.mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=False,
-            ),
-            # in-place params/state update on device: the outputs reuse
-            # the donated input buffers, cutting peak HBM by one
-            # params+opt-state copy (see donate_buffers in __init__)
-            donate_argnums=(0, 1, 2) if self.donate_buffers else (),
-        )
+        return self._jit_spmd(spmd, in_specs, out_specs, donate=True)
 
     def _build_accum_grad_step(self, loss_fn, accum_steps: int):
         """Gradient accumulation: each worker scans ``accum_steps``
@@ -1734,16 +1754,9 @@ class MPI_PS:
         out_specs = (pspec, opt_spec, state_spec, P()) + (
             (P(),) if self.numerics else ()
         )
-        return jax.jit(
-            jax.shard_map(
-                spmd,
-                mesh=self.mesh,
-                in_specs=(pspec, opt_spec, state_spec, mb_spec, P()),
-                out_specs=out_specs,
-                check_vma=False,
-            ),
-            donate_argnums=(0, 1, 2) if self.donate_buffers else (),
-        )
+        return self._jit_spmd(
+            spmd, (pspec, opt_spec, state_spec, mb_spec, P()), out_specs,
+            donate=True)
 
     def step_memory_analysis(
         self, loss_fn: Callable, batch: PyTree, rng=None,
@@ -1755,7 +1768,13 @@ class MPI_PS:
         None (XLA:CPU): ``donate_buffers`` shows up as
         ``alias_size_in_bytes`` (outputs re-using argument buffers), so
         ``argument + output + temp - alias`` estimates the step's peak
-        working set either way. Pass ``aux_state`` iff the step does
+        working set either way. Beside it, ``collectives`` and
+        ``async_collectives``: how many collectives the optimized
+        program runs and how many of them are asynchronous
+        (``comms.count_scheduled_collectives``; 0 of 5 in BERT-base over
+        four chips before the overlapping schedule, 51 of 52 with it),
+        with one ``ps.step_program`` row on the FlightRecorder each time
+        they are read. Pass ``aux_state`` iff the step does
         (the loss_fn signature changes with it). NOTE the first call
         per loss_fn pays a full AOT compile — ``jitted.lower()`` does
         not consult the jit dispatch cache — so the compiled object is
@@ -1794,6 +1813,14 @@ class MPI_PS:
                 out["argument_size_in_bytes"] + out["output_size_in_bytes"]
                 + out["temp_size_in_bytes"] - out.get("alias_size_in_bytes", 0)
             )
+        # whether the overlapping schedule engaged: the program's
+        # collectives, and how many of them run beside other work
+        out.update(comms.count_scheduled_collectives(self._analysed.as_text()))
+        rec = get_recorder()
+        if rec is not None:
+            rec.event("ps.step_program",
+                      collectives=out["collectives"],
+                      async_collectives=out["async_collectives"])
         return out
 
     def step_program_text(self) -> Optional[str]:
@@ -1896,16 +1923,9 @@ class MPI_PS:
         out_specs = (P(), opt_spec, state_spec) + (
             (P(),) if self.numerics else ()
         )
-        return jax.jit(
-            jax.shard_map(
-                spmd,
-                mesh=self.mesh,
-                in_specs=(P(), opt_spec, state_spec, grads_spec, P()),
-                out_specs=out_specs,
-                check_vma=False,
-            ),
-            donate_argnums=(0, 1, 2) if self.donate_buffers else (),
-        )
+        return self._jit_spmd(
+            spmd, (P(), opt_spec, state_spec, grads_spec, P()), out_specs,
+            donate=True)
 
     def _schema_dict(self) -> Dict[str, float]:
         """The reference's per-step metrics schema (``ps.py:116-148,
@@ -2200,6 +2220,7 @@ class MPI_PS:
         )
         self.codec_state = self._decommit_restored(sd["codec_state"])
         self.aux_state = self._decommit_restored(sd.get("aux_state"))
+        self._place_state()  # or the next step is compiled again
         self._step_count = int(sd["step_count"])
         # rng too: a restored key committed to the restore sharding would
         # commit every subsequent step's rng arg and poison jit's device
@@ -2250,16 +2271,9 @@ class MPI_PS:
             batch_spec = jax.tree.map(lambda _: step_spec, batches)
             opt_spec = self._opt_state_spec()
             pspec = self.param_specs if self._model_parallel else P()
-            self._compiled[key] = jax.jit(
-                jax.shard_map(
-                    spmd,
-                    mesh=self.mesh,
-                    in_specs=(pspec, opt_spec, state_spec, batch_spec, P()),
-                    out_specs=(pspec, opt_spec, state_spec, P()),
-                    check_vma=False,
-                ),
-                donate_argnums=(0, 1, 2) if self.donate_buffers else (),
-            )
+            self._compiled[key] = self._jit_spmd(
+                spmd, (pspec, opt_spec, state_spec, batch_spec, P()),
+                (pspec, opt_spec, state_spec, P()), donate=True)
         t0 = time.perf_counter()
         self._rng, rng = jax.random.split(self._rng)
         self.params, self.opt_state, self.codec_state, losses = self._compiled[key](

@@ -32,12 +32,18 @@ RAGGED = 1000 * 1024  # rows that no kernel's block size divides
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def v5e_2x2():
     from jax.experimental import topologies
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    dev = topo.devices[0]
+    assert len(topo.devices) == 4, topo.devices
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    dev = v5e_2x2[0]
     assert dev.device_kind == "TPU v5 lite", dev.device_kind
     return dev
 
@@ -153,3 +159,47 @@ def test_vmem_overflow_is_a_compile_error(v5e):
     qkv = ((1, 8192, 2, 128), jnp.float32)
     with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
         compile_for(v5e, fwd, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("chips,asynchronous", [(1, False), (4, True)])
+def test_fused_step_compiles_with_the_overlapping_schedule(v5e_2x2, chips,
+                                                           asynchronous):
+    """The fused step of ``MPI_PS`` for described chips: on four, the
+    TPU compiler accepts every name ``comms.async_allreduce_options``
+    passes (an unknown one is a compile error) and each matrix's
+    all-reduce becomes an asynchronous pair; on one, no option is passed
+    and no collective is left. The optimizer is built on this backend's
+    devices (its state has to live somewhere) and handed the described
+    mesh before its step is traced."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_ps_mpi_tpu import MPI_PS, comms
+    from pytorch_ps_mpi_tpu.mesh import make_mesh
+
+    def loss_fn(p, batch):
+        return jnp.mean((jnp.tanh(batch["x"] @ p["w1"]) @ p["w2"] + p["b"]
+                         - batch["y"]) ** 2)
+
+    params = {"w1": jnp.zeros((512, 1024)), "w2": jnp.zeros((1024, 512)),
+              "b": jnp.zeros((512,))}
+    opt = MPI_PS(params, optim="adam", average=True, lr=1e-3,
+                 mesh=make_mesh(devices=jax.devices()[:chips]))
+    opt.mesh = mesh = make_mesh(devices=v5e_2x2[:chips])
+    assert (comms.async_allreduce_options(mesh, ("data",)) is not None
+            ) == asynchronous
+
+    def on(tree, spec):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    batch = {"x": jnp.zeros((32, 512)), "y": jnp.zeros((32, 512))}
+    text = opt._build_grad_step(loss_fn).lower(
+        on(opt.params, P()), on(opt.opt_state, P()),
+        on(opt.codec_state, P("data")), on(batch, P("data")),
+        on(jax.random.key(0), P())).compile().as_text()
+    counts = comms.count_scheduled_collectives(text)
+    if asynchronous:
+        assert counts["async_collectives"] == 2, counts
+        assert counts["collectives"] == 3, counts
+    else:
+        assert counts == {"collectives": 0, "async_collectives": 0}

@@ -961,3 +961,92 @@ def test_a_call_that_needs_its_own_step_waits_for_it(mesh8, waits, asks):
     assert data["host_ahead"] == 0.0 and opt._in_flight is None
     if asks != "grads":
         assert_bit_equal(opt.params, twin.params)
+
+
+# -- the one place a step program is handed to the compiler (PR 28) ----------
+
+@pytest.mark.parametrize("bucket_mb, want", [
+    (0.0, {"an_option": "true"}),
+    (16.0, None),
+], ids=["per-leaf", "flat-buckets"])
+def test_jit_spmd_hands_jit_what_comms_decides(monkeypatch, mesh8, bucket_mb,
+                                               want):
+    """The decision is ``comms.async_allreduce_options``' alone, on this
+    optimizer's mesh and aggregation axes (None on this backend), and
+    ``jax.jit`` gets it as it is; a program whose exchange is in flat
+    buckets is compiled without, whatever the mesh."""
+    from jax.sharding import PartitionSpec as P
+
+    from pytorch_ps_mpi_tpu import comms
+
+    opt = Adam(make_params(), mesh=mesh8, lr=0.05, bucket_mb=bucket_mb)
+    assert comms.async_allreduce_options(opt.mesh, opt._agg_axes) is None
+    asked, seen, real = [], {}, jax.jit
+
+    def decide(mesh, axes):
+        asked.append((mesh, axes))
+        return {"an_option": "true"}
+
+    def spy(fn, **kw):
+        seen.update(kw)
+        return real(fn)
+
+    monkeypatch.setattr(comms, "async_allreduce_options", decide)
+    monkeypatch.setattr(jax, "jit", spy)
+    opt._jit_spmd(lambda x: x, P(), P())
+    assert asked == ([(opt.mesh, opt._agg_axes)] if want else [])
+    assert seen["compiler_options"] == want
+
+
+@pytest.mark.parametrize("n_devices, kw", [
+    (1, dict(mode="allgather")),
+    (4, dict(mode="allgather")),
+    (4, dict(mode="allgather", code="int8")),
+    (4, dict(mode="leader")),
+], ids=["one-device", "identity", "int8", "leader"])
+def test_the_first_step_is_the_program_of_every_later_one(n_devices, kw):
+    """The state is placed as the step returns it (``_place_state``), so
+    three steps trace and compile the step once: left on one device, or
+    on the host, the first step was a program of its own."""
+    from pytorch_ps_mpi_tpu.mesh import make_mesh
+
+    mesh = make_mesh(devices=jax.devices()[:n_devices])
+    code = get_codec(kw["code"]) if "code" in kw else None
+    opt = Adam(jax.device_get(make_params()), mesh=mesh, lr=0.05,
+               mode=kw["mode"], code=code, average=True)
+    for leaf in jax.tree.leaves((opt.params, opt.opt_state, opt.codec_state)):
+        assert leaf.sharding.mesh == mesh and leaf.committed
+    for seed in (1, 2, 3):
+        opt.step(loss_fn=quad_loss, batch=batch_for(mesh, seed=seed))
+    assert [step._cache_size() for step in opt._compiled.values()] == [1]
+
+
+def test_placing_the_state_copies_nothing_that_lies_there_already():
+    from pytorch_ps_mpi_tpu.mesh import make_mesh
+
+    params = make_params()
+    opt = Adam(params, mesh=make_mesh(devices=jax.devices()[:1]), lr=0.05)
+    for mine, theirs in zip(jax.tree.leaves(opt.params),
+                            jax.tree.leaves(params)):
+        assert (mine.unsafe_buffer_pointer()
+                == theirs.unsafe_buffer_pointer())
+
+
+def test_step_memory_analysis_counts_the_programs_collectives(mesh8):
+    """Beside the memory analysis: the optimized program's collectives
+    and how many of them are asynchronous (none off a TPU), with one
+    recorder row each time they are read."""
+    from pytorch_ps_mpi_tpu import telemetry
+
+    rec = telemetry.configure()
+    try:
+        opt = Adam(make_params(), mesh=mesh8, lr=0.05)
+        batch = batch_for(mesh8)
+        out = opt.step_memory_analysis(quad_loss, batch)
+        rows = [e for e in rec.events() if e["name"] == "ps.step_program"]
+    finally:
+        telemetry.disable()
+    assert out["collectives"] >= 1 and out["async_collectives"] == 0
+    assert len(rows) == 1
+    assert rows[0]["attrs"] == {"collectives": out["collectives"],
+                                "async_collectives": 0}
