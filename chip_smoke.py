@@ -459,7 +459,7 @@ def phase_kernels(dry_run: bool) -> dict:
         verdicts(f"tern[{n}]", tern_ok, x, u, s, tern, unp)
         done.append(f"int8+sign+tern[{n}]")
 
-    # exact top-k at codec_bench's 8M: SMEM count accumulators over 31
+    # exact top-k at 8M elements: SMEM count accumulators over 31
     # passes; the same value multiset as lax.top_k
     n, k = (16384, 160) if dry_run else (1 << 23, (1 << 23) // 100)
     x = jax.random.normal(jax.random.fold_in(key, 7), (n,), jnp.float32)

@@ -409,12 +409,8 @@ def main(argv=None, worker_env=None):
         finally:
             _stop_ps_top(top)
         if args.telemetry_dir:
-            # merged trace + report from the per-process JSONLs (no
-            # device trace on the supervised path: the server process
-            # restarts across phases, so there is no single profiler
-            # session to capture)
-            metrics.update(_export_telemetry(args.telemetry_dir,
-                                             None, None))
+            # merged trace + report from the per-process JSONLs
+            metrics.update(_export_telemetry(args.telemetry_dir))
         print(json.dumps(metrics, default=str))
         return metrics
 
@@ -459,17 +455,6 @@ def main(argv=None, worker_env=None):
             else args.health_port)
         print(f"/health live on port {bound}")
         top = _spawn_ps_top(bound)
-    device_trace_dir = device_t0_wall = None
-    if args.telemetry_dir:
-        # device-side half of the merged timeline: trace the server
-        # process's XLA programs (the jitted decode+update+publish path)
-        # while serve() runs; workers are separate processes — their
-        # host-side story arrives through their JSONLs
-        import time as _time
-
-        device_trace_dir = os.path.join(args.telemetry_dir, "device-trace")
-        jax.profiler.start_trace(device_trace_dir)
-        device_t0_wall = _time.time()
     try:
         procs = [spawn_worker(name, i, cfg, env=worker_env)
                  for i in range(args.workers)]
@@ -483,13 +468,6 @@ def main(argv=None, worker_env=None):
             if rc != 0:
                 raise SystemExit(f"worker exited {rc}")
     finally:
-        if device_trace_dir is not None:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:  # a profiler write error must never
-                # skip the server close / orphan-worker kill below
-                print(f"device trace capture failed: {e}", file=sys.stderr)
-                device_trace_dir = None
         _stop_ps_top(top)
         # server.close() also tears down the /metrics + /health endpoint
         # (PSServerTelemetry.close_metrics_http) — no leaked sockets
@@ -498,9 +476,7 @@ def main(argv=None, worker_env=None):
         join_workers(procs, timeout=5.0)
 
     if args.telemetry_dir:
-        metrics.update(_export_telemetry(
-            args.telemetry_dir, device_trace_dir, device_t0_wall
-        ))
+        metrics.update(_export_telemetry(args.telemetry_dir))
     print(json.dumps(metrics, default=str))
     return metrics
 
@@ -534,9 +510,9 @@ def _parse_fault_plan(spec: str):
     return json.loads(spec)
 
 
-def _export_telemetry(tdir: str, device_trace_dir, device_t0_wall) -> dict:
-    """Merge every process's JSONL (+ the server's device trace) into
-    trace.json, print the per-phase report, return artifact paths.
+def _export_telemetry(tdir: str) -> dict:
+    """Merge every process's JSONL into trace.json, print the per-phase
+    report, return artifact paths.
 
     When lineage files are present (``--trace``), the worker JSONLs are
     first shifted onto the server's clock by the per-worker offsets
@@ -581,7 +557,6 @@ def _export_telemetry(tdir: str, device_trace_dir, device_t0_wall) -> dict:
         hop_rows.extend(load_hop_rows(f))
     trace_path, counts = export_chrome_trace(
         os.path.join(tdir, "trace.json"), events,
-        device_trace_dir=device_trace_dir, device_t0_wall=device_t0_wall,
         lineage_rows=lineage_rows or None, clock_offsets=offsets,
         hop_rows=hop_rows or None,
     )
@@ -602,7 +577,6 @@ def _export_telemetry(tdir: str, device_trace_dir, device_t0_wall) -> dict:
     out = {
         "telemetry_trace": trace_path,
         "telemetry_trace_host_events": counts["host"],
-        "telemetry_trace_device_events": counts["device"],
         "telemetry_files": files,
     }
     if lineage_rows:

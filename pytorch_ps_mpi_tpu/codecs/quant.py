@@ -102,11 +102,8 @@ class _ScaleFoldedInt8(Codec):
 class Int8Codec(_ScaleFoldedInt8):
     """Per-tensor symmetric int8: q = round(g / scale), scale = max|g|/127.
 
-    ``use_pallas`` defaults to False: measured under Mosaic on a v5e
-    (``benchmarks/codec_bench.py``, 8M elems), XLA's fused abs-max +
-    quantize beats the two-pass SMEM Pallas kernel 6× (0.16 ms vs
-    0.96 ms enc+dec) — the kernel's extra HBM pass for the absmax loses
-    to XLA's fusion. The kernel stays available for layout experiments.
+    ``use_pallas`` defaults to False: no chip number; see ``PERF.md``
+    section 7.
     """
 
     # shape-agnostic + stateless: bucketed aggregation quantizes with a

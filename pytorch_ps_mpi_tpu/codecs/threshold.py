@@ -29,13 +29,11 @@ With ``target_fraction`` set, ``tau`` becomes adaptive codec state: a
 multiplicative controller nudges it so the mean kept fraction tracks the
 target (kept > target → raise the bar, and vice versa).
 
-Performance note (measured on TPU v5 lite, ``benchmarks/codec_bench.py``):
-the ``nonzero(size=cap)`` compaction lowers to an n-sized scatter, which
-TPUs execute serially — 67-72 ms at 8M elems, 1.6 s at 132M, orders
-slower than the dense codecs (sign/int8 at ~1 ms or below at 8M). The
-default TPU path therefore compacts with one ``lax.sort`` instead
-(``compaction='sort'``: bitonic, vectorized; see ``__init__``), keeping
-the scatter path for CPUs where it wins. Even so, for on-chip
+Performance note (no chip number; see ``PERF.md`` section 7): the
+``nonzero(size=cap)`` compaction lowers to an n-sized scatter, which
+TPUs execute serially. The default TPU path therefore compacts with one
+``lax.sort`` instead (``compaction='sort'``: bitonic, vectorized; see
+``__init__``), keeping the scatter path for CPUs. Even so, for on-chip
 compression where raggedness is NOT the point, prefer ``topk-approx``
 or ``sign``/``terngrad``; use this codec where the ragged protocol
 itself is (DCN wires with real byte budgets).

@@ -76,9 +76,9 @@ every evaluated row — the ingest throttle is bypassed), and feeds the
 engine. :meth:`Controller.replay` over those persisted TSDB rows
 re-derives the **identical action sequence** — the PR 3 "every decision
 is a recorded, replayable event" discipline, now for actions instead of
-verdicts. Setpoints come calibrated from the committed perf trajectory
-via :func:`telemetry.slo.derive_targets`; explicit
-``cfg["control_kw"]["read_p95_target_ms"]`` wins.
+verdicts. The read-latency setpoint is
+``cfg["control_kw"]["read_p95_target_ms"]`` when given, else
+``telemetry.slo.DEFAULT_TARGETS["read_p95_ms"]``: no file is read.
 
 Every action row carries its **triggering verdict** with a
 monotonically increasing ``id`` and the owning ``rule`` name injected
@@ -138,7 +138,7 @@ CONTROL_KNOBS: Dict[str, Any] = {
     "depth_max": 1024,
     "ring_grow_per_s": 0.5,    # ring ageouts/s above => grow the ring
     "ring_max": 64,
-    "read_p95_target_ms": None,  # None => slo.derive_targets()
+    "read_p95_target_ms": None,  # None => slo.DEFAULT_TARGETS
     # -- structural actions (rule "topo"; cfg["topo_actions"] arms) -----
     "topo_actions": False,       # master switch (mirrors cfg key)
     "replan_max": 1,             # group splits per run (spare wid slots)
@@ -336,11 +336,9 @@ class ControlEngine:
             self.read_p95_target_ms = float(
                 self.knobs["read_p95_target_ms"])
         else:
-            from pytorch_ps_mpi_tpu.telemetry.slo import derive_targets
+            from pytorch_ps_mpi_tpu.telemetry.slo import DEFAULT_TARGETS
 
-            self.read_p95_target_ms = float(
-                derive_targets("benchmarks/results",
-                               "BENCH_r*.json")["read_p95_ms"])
+            self.read_p95_target_ms = float(DEFAULT_TARGETS["read_p95_ms"])
         # structural-action state (rule "topo"): the engine's intended
         # shape — the executors chase it, never the other way round
         self.replans = 0           # tree group splits in force

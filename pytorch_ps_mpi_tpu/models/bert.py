@@ -85,8 +85,7 @@ class SelfAttention(nn.Module):
             # for tests). 'full': on TPU the kernel for sequences of
             # FLASH_MIN_SEQ and up that tile, the dense einsum otherwise
             # (ops/attention_pallas.flash_auto_ok). 'einsum': force the
-            # dense path (the flash-vs-einsum A/B in
-            # benchmarks/bert_bench.py).
+            # dense path.
             from pytorch_ps_mpi_tpu.ops.attention_pallas import (
                 flash_attention,
                 flash_auto_ok,
@@ -105,7 +104,6 @@ class SelfAttention(nn.Module):
             # 'full' takes the kernel from FLASH_MIN_SEQ up, where the
             # O(L^2) score matrix dominates; below it XLA's fused dense
             # attention batches the heads' matmuls on the MXU
-            # (benchmarks/flash_tune.py measures the crossover)
             use_kernel = c.attention == "flash" or (
                 c.attention == "full" and flash_auto_ok(l, l, c.dtype)
             )

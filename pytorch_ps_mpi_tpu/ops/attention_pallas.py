@@ -78,8 +78,8 @@ _MASK_THRESH = -1e29   # "this score was masked" test (real scores are tiny)
 # the kernel. XLA's fused dense attention suits short sequences — its
 # matmuls batch across heads on the MXU while the kernel pays a
 # sequential batch*heads grid — and the kernel takes over where O(L^2)
-# score materialization dominates (benchmarks/flash_tune.py measures the
-# crossover). Overridable for re-measurement on other chip generations
+# score materialization dominates. 512: no chip number; see PERF.md
+# section 7. Overridable for re-measurement on other chip generations
 # (FLASH_MIN_SEQ env var).
 import os as _os
 
@@ -540,8 +540,8 @@ def _default_block_targets(lq: int, lk: int) -> tuple:
     from there up — larger k/v tiles amortize per-grid-step dispatch and
     keep the MXU fed once the score block is MXU-shaped on both dims,
     while below ~1k sequence the grid is too small for tile residency to
-    matter and 128's divisibility into short tails wins
-    (benchmarks/flash_tune.py sweeps the tiles)."""
+    matter and 128's divisibility into short tails wins (no chip
+    number; see ``PERF.md`` section 7)."""
     if max(lq, lk) >= 1024:
         return 512, 1024
     return 128, 128

@@ -29,9 +29,9 @@ of a real multi-process run, via :func:`staleness_probs_from_histogram`;
 a fixed schedule remains available for deterministic tests). The
 *wall-clock* benefit asynchrony exists for — fast workers streaming
 past a straggler — is demonstrated by the multi-process stack with real
-jitted compute in ``parallel/async_train.py`` (measured 2.7× a
-synchronous barrier under a forced straggler,
-``benchmarks/async_bench.py``); the two are tied together by
+jitted compute in ``parallel/async_train.py`` (the chip's number for it
+is the ``resnet18-cifar.async1`` cell of ``BENCHMARK.json``); the two
+are tied together by
 ``tests/test_async_train.py::
 test_inxla_sampled_staleness_matches_shm_arrival_histogram``.
 """

@@ -16,13 +16,13 @@ What changed architecturally (SURVEY §3.1 vs. this file):
   where the backend emits async collectives (TPU/GPU), the compiler
   overlaps them with the remaining backward compute — the TPU-native form
   of the same optimization, with no threads, futures, or GIL reasoning
-  (the races of SURVEY §5.2 are gone by construction). This is measured,
-  not assumed: ``benchmarks/overlap_bench.py`` traces the fused step and
-  reports the comm∩compute timeline fraction
-  (``utils.tracing.profiled_overlap``); on the XLA:CPU test backend the
-  collective thunks are synchronous and the measured overlap is 0.0 —
-  the committed artifact quantifies exactly where the claim does and
-  does not hold.
+  (the races of SURVEY §5.2 are gone by construction). This is measured
+  on the chip, not assumed: a ``chipbench.run --trace 1`` run reads the
+  collectives' time and the part of it no compute hides
+  (``coll.time_ms`` / ``coll.exposed_ms``), and
+  ``step_memory_analysis()["collectives" / "async_collectives"]`` counts
+  them in the compiled program. On the XLA:CPU test backend the collective
+  thunks are synchronous and nothing overlaps.
 - The two-phase size exchange (``prepare``/``Iallgatherv``,
   ``ps.py:140-147``) is compile-time: payload shapes are static.
 - The per-parameter reverse-order receive loop (``ps.py:155-176``)
@@ -869,8 +869,9 @@ class MPI_PS:
         if self._model_parallel and instrument:
             raise NotImplementedError(
                 "instrument=True (the staged host-timed pipeline) is not "
-                "supported with param_specs — use profile=True on the "
-                "fused step for the trace-derived comm/compute split"
+                "supported with param_specs — for the comm/compute split "
+                "of the fused step read a chipbench --trace 1 run's coll.* "
+                "metrics or step_memory_analysis()"
             )
         if mode == "leader":
             # ZeRO-1-style sharded optimizer: each worker owns a 1/world
@@ -1834,8 +1835,7 @@ class MPI_PS:
         return None if compiled is None else compiled.as_text()
 
     def step_accumulate(
-        self, loss_fn: Callable, microbatches: PyTree, *,
-        profile: bool = False,
+        self, loss_fn: Callable, microbatches: PyTree,
     ) -> Tuple[jax.Array, Dict[str, float]]:
         """One optimizer step over ``accum_steps`` microbatches per worker.
         ``microbatches`` leaves are ``[accum_steps, global_batch, ...]``;
@@ -1844,19 +1844,9 @@ class MPI_PS:
         ``instrument=True`` stage-times this path like :meth:`step`: the
         accumulation scan is one fused program (grad stage), timed whole
         with a per-microbatch mean in ``grad_time_per_microbatch``; the
-        encode/comm/decode/update stages get real per-stage walls.
-        ``profile=True`` instead traces the fully-fused program and fills
-        ``comm_wait`` with the real per-device collective time."""
+        encode/comm/decode/update stages get real per-stage walls."""
         accum_steps = int(jax.tree.leaves(microbatches)[0].shape[0])
         if self.instrument:
-            if profile:
-                raise ValueError(
-                    "profile=True and instrument=True are mutually "
-                    "exclusive: instrument runs a staged pipeline (host "
-                    "walls per stage) while profile traces the fused "
-                    "program — construct the optimizer without "
-                    "instrument=True to use profile"
-                )
             t0 = time.perf_counter()
             data = self._schema_dict()
             data["accum_steps"] = float(accum_steps)
@@ -1875,17 +1865,9 @@ class MPI_PS:
         data = self._schema_dict()
         data["accum_steps"] = float(accum_steps)
         self._rng, rng = jax.random.split(self._rng)
-        call = lambda: self._compiled[key](
+        out = self._compiled[key](
             self.params, self.opt_state, self.codec_state, microbatches, rng
         )
-        if profile:
-            out, _ = self._profiled_call(
-                call, data,
-                lowered=lambda: self._compiled[key].lower(
-                    self.params, self.opt_state, self.codec_state,
-                    microbatches, rng).as_text())
-        else:
-            out = call()
         if self.numerics:
             (self.params, self.opt_state, self.codec_state, loss,
              nvec) = out
@@ -1981,7 +1963,6 @@ class MPI_PS:
         batch: Optional[PyTree] = None,
         aux_state: Optional[PyTree] = None,
         closure: Optional[Callable] = None,
-        profile: bool = False,
     ) -> Tuple[Optional[jax.Array], Dict[str, float]]:
         """Run one distributed step; returns ``(loss, data)`` exactly like
         the reference (``ps.py:193`` — its known deviation from the torch
@@ -1993,14 +1974,14 @@ class MPI_PS:
         ``closure`` is accepted for signature parity (``ps.py:110-112``)
         and invoked for its loss value if given.
 
-        ``profile=True`` traces THIS step with ``jax.profiler`` and fills
         ``comm_wait`` (the reference's collective-wait metric,
-        ``ps.py:162``) with the fused program's real per-device mean
-        communication time — the comm/compute split ``instrument=True``
-        cannot measure because it splits the program. Extra keys
-        ``profile_device_busy``/``profile_compute``/``profile_devices``
-        carry the rest of the split. For per-stage encode/decode/update
-        walls, use ``instrument=True`` instead.
+        ``ps.py:162``) reads 0.0 here: the fused program has no stage the
+        host could time. ``instrument=True`` fills it, with the other
+        per-stage walls, by running the stages as separate programs; the
+        fused program's own comm/compute split comes from a device trace
+        (``chipbench.run --trace 1``: ``coll.time_ms`` /
+        ``coll.exposed_ms``) and from
+        ``step_memory_analysis()["collectives" / "async_collectives"]``.
 
         **One step in flight** (fused ``loss_fn`` + ``batch`` path). The
         call launches step n and returns without waiting for it: ``loss``
@@ -2017,8 +1998,7 @@ class MPI_PS:
         the host came to wait for it (the device had its next program
         queued and never waited for the host), else 0.0.
         A call that needs THIS step's values on the host still waits for
-        this step: ``profile=True``, a ``numerics`` monitor, a
-        ``closure``; so does the ``grads=`` path, ``instrument=True``,
+        this step: a ``numerics`` monitor, a ``closure``; so does the ``grads=`` path, ``instrument=True``,
         :meth:`step_accumulate` and :meth:`run_steps`.
         """
         t0 = time.perf_counter()
@@ -2027,14 +2007,6 @@ class MPI_PS:
         if self.instrument:
             data = self._schema_dict()
             self._rng, rng = jax.random.split(self._rng)
-            if profile:
-                raise ValueError(
-                    "profile=True and instrument=True are mutually "
-                    "exclusive: instrument runs a staged pipeline (host "
-                    "walls per stage) while profile traces the fused "
-                    "program — construct the optimizer without "
-                    "instrument=True to use profile"
-                )
             if loss_fn is None and grads is None:
                 raise ValueError("pass grads or loss_fn+batch")
             if loss_fn is not None and batch is None:
@@ -2096,12 +2068,7 @@ class MPI_PS:
                     raise ValueError("pass grads or loss_fn+batch")
                 fn = self._compiled[key]
             with span("ps.dispatch"):
-                if profile:
-                    out, _ = self._profiled_call(
-                        lambda: fn(*args), data,
-                        lowered=lambda: fn.lower(*args).as_text())
-                else:
-                    out = fn(*args)
+                out = fn(*args)
             # the donated buffers die with their last reference: here,
             # while the device runs, and not as this frame is left
             del args
@@ -2122,7 +2089,7 @@ class MPI_PS:
             # Keep one step in flight: wait for the step BEFORE this one
             # (its loss is the output this step did not donate), unless
             # the call asked for this step's values on the host.
-            own = (loss_fn is None or profile or self.numerics
+            own = (loss_fn is None or self.numerics
                    or closure is not None)
             waits_for, self._in_flight = (
                 (self.params, None) if own else (self._in_flight, loss))
@@ -2136,32 +2103,14 @@ class MPI_PS:
                 jax.block_until_ready(waits_for)
             # The fused program has no separable comm/decode/update stages
             # — step_time is entry to return of this call (see the
-            # docstring); profile=True adds the trace-derived comm/compute
-            # split, and instrument=True (separate mode) fills the
-            # remaining per-stage keys with host wall times.
+            # docstring); instrument=True (separate mode) fills the
+            # per-stage keys with host wall times.
             data["step_time"] = time.perf_counter() - t0
             self._step_count += 1
             if attrs is not None:
                 attrs.update((k, v) for k, v in data.items()
                              if isinstance(v, (int, float, str)))
         return loss, data
-
-    def _profiled_call(self, call, data: Dict[str, float], lowered=None):
-        """Run one compiled fused step under the JAX profiler and fill the
-        reference's ``comm_wait`` (``ps.py:162``) with the program's real
-        per-device mean collective time.  ``lowered``
-        (a lazy lowered-text provider) arms the launch-counter fallback
-        for participant counting — ``bucketing.count_collectives`` over
-        the lowered program backstops a trace with no per-lane
-        attribution at all."""
-        from pytorch_ps_mpi_tpu.utils.tracing import profiled_device_split
-
-        out, split = profiled_device_split(call, lowered=lowered)
-        data["comm_wait"] = split["comm_s"]
-        data["profile_device_busy"] = split["device_busy_s"]
-        data["profile_compute"] = split["compute_s"]
-        data["profile_devices"] = float(split["devices"])
-        return out, split
 
     def state_dict(self) -> Dict[str, Any]:
         """Checkpointable state in this repo's schema (params/opt_state/

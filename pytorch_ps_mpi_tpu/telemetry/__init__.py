@@ -3,8 +3,7 @@
 The observability layer the reference never had (its only surface was the
 wall-clock dict every ``step`` returned, ``ps.py:116-148``) and this repo
 previously scattered across per-module shims (``utils/metrics.py``
-timers, ``utils/tracing.py`` profiler wrappers, per-server ``metrics()``
-dicts). One system, three faces:
+timers, per-server ``metrics()`` dicts). One system, three faces:
 
 - :class:`FlightRecorder` — bounded, thread-safe structured event/span
   log (monotonic timestamps, worker id, step, staleness) with JSONL
@@ -15,8 +14,8 @@ dicts). One system, three faces:
   a Prometheus text rendering; :class:`PSServerTelemetry` gives the shm
   and TCP parameter servers one canonical metric schema, and
   :class:`MetricsHTTPServer` serves it at ``/metrics``.
-- :mod:`trace export <.trace_export>` — merges host-side recorder spans
-  with ``jax.profiler`` device traces into one Chrome/Perfetto timeline.
+- :mod:`trace export <.trace_export>` — merges every process's recorder
+  spans into one Chrome/Perfetto timeline.
 - :mod:`diagnosis <.diagnosis>` — the layer that turns the streams into
   ANSWERS: :class:`HealthMonitor` derives per-worker verdicts (EWMA +
   MAD anomaly flags, compute/wire/churn straggler attribution, sync-
@@ -78,8 +77,7 @@ dicts). One system, three faces:
   skew detection; ``tools/ps_top.py --fleet`` renders it live.
 
 ``tools/telemetry_report.py`` turns a recorded JSONL into the per-phase
-summary table; ``make telemetry-smoke`` bounds the enabled-recorder
-overhead against the disabled path; ``make obs-smoke`` gates the
+summary table; ``make obs-smoke`` gates the
 observability plane end-to-end.
 """
 
@@ -188,10 +186,7 @@ from pytorch_ps_mpi_tpu.telemetry.profiler import (
     merge_profiles,
     top_frames,
 )
-from pytorch_ps_mpi_tpu.telemetry.slo import (
-    SLOWatchdog,
-    derive_targets,
-)
+from pytorch_ps_mpi_tpu.telemetry.slo import SLOWatchdog
 from pytorch_ps_mpi_tpu.telemetry.fleet import (
     FleetMonitor,
     deregister_endpoint,
@@ -264,7 +259,6 @@ __all__ = [
     "merge_profiles",
     "top_frames",
     "SLOWatchdog",
-    "derive_targets",
     "FleetMonitor",
     "deregister_endpoint",
     "parse_prometheus_text",

@@ -25,21 +25,13 @@ verdict sequence from the persisted ``timeseries-*.jsonl`` rows):
   ``ps_slo_burn_rate{rule=...}`` gauge and ``ps_slo_breaches_total``
   scrape instruments.
 
-Targets come from the committed perf trajectory when one exists:
-:func:`derive_targets` reads ``bench_gate``-style
-``benchmarks/results/*.jsonl`` rows and ``BENCH_r*.json`` round records
-and sets each target at ``median × slack`` — the SLO is "don't regress
-past what this repo has measured", the same contract ``bench_gate``
-enforces offline, now evaluated live. Explicit
-``cfg["slo_kw"]["targets"]`` always wins; :data:`DEFAULT_TARGETS` backs
-everything else.
+Targets are :data:`DEFAULT_TARGETS` unless ``cfg["slo_kw"]["targets"]``
+names one: no file is read for them.
 """
 
 from __future__ import annotations
 
-import glob
 import json
-import math
 import os
 import time
 from typing import Any, Dict, List, Optional
@@ -52,12 +44,11 @@ SLO_KNOBS: Dict[str, Any] = {
     "burn_threshold": 1.0,   # burn > this on BOTH windows => breach
     "recovery_factor": 0.9,  # both windows under thr*this => recover
     "min_samples": 4,        # window warmup before a rule can breach
-    "slack": 2.0,            # derive_targets: target = median * slack
     "targets": {},           # explicit {key: target} overrides
     "rules": None,           # full rule-list override
 }
 
-#: fallback targets when no measured trajectory covers a key — generous
+#: targets for every key ``cfg["slo_kw"]["targets"]`` leaves out — generous
 #: by design: an SLO that false-positives on a healthy laptop run is
 #: worse than one that only catches real regressions
 DEFAULT_TARGETS: Dict[str, float] = {
@@ -79,76 +70,8 @@ DEFAULT_TARGETS: Dict[str, float] = {
     "hop_busy_frac": 0.95,
 }
 
-#: map a measured artifact field -> the SLO target key it calibrates
-_ARTIFACT_FIELDS: Dict[str, str] = {
-    "e2e_ms_p95": "push_e2e_p95_ms",
-    "push_e2e_p95_ms": "push_e2e_p95_ms",
-    "read_p95_ms": "read_p95_ms",
-}
-
-
 def slo_path(slo_dir: str, name: str) -> str:
     return os.path.join(slo_dir, f"slo-{name}.jsonl")
-
-
-def _median(xs: List[float]) -> float:
-    s = sorted(xs)
-    n = len(s)
-    return s[n // 2] if n % 2 else 0.5 * (s[n // 2 - 1] + s[n // 2])
-
-
-def derive_targets(results_dir: Optional[str] = None,
-                   bench_glob: Optional[str] = None,
-                   slack: float = 2.0) -> Dict[str, float]:
-    """Targets from the committed perf trajectory: scan bench_gate-style
-    JSONL rows (``benchmarks/results/*.jsonl``) and ``BENCH_r*.json``
-    round records for the fields in :data:`_ARTIFACT_FIELDS`; each
-    covered key's target is ``median(measured) × slack``. Keys with no
-    measured history keep :data:`DEFAULT_TARGETS`. Unreadable files are
-    skipped — a corrupt artifact must never unarm the watchdog."""
-    seen: Dict[str, List[float]] = {}
-
-    def _take(obj: Any) -> None:
-        if not isinstance(obj, dict):
-            return
-        for field, key in _ARTIFACT_FIELDS.items():
-            v = obj.get(field)
-            if isinstance(v, (int, float)) and not isinstance(v, bool) \
-                    and math.isfinite(float(v)) and float(v) > 0:
-                seen.setdefault(key, []).append(float(v))
-
-    paths: List[str] = []
-    if results_dir and os.path.isdir(results_dir):
-        paths.extend(sorted(glob.glob(os.path.join(results_dir,
-                                                   "*.jsonl"))))
-    if bench_glob:
-        paths.extend(sorted(glob.glob(bench_glob)))
-    for p in paths:
-        try:
-            with open(p) as f:
-                text = f.read()
-        except OSError:
-            continue
-        if p.endswith(".jsonl"):
-            for line in text.splitlines():
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    _take(json.loads(line))
-                except ValueError:
-                    continue
-        else:
-            try:
-                doc = json.loads(text)
-            except ValueError:
-                continue
-            _take(doc.get("parsed") if isinstance(doc, dict) else None)
-            _take(doc)
-    out = dict(DEFAULT_TARGETS)
-    for key, vals in seen.items():
-        out[key] = _median(vals) * float(slack)
-    return out
 
 
 def default_rules(targets: Dict[str, float]) -> List[Dict[str, Any]]:

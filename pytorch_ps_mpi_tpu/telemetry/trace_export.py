@@ -1,11 +1,10 @@
-"""Merged host+device Chrome/Perfetto trace export.
+"""Merged multi-process Chrome/Perfetto trace export of the host's story.
 
-Host side: :class:`~.recorder.FlightRecorder` spans/events (from live
-recorders or their JSONL dumps — several processes' files merge into one
-timeline on the ``wall`` clock each record carries). Device side: the
-``*.xplane.pb`` files a ``jax.profiler`` trace directory holds, read
-through :func:`pytorch_ps_mpi_tpu.utils.tracing._iter_hlo_events` — the
-same event source the comm/compute split uses.
+:class:`~.recorder.FlightRecorder` spans/events (from live recorders or
+their JSONL dumps — several processes' files merge into one timeline on
+the ``wall`` clock each record carries). The device's side of a run is
+read in one place, ``chipbench/trace_reduce.py`` over a
+``chipbench.run --trace 1`` capture, and is not merged here.
 
 Clock honesty: host rows are placed by their ``wall`` timestamps (one
 clock across processes, NTP-grade alignment). When gradient lineage is
@@ -13,13 +12,7 @@ armed, worker-process rows are additionally shifted by the per-worker
 clock offsets :func:`~.lineage.clock_offsets_from_rows` fits from the
 frame (send_wall, recv_wall) timestamp pairs — see
 :func:`apply_clock_offsets` — so worker and server spans line up to
-~min-wire-latency accuracy even across hosts with skewed clocks. Device
-ops only carry the profiler's own timebase, so they are placed relative
-to the wall time at which the trace capture started (``device_t0_wall``,
-recorded by the caller at ``start_trace``; defaults to the host
-timeline's start). The alignment is therefore approximate at the ~ms
-level — good for "which step was the device idle in", not for ns-level
-attribution.
+~min-wire-latency accuracy even across hosts with skewed clocks.
 
 Cross-process causality: pass ``lineage_rows`` (the ``publish``/``drop``
 rows of a ``lineage-*.jsonl``) and every composed push whose worker
@@ -38,7 +31,6 @@ import json
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 HOST_PID = 1
-DEVICE_PID_BASE = 1000
 
 
 def apply_clock_offsets(
@@ -157,46 +149,14 @@ def _flow_events(
     return out
 
 
-def _device_events(
-    trace_dir: str, t0_wall: float, device_t0_wall: Optional[float],
-    host_t0_wall: float,
-) -> List[Dict[str, Any]]:
-    from pytorch_ps_mpi_tpu.utils.tracing import _iter_hlo_events
-
-    raw = list(_iter_hlo_events(trace_dir))
-    if not raw:
-        return []
-    min_ns = min(start for _, _, start, _ in raw)
-    anchor = device_t0_wall if device_t0_wall is not None else host_t0_wall
-    base_us = (anchor - t0_wall) * 1e6
-    out: List[Dict[str, Any]] = []
-    pids: Dict[Any, int] = {}
-    for dev, name, start_ns, dur_ns in raw:
-        pid = pids.setdefault(dev, DEVICE_PID_BASE + len(pids))
-        out.append({
-            "ph": "X", "name": name, "cat": "device",
-            "pid": pid, "tid": 1,
-            "ts": base_us + (start_ns - min_ns) / 1e3,
-            "dur": dur_ns / 1e3,
-        })
-    for dev, pid in pids.items():
-        out.append({
-            "ph": "M", "name": "process_name", "pid": pid,
-            "args": {"name": f"device {dev} (jax.profiler)"},
-        })
-    return out
-
-
 def merged_trace_events(
     host_events: Iterable[Dict[str, Any]],
-    device_trace_dir: Optional[str] = None,
-    device_t0_wall: Optional[float] = None,
     lineage_rows: Optional[Iterable[Dict[str, Any]]] = None,
     clock_offsets: Optional[Dict[Any, float]] = None,
     freshness_rows: Optional[Iterable[Dict[str, Any]]] = None,
     hop_rows: Optional[Iterable[Dict[str, Any]]] = None,
 ) -> List[Dict[str, Any]]:
-    """FlightRecorder records (+ optional jax trace dir) → Chrome
+    """FlightRecorder records → Chrome
     ``traceEvents`` list, all timestamps relative to the earliest host
     record. ``clock_offsets`` (per-worker, from lineage) are applied to
     worker records first; ``lineage_rows`` add cross-process flow
@@ -210,7 +170,7 @@ def merged_trace_events(
     STEP events, joined by the leaders' lineage hop rows)."""
     host_events = apply_clock_offsets(host_events, clock_offsets)
     walls = [e["wall"] for e in host_events if "wall" in e]
-    t0_wall = min(walls) if walls else (device_t0_wall or 0.0)
+    t0_wall = min(walls) if walls else 0.0
     out, span_index = _host_events(host_events, t0_wall)
     if lineage_rows is not None:
         lineage_rows = list(lineage_rows)
@@ -231,39 +191,30 @@ def merged_trace_events(
         out.extend(hop_trace_events(
             hop_rows, lineage_rows, t0_wall=t0_wall
         ))
-    if device_trace_dir is not None:
-        out.extend(_device_events(
-            device_trace_dir, t0_wall, device_t0_wall, t0_wall
-        ))
     return out
 
 
 def export_chrome_trace(
     path: str,
     host_events: Iterable[Dict[str, Any]],
-    device_trace_dir: Optional[str] = None,
-    device_t0_wall: Optional[float] = None,
     lineage_rows: Optional[Iterable[Dict[str, Any]]] = None,
     clock_offsets: Optional[Dict[Any, float]] = None,
     freshness_rows: Optional[Iterable[Dict[str, Any]]] = None,
     hop_rows: Optional[Iterable[Dict[str, Any]]] = None,
 ) -> Tuple[str, Dict[str, int]]:
     """Write the merged timeline to ``path``; returns ``(path, {"host":
-    n, "device": m, "flow": k, "fresh_flow": j, "hop": h})`` so callers
+    n, "flow": k, "fresh_flow": j, "hop": h})`` so callers
     can assert every side actually landed in the artifact (``flow``
     counts the lineage flow START events — each is half of one
     cross-process arrow; ``fresh_flow`` the read-path publish→edge flow
     starts; ``hop`` the leader-track sub-stage spans)."""
     events = merged_trace_events(
-        host_events, device_trace_dir, device_t0_wall,
-        lineage_rows=lineage_rows, clock_offsets=clock_offsets,
+        host_events, lineage_rows=lineage_rows, clock_offsets=clock_offsets,
         freshness_rows=freshness_rows, hop_rows=hop_rows,
     )
     counts = {
         "host": sum(1 for e in events
                     if e.get("cat") == "host" and e["ph"] != "M"),
-        "device": sum(1 for e in events
-                      if e.get("cat") == "device" and e["ph"] != "M"),
         "flow": sum(1 for e in events if e.get("ph") == "s"
                     and e.get("cat") != "freshness"),
         "fresh_flow": sum(1 for e in events if e.get("ph") == "s"

@@ -1,6 +1,6 @@
 """One placement rule for JAX's persistent compilation cache.
 
-Every process that compiles — the CLIs, ``bench.py``, ``chip_smoke.py``,
+Every process that compiles — the CLIs, ``chipbench``, ``chip_smoke.py``,
 ``__graft_entry__.py`` and the fleet's child processes — calls
 :func:`enable_compilation_cache` before its first jit. The cache
 directory is no program's argument: it is where

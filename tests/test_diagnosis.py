@@ -1,7 +1,6 @@
 """Online health diagnosis: EWMA/MAD anomaly gates, straggler
 attribution (compute vs wire vs churn), sync-round critical-path
-gating, the /health endpoint, ps_top rendering, and the bench_gate
-perf-regression gate."""
+gating, the /health endpoint and ps_top rendering."""
 
 import json
 import os
@@ -334,92 +333,6 @@ def test_ps_top_render_table():
     assert normalize_url("host:91") == "http://host:91/health"
     assert (normalize_url("http://h:91/health")
             == "http://h:91/health")
-
-
-# -- bench_gate -------------------------------------------------------------
-
-def _write_jsonl(path, rows):
-    with open(path, "w") as f:
-        f.writelines(json.dumps(r) + "\n" for r in rows)
-    return str(path)
-
-
-def test_bench_gate_pass_fail_and_direction(tmp_path):
-    from tools.bench_gate import main as gate
-
-    rows = [
-        {"metric": "updates_per_sec", "value": 100.0, "unit": "updates/sec"},
-        {"metric": "updates_per_sec", "value": 110.0, "unit": "updates/sec"},
-        {"metric": "updates_per_sec", "value": 90.0, "unit": "updates/sec"},
-        {"metric": "push_p95_ms", "value": 10.0, "unit": "ms"},
-    ]
-    base = _write_jsonl(tmp_path / "base.jsonl", rows)
-    same = _write_jsonl(tmp_path / "same.jsonl", rows)
-    assert gate([base, same]) == 0  # identical files pass
-
-    doctored = [dict(r) for r in rows]
-    for r in doctored:
-        r["value"] *= 0.8 if r["unit"] == "updates/sec" else 1.2
-    bad = _write_jsonl(tmp_path / "bad.jsonl", doctored)
-    assert gate([base, bad]) == 1  # 20% regression fails (both ways)
-
-    # within tolerance: a 5% wobble is noise, not a regression
-    noisy = [dict(r, value=r["value"] * 1.05) for r in rows
-             if r["unit"] == "ms"]
-    ok = _write_jsonl(tmp_path / "ok.jsonl", rows[:3] + noisy)
-    assert gate([base, ok]) == 0
-
-    # a 20% IMPROVEMENT must not fail the gate
-    better = [dict(r) for r in rows]
-    for r in better:
-        r["value"] *= 1.2 if r["unit"] == "updates/sec" else 0.8
-    good = _write_jsonl(tmp_path / "good.jsonl", better)
-    assert gate([base, good]) == 0
-
-    # unknown direction is SKIPPED (reported), never gated blindly
-    mystery = _write_jsonl(tmp_path / "m1.jsonl",
-                           [{"metric": "blorp", "value": 1.0}])
-    mystery2 = _write_jsonl(tmp_path / "m2.jsonl",
-                            [{"metric": "blorp", "value": 99.0}])
-    assert gate([mystery, mystery2]) == 0
-    # ...unless the spec names it
-    assert gate([mystery, mystery2, "--metric", "blorp:lower:0.1"]) == 1
-
-
-def test_bench_gate_trajectory_and_flat_rows(tmp_path):
-    from tools.bench_gate import main as gate
-
-    path = tmp_path / "smoke.jsonl"
-    _write_jsonl(path, [{"bench": "s", "wall_s": 10.0, "t": 1}])
-    assert gate(["--trajectory", str(path)]) == 0  # single run: pass
-    _write_jsonl(path, [
-        {"bench": "s", "wall_s": 10.0, "t": 1},
-        {"bench": "s", "wall_s": 10.5, "t": 2},
-        {"bench": "s", "wall_s": 25.0, "t": 3},
-    ])
-    assert gate(["--trajectory", str(path),
-                 "--metric", "s.wall_s:lower:0.5"]) == 1
-    # flat numeric fields are gated ONLY when named — even without
-    # --only-listed, the name heuristic must NOT judge a run-row field
-    # whose improve-direction was never declared (a 2.5x wall jump
-    # passes because nothing listed it)
-    assert gate(["--trajectory", str(path)]) == 0
-    assert gate(["--trajectory", str(path), "--only-listed"]) == 0
-
-
-def test_bench_gate_reads_round_records(tmp_path):
-    from tools.bench_gate import main as gate
-
-    rec = {"n": 1, "cmd": "x", "rc": 0,
-           "parsed": {"metric": "resnet_steps_per_sec", "value": 2.0,
-                      "unit": "steps/sec"}}
-    a = tmp_path / "BENCH_a.json"
-    b = tmp_path / "BENCH_b.json"
-    a.write_text(json.dumps(rec))
-    rec2 = dict(rec, parsed=dict(rec["parsed"], value=1.5))
-    b.write_text(json.dumps(rec2))
-    assert gate([str(a), str(a)]) == 0
-    assert gate([str(a), str(b)]) == 1
 
 
 # -- telemetry_report: labeled series --------------------------------------

@@ -30,11 +30,7 @@ from pytorch_ps_mpi_tpu.telemetry.profiler import (
     merge_profiles,
     top_frames,
 )
-from pytorch_ps_mpi_tpu.telemetry.slo import (
-    DEFAULT_TARGETS,
-    SLOWatchdog,
-    derive_targets,
-)
+from pytorch_ps_mpi_tpu.telemetry.slo import SLOWatchdog
 from pytorch_ps_mpi_tpu.telemetry.timeseries import (
     MetricsHistory,
     history_from_rows,
@@ -322,28 +318,6 @@ def test_slo_verdicts_replay_identically(tmp_path):
     with open(tmp_path / "slo-server.jsonl") as f:
         persisted = [json.loads(ln) for ln in f if ln.strip()]
     assert strip(persisted) == strip(live)
-
-
-def test_slo_targets_derived_from_bench_artifacts(tmp_path):
-    results = tmp_path / "results"
-    results.mkdir()
-    with open(results / "trace_smoke.jsonl", "w") as f:
-        for v in (10.0, 20.0, 30.0):
-            f.write(json.dumps({"bench": "trace_smoke",
-                                "e2e_ms_p95": v}) + "\n")
-    with open(tmp_path / "BENCH_r01.json", "w") as f:
-        json.dump({"parsed": {"read_p95_ms": 40.0}}, f)
-    t = derive_targets(results_dir=str(results),
-                       bench_glob=str(tmp_path / "BENCH_r*.json"),
-                       slack=2.0)
-    assert t["push_e2e_p95_ms"] == 40.0  # median(10,20,30) * 2
-    assert t["read_p95_ms"] == 80.0
-    # uncovered keys keep the generous defaults
-    assert t["decodes_per_publish"] == DEFAULT_TARGETS[
-        "decodes_per_publish"]
-    # no artifacts at all -> pure defaults, never a crash
-    assert derive_targets(results_dir=str(tmp_path / "nope")) \
-        == DEFAULT_TARGETS
 
 
 def test_slo_scrape_instruments_and_bad_target():

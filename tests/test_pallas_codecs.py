@@ -4,8 +4,8 @@ selection kernel and the fused sign / terngrad encode paths.
 Interpret mode runs the same kernel logic element-for-element, so
 these tests pin correctness; that the kernels compile for a TPU is
 ``tests/test_kernels_compile_tpu.py``, that they execute there against
-their references is ``chip_smoke.py`` phase (c), and their speed is
-``benchmarks/codec_bench.py``'s to measure on a chip.
+their references is ``chip_smoke.py`` phase (c); their speed has no
+chip number yet (``PERF.md`` section 7).
 """
 
 from __future__ import annotations

@@ -325,8 +325,6 @@ def test_instrumented_step_accumulate_matches_plain(mesh8):
     assert d["accum_steps"] == 2
     assert d["grad_time"] > 0 and d["comm_wait"] > 0 and d["optim_step_time"] > 0
     assert d["grad_time_per_microbatch"] == pytest.approx(d["grad_time"] / 2)
-    with pytest.raises(ValueError):
-        instr.step_accumulate(quad_loss, micro, profile=True)
 
 
 def test_step_accumulate_matches_big_batch(mesh8):
@@ -455,53 +453,6 @@ def test_leader_mode_run_steps(mesh8):
         ),
         a.params, b.params,
     )
-
-
-def test_profile_step_fills_trace_derived_comm_split(mesh8):
-    """profile=True traces the fused step and fills comm_wait with the
-    program's real device collective time: nonzero
-    comm on a psum step, comm + compute == device busy, and the step's
-    numerics are identical to an unprofiled step."""
-    params = {"w": jnp.zeros((512,), jnp.float32)}
-    world = 8
-    grads = {"w": jnp.ones((world, 512), jnp.float32)}
-
-    opt = SGD(params, lr=0.1, mesh=mesh8)
-    _, data = opt.step(grads=grads, profile=True)
-
-    assert data["profile_devices"] == world
-    assert data["comm_wait"] > 0.0, data
-    assert data["profile_device_busy"] >= data["comm_wait"]
-    np.testing.assert_allclose(
-        data["comm_wait"] + data["profile_compute"],
-        data["profile_device_busy"], rtol=1e-6,
-    )
-
-    # numerics identical to the unprofiled path
-    opt2 = SGD(params, lr=0.1, mesh=mesh8)
-    opt2.step(grads=grads)
-    np.testing.assert_allclose(
-        np.asarray(opt.params["w"]), np.asarray(opt2.params["w"])
-    )
-
-
-def test_profile_step_accumulate(mesh8):
-    """step_accumulate(profile=True): the one fused-program path that
-    instrument=True structurally cannot stage-time gets its comm split
-    from the trace instead."""
-    params = {"w": jnp.zeros((64,), jnp.float32)}
-    opt = SGD(params, lr=0.1, mesh=mesh8)
-
-    def loss_fn(p, batch):
-        x, y = batch
-        return jnp.mean((x @ p["w"] - y) ** 2)
-
-    x = jax.random.normal(jax.random.key(0), (2, 16, 64))   # [accum, batch, d]
-    y = jax.random.normal(jax.random.key(1), (2, 16))
-    loss, data = opt.step_accumulate(loss_fn, (x, y), profile=True)
-    assert np.isfinite(float(loss))
-    assert data["comm_wait"] > 0.0
-    assert data["profile_devices"] == 8
 
 
 def test_clip_norm_matches_manual_oracle(mesh8):
@@ -925,9 +876,9 @@ def test_step_returns_while_it_runs_and_waits_for_the_step_before(waits):
     assert_bit_equal(opt.params, plain.params)
 
 
-@pytest.mark.parametrize("asks", ["profile", "numerics", "closure", "grads"])
+@pytest.mark.parametrize("asks", ["numerics", "closure", "grads"])
 def test_a_call_that_needs_its_own_step_waits_for_it(mesh8, waits, asks):
-    """``profile=True``, a numerics monitor and a ``closure`` read this
+    """A numerics monitor and a ``closure`` read this
     step's values on the host, and the ``grads=`` path returns nothing to
     hold: each waits for its own outputs and leaves nothing in flight."""
     batch = batch_for(mesh8)
@@ -950,13 +901,9 @@ def test_a_call_that_needs_its_own_step_waits_for_it(mesh8, waits, asks):
         assert loss == 7.0  # the closure's value, as before
         np.testing.assert_array_equal(seen[0], np.asarray(twin.params["w"]))
     else:
-        loss, data = opt.step(loss_fn=quad_loss, batch=batch,
-                              profile=asks == "profile")
+        loss, data = opt.step(loss_fn=quad_loss, batch=batch)
         assert float(loss) == float(expected)
-        if asks == "profile":
-            assert data["profile_devices"] == 8
-        else:
-            assert np.isfinite(data["grad_norm"]) and data["grad_norm"] > 0
+        assert np.isfinite(data["grad_norm"]) and data["grad_norm"] > 0
     assert waits.seen[-1][0] is opt.params
     assert data["host_ahead"] == 0.0 and opt._in_flight is None
     if asks != "grads":

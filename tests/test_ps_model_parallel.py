@@ -646,24 +646,6 @@ def test_mpips_dp_tp_accumulate_matches_plain_step(mesh_dp_tp):
     assert "model" in str(accum.params["w1"].sharding.spec)
 
 
-def test_mpips_dp_tp_profile_smoke(mesh_dp_tp):
-    """profile=True on the model-parallel fused step: the traced
-    comm/compute split fills the reference schema without breaking the
-    step (instrument=True is the blocked mode, profile is the supported
-    one)."""
-    params, x, y = _tp_setup()
-    opt = MPI_PS(
-        params, optim="sgd", lr=0.1, mesh=mesh_dp_tp, axis_name="data",
-        param_specs=tp.tp_param_spec(params, "model"),
-        batch_spec=P("data"),
-    )
-    opt.step(loss_fn=_tp_loss_fn, batch=(x, y))  # compile first
-    loss, data = opt.step(loss_fn=_tp_loss_fn, batch=(x, y), profile=True)
-    assert jnp.isfinite(loss)
-    assert "profile_device_busy" in data
-    assert data["comm_wait"] >= 0.0
-
-
 def test_mpips_3d_ulysses_equals_ring_twin():
     """The DP x SP x TP composition with the ALL-TO-ALL sequence-
     parallel design (Ulysses) under MPI_PS: both SP designs compute

@@ -399,8 +399,7 @@ def test_chrome_trace_export_merges_processes(tmp_path):
     r2.event("worker.push", step=1)
     events = r1.events() + r2.events()
     path, counts = export_chrome_trace(str(tmp_path / "t.json"), events)
-    assert counts == {"host": 2, "device": 0, "flow": 0, "fresh_flow": 0,
-                      "hop": 0}
+    assert counts == {"host": 2, "flow": 0, "fresh_flow": 0, "hop": 0}
     trace = json.load(open(path))
     xs = [e for e in trace["traceEvents"] if e["ph"] == "X"]
     names = {e["name"] for e in trace["traceEvents"]}

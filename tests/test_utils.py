@@ -231,3 +231,32 @@ def test_save_load_pytree_python_scalar_leaves(tmp_path):
                           "step_count": 0})
     np.testing.assert_array_equal(out["w"], tree["w"])
     assert int(out["step_count"]) == 7
+
+
+def test_the_package_imports_nothing_above_it():
+    """The arrow points one way: ``benchmarks``, ``tools``, ``chipbench``,
+    ``examples`` and the tests use the package, and no module of the
+    package imports one of them (or a ``bench`` beside it), at module
+    level or inside a function."""
+    import ast
+    import glob
+    import os
+
+    import pytorch_ps_mpi_tpu
+
+    above = {"benchmarks", "tools", "chipbench", "examples", "bench", "tests"}
+    root = os.path.dirname(pytorch_ps_mpi_tpu.__file__)
+    found = []
+    for path in sorted(glob.glob(os.path.join(root, "**", "*.py"),
+                                 recursive=True)):
+        with open(path, encoding="utf-8") as f:
+            tree_ = ast.parse(f.read())
+        for node in ast.walk(tree_):
+            names = []
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                names = [node.module or ""]
+            found += [(os.path.relpath(path, root), node.lineno, n)
+                      for n in names if n.split(".")[0] in above]
+    assert not found, found

@@ -1,7 +1,7 @@
 """Homomorphic-aggregation smoke gate (make agg-smoke, in the default
 `make test` path).
 
-Four checks, each a hard assert:
+Three checks, each a hard assert:
 
 1. **one decode per publish** — a real 2-process shm sync-barrier run
    over the top-k wire must arm aggregation (``agg_mode == 1.0``),
@@ -13,30 +13,19 @@ Four checks, each a hard assert:
    tolerance (exact-algebra codec, real ``CodecWire`` buffers);
 3. **automatic fallback** — the same run with ``agg: "off"`` keeps the
    legacy decode-sum path (``agg_mode == 0.0``, ~world decodes per
-   publish), so the knob is a real switch, not a label;
-4. **per-push accumulate flat in model size** — ``agg_bench --quick``'s
-   gates (sparse fold cost ≤1.2× between 1× and 8× models, integer
-   per-push accumulate beats a per-push decode) re-asserted at CI
-   scale.
-
-Appends a trajectory row to ``benchmarks/results/agg_smoke.jsonl`` and
-gates it with ``tools/bench_gate.py --trajectory``.
+   publish), so the knob is a real switch, not a label.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
-import time
 
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results", "agg_smoke.jsonl")
 
 
 def check(name: str, cond: bool, detail: str = "") -> None:
@@ -81,8 +70,6 @@ def run_serve(agg: str):
 
 
 def main() -> int:
-    t_wall0 = time.perf_counter()
-
     # -- 1. one decode per publish (the headline) -------------------------
     m = run_serve("auto")
     check("aggregation armed", m["agg_mode"] == 1.0)
@@ -102,7 +89,6 @@ def main() -> int:
           and fleet["decodes_per_publish"] == 1.0,
           json.dumps({k: fleet[k] for k in
                       ("agg_mode", "decodes_per_publish")}))
-    loss_drop_agg = m["loss_initial"] - m["loss_final"]
 
     # -- 2. wire-level exactness ------------------------------------------
     import jax
@@ -138,32 +124,8 @@ def main() -> int:
     check("both paths trained comparably",
           m_off["loss_final"] < m_off["loss_initial"])
 
-    # -- 4. per-push cost gates (agg_bench --quick) -----------------------
-    rc = subprocess.call(
-        [sys.executable, os.path.join(REPO, "benchmarks", "agg_bench.py"),
-         "--quick"],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    check("agg_bench --quick gates green", rc == 0, f"rc={rc}")
-
-    wall = time.perf_counter() - t_wall0
-    row = {
-        "bench": "agg_smoke", "t": time.time(),
-        "wall_s": round(wall, 3),
-        "decodes_per_publish": m["decodes_per_publish"],
-        "loss_drop": round(loss_drop_agg, 4),
-        "updates_per_sec": round(m["updates_per_sec"], 2),
-    }
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"agg_smoke: all checks green in {wall:.1f}s — {row}")
-
-    return subprocess.call([
-        sys.executable, os.path.join(REPO, "tools", "bench_gate.py"),
-        "--trajectory", RESULTS,
-        "--metric", "agg_smoke.wall_s:lower:1.5",
-        "--metric", "agg_smoke.decodes_per_publish:lower:0.01",
-    ])
+    print("agg_smoke: all checks green")
+    return 0
 
 
 if __name__ == "__main__":

@@ -17,10 +17,6 @@ stopped looking. This smoke runs the suite both ways:
    with the ASan flags from ``utils/native.SANITIZE_FLAGS`` must be
    caught at runtime (the wiring ``make native-asan`` relies on
    detects a real bug, not just compiles).
-
-Appends a bench_gate trajectory row (analyze wall time) to
-``benchmarks/results/analyze_smoke.jsonl`` so the analysis pass itself
-has a time budget.
 """
 
 from __future__ import annotations
@@ -31,12 +27,9 @@ import shutil
 import subprocess
 import sys
 import tempfile
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-RESULTS = os.path.join(REPO, "benchmarks", "results",
-                       "analyze_smoke.jsonl")
 
 #: directories a seeded-defect tree needs (tools/ itself is the
 #: analyzer, not an analysis target)
@@ -124,17 +117,12 @@ def seeded_tree(td: str, rule: str, tag: str = "") -> str:
 
 
 def main() -> int:
-    t0 = time.perf_counter()
-
     # 1) clean tree: silent, exit 0
-    t_clean = time.perf_counter()
     rc, doc = run_psanalyze(REPO)
-    analyze_wall = time.perf_counter() - t_clean
     assert rc == 0 and doc["finding_count"] == 0, (
         f"psanalyze must be clean on the committed tree, got rc={rc}: "
         f"{doc['findings']}")
-    print(f"analyze_smoke: clean tree silent in {analyze_wall:.2f}s "
-          f"({len(doc['rules'])} rules)")
+    print(f"analyze_smoke: clean tree silent ({len(doc['rules'])} rules)")
 
     # 2) every rule fires on its seeded defect
     with tempfile.TemporaryDirectory(prefix="psanalyze_smoke_") as td:
@@ -187,23 +175,8 @@ def main() -> int:
         print("analyze_smoke: ASan wiring caught the seeded "
               "heap-buffer-overflow")
 
-    wall = time.perf_counter() - t0
-    row = {
-        "bench": "analyze_smoke", "t": time.time(),
-        "wall_s": round(wall, 3),
-        "analyze_wall_s": round(analyze_wall, 3),
-    }
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"analyze_smoke: all checks green in {wall:.1f}s — {row}")
-
-    return subprocess.call([
-        sys.executable, os.path.join(REPO, "tools", "bench_gate.py"),
-        "--trajectory", RESULTS,
-        "--metric", "analyze_smoke.analyze_wall_s:lower:1.5",
-        "--metric", "analyze_smoke.wall_s:lower:1.5",
-    ])
+    print("analyze_smoke: all checks green")
+    return 0
 
 
 if __name__ == "__main__":

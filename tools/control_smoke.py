@@ -30,9 +30,7 @@ controller armed, against the SAME scenario uncontrolled:
    sequence BYTE-identically, and neither the live run nor
    ``tools/telemetry_report.py``'s flap check may find a flap.
 6. **The controller helps.** The controlled run's final loss must beat
-   the uncontrolled run's. Appends a trajectory row to
-   ``benchmarks/results/control_smoke.jsonl`` (wall + loss ratio gated
-   by ``tools/bench_gate.py`` from the Makefile).
+   the uncontrolled run's.
 
 Run via ``make control-smoke``. Exits nonzero on any wrong verdict.
 """
@@ -63,9 +61,6 @@ from pytorch_ps_mpi_tpu.parallel.async_train import (
     spawn_worker,
 )
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results",
-                       "control_smoke.jsonl")
 
 STEPS = 30
 NAN_STEPS = (2, 3)
@@ -382,7 +377,6 @@ def tcp_renegotiation_leg() -> None:
 
 
 def main() -> int:
-    t_wall0 = time.perf_counter()
     workdir = tempfile.mkdtemp(prefix="control_smoke_")
     tdir = os.path.join(workdir, "telemetry")
 
@@ -414,26 +408,8 @@ def main() -> int:
           f"controlled={loss_ctl:.4f} uncontrolled={loss_un:.4f} "
           f"ratio={ratio:.3f}")
 
-    wall = time.perf_counter() - t_wall0
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    row = {
-        "bench": "control_smoke", "t": time.time(),
-        "wall_total_s": round(wall, 3),
-        "loss_controlled": round(loss_ctl, 6),
-        "loss_uncontrolled": round(loss_un, 6),
-        "loss_ratio": round(ratio, 4),
-        "actions": len(actions),
-        "flaps": int(m_ctl["control"]["flaps"]),
-        "epoch": int(m_ctl["control"]["epoch"]),
-        "epoch_old_frames": int(m_ctl["control"]["epoch_old_frames"]),
-        "readmissions": int(m_ctl["numerics"]["readmissions"]),
-        "reads_shed": int(m_ctl["reads_shed"]),
-    }
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"control_smoke: PASS in {wall:.1f}s — "
-          f"{len(actions)} actions, 0 flaps, loss ratio {ratio:.3f} "
-          f"(row appended to {RESULTS})")
+    print(f"control_smoke: PASS — "
+          f"{len(actions)} actions, 0 flaps, loss ratio {ratio:.3f}")
     return 0
 
 

@@ -23,9 +23,7 @@ freshness plane exercised live:
 - ``Controller.replay`` over the persisted TSDB rows must re-derive the
   action sequence (including the edge_age_burn verdict) byte-identically.
 
-Appends a trajectory row to ``benchmarks/results/fresh_smoke.jsonl``
-(gated by ``tools/bench_gate.py`` from the Makefile). Run via
-``make fresh-smoke``. Exits nonzero on any wrong verdict.
+Run via ``make fresh-smoke``. Exits nonzero on any wrong verdict.
 """
 
 from __future__ import annotations
@@ -43,9 +41,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results", "fresh_smoke.jsonl")
 
 STEPS = 60
 WORKERS = 2
@@ -132,7 +127,6 @@ def main() -> int:
     from pytorch_ps_mpi_tpu.telemetry.lineage import trace_id
 
     print("== fresh_smoke: slow follower -> edge_age_burn ==", flush=True)
-    t0 = time.perf_counter()
     workdir = tempfile.mkdtemp(prefix="fresh_smoke_")
     cfg = smoke_cfg(workdir)
     tdir = cfg["telemetry_dir"]
@@ -338,21 +332,9 @@ def main() -> int:
           json.dumps(replayed) == json.dumps(actions),
           f"live={len(actions)} replayed={len(replayed)}")
 
-    wall = time.perf_counter() - t0
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    row = {"bench": "fresh_smoke", "t": time.time(),
-           "wall_total_s": round(wall, 3),
-           "healthy_age_p95_ms": round(p95, 3),
-           "stall_age_max_ms": round(max(state["stall_ages"]), 1),
-           "verdict_edge_age_ms": float(rep[0]["verdict"]["edge_age_ms"]),
-           "deliveries": int(state["deliveries"]),
-           "replica_actions": len(rep),
-           "flaps": int(m["control"]["flaps"])}
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"fresh_smoke: PASS in {wall:.1f}s — healthy p95 "
+    print(f"fresh_smoke: PASS — healthy p95 "
           f"{p95:.0f}ms, stall max {max(state['stall_ages']):.0f}ms, "
-          f"1 edge_age_burn, 0 flaps (row appended to {RESULTS})")
+          f"1 edge_age_burn, 0 flaps")
     return 0
 
 

@@ -1,7 +1,7 @@
 """Native fast-path smoke gate (make native-smoke, in the default
 `make test` path).
 
-Four checks, each a hard assert:
+Three checks, each a hard assert:
 
 1. **both libraries build** — ``libwirecodec.so`` (fold kernels) and
    ``libtcpps.so`` (epoll transport + batched ingest) compile from
@@ -14,18 +14,11 @@ Four checks, each a hard assert:
 3. **batched ingest** — a live ``TcpPSServer`` drains a worker's framed
    pushes through ``poll_grad_batch`` (C++ validation, one pump+pop),
    with poll-identical accounting, and reason-counts a corrupt frame
-   instead of delivering or crashing on it;
-4. **the fold is a measured win** — native int8 steady-state fold vs
-   the numpy fallback at 1M elements must clear 1.5× right here in CI
-   (the full ≥2× @8M gate lives in ``benchmarks/agg_bench.py``).
-
-Appends a trajectory row to ``benchmarks/results/native_smoke.jsonl``
-and gates it with ``tools/bench_gate.py --trajectory``.
+   instead of delivering or crashing on it.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import socket
 import struct
@@ -36,10 +29,8 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results", "native_smoke.jsonl")
+sys.path.insert(0, REPO)
 
 PARITY_CODECS = [
     ("int8", {}),
@@ -168,86 +159,15 @@ def check_ingest() -> None:
         server.close()
 
 
-def measure_fold_speedup() -> float:
-    """Steady-state int8 fold, native vs numpy fallback, 1M elements."""
-    import jax
-
-    from pytorch_ps_mpi_tpu.codecs import get_codec
-    from pytorch_ps_mpi_tpu.parallel.dcn import CodecWire
-
-    template = {"w": np.zeros(1_000_000, np.float32)}
-    wire = CodecWire(get_codec("int8"), template, seed=0)
-    rng = np.random.RandomState(3)
-    bufs = [np.copy(wire.encode_to_bytes(jax.tree.map(
-        lambda x: rng.randn(*x.shape).astype(np.float32), template)))
-        for _ in range(4)]
-
-    def steady(rounds=6):
-        agg = wire.agg_begin()
-        for b in bufs:
-            agg.fold(b)  # warm (allocation, jit)
-        _block(agg)
-        samples = []
-        for _ in range(rounds):
-            t0 = time.perf_counter()
-            for b in bufs:
-                agg.fold(b)
-            _block(agg)
-            samples.append(time.perf_counter() - t0)
-        return float(np.min(samples))
-
-    def _block(agg):
-        for acc in agg._accs:
-            a = acc.get("acc") if isinstance(acc, dict) else None
-            if a is not None and not isinstance(a, np.ndarray):
-                jax.block_until_ready(a)
-
-    t_native = steady()
-    os.environ["PS_NO_NATIVE"] = "1"
-    try:
-        t_numpy = steady()
-    finally:
-        os.environ.pop("PS_NO_NATIVE", None)
-    speedup = t_numpy / max(t_native, 1e-9)
-    check("native int8 fold beats the fallback >=1.5x @1M",
-          speedup >= 1.5, f"{speedup:.2f}x "
-          f"(native {t_native*250:.3f} ms/push, "
-          f"numpy {t_numpy*250:.3f} ms/push)")
-    return speedup
-
-
 def main() -> int:
-    t0 = time.perf_counter()
     print("native_smoke: build")
     check_build()
     print("native_smoke: fold parity (native vs PS_NO_NATIVE=1)")
     check_fold_parity()
     print("native_smoke: batched ingest")
     check_ingest()
-    print("native_smoke: fold speedup")
-    speedup = measure_fold_speedup()
-
-    wall = time.perf_counter() - t0
-    row = {
-        "bench": "native_smoke", "t": time.time(),
-        "wall_s": round(wall, 3),
-        "fold_speedup_int8_x": round(speedup, 2),
-    }
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"native_smoke: all checks green in {wall:.1f}s — {row}")
-
-    # wall time gates cross-run (generous tolerance); the fold speedup
-    # is gated by the in-run >=1.5x assert above ONLY — as a cross-run
-    # median it flakes, because the measured ratio on this 2-core box
-    # legitimately swings ~3x with machine load (4.35x quiet, 1.5x
-    # under a parallel suite) and both sides of the A/B move with it.
-    return subprocess.call([
-        sys.executable, os.path.join(REPO, "tools", "bench_gate.py"),
-        "--trajectory", RESULTS,
-        "--metric", "native_smoke.wall_s:lower:1.5",
-    ])
+    print("native_smoke: all checks green")
+    return 0
 
 
 if __name__ == "__main__":

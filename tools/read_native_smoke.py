@@ -10,12 +10,10 @@ loop it replaces, plus one follower hop — each a hard assert:
 2. **wire parity** — raw PSR1 reply byte streams (header AND payload)
    from the native tier match the Python loop bit-for-bit across the
    full / delta / not-modified kinds;
-3. **served latency** — the same concurrent full-read workload through
-   both tiers; the native p99 must not regress (the ratio is a
-   bench_gate trajectory metric, so CI flags drift, not noise);
+3. **concurrent reads** — 24 readers' full reads through the native
+   tier are all answered and every byte of them is sent;
 4. **admission shedding** — a depth-1 storm through the native tier
-   sheds, every reader still completes via retry-after, and the shed
-   fraction rides the trajectory gate;
+   sheds and every reader still completes via retry-after;
 5. **replica hop** — a ``FollowerLoop`` replica pulled off the native
    root re-serves bit-exact bytes with lag 0 and nonzero
    ``follower_bytes_relayed``.
@@ -23,18 +21,12 @@ loop it replaces, plus one follower hop — each a hard assert:
 Skips (exit 0, with a notice) when the toolchain is missing or
 ``PS_NO_NATIVE`` is set — the Python loop is the tested fallback and
 the rest of `make test` already covers it.
-
-Appends a trajectory row to
-``benchmarks/results/read_native_smoke.jsonl`` and gates it with
-``tools/bench_gate.py --trajectory``.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import socket
-import subprocess
 import sys
 import threading
 import time
@@ -42,10 +34,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results",
-                       "read_native_smoke.jsonl")
 
 N_ELEMS = 49_000
 TEMPLATE_SHAPE = {"w0": (40_000,), "w1": (9_000,)}
@@ -79,28 +67,25 @@ def raw_reply(port: int, have_version: int = 0) -> bytes:
         return hdr + _recv_exact(s, net._REP.unpack(hdr)[7])
 
 
-def served_quantile(port: int, n_readers: int, reads_each: int,
-                    q: float = 0.99) -> float:
-    """p-quantile served latency (ms) of concurrent full reads — every
-    request does real work (have_version=0), so this times the serve
-    path, not the not-modified fast exit."""
+def concurrent_full_reads(port: int, n_readers: int,
+                          reads_each: int) -> int:
+    """Concurrent full reads — every request does real work
+    (have_version=0), never the not-modified fast exit. Returns how
+    many were served."""
     from pytorch_ps_mpi_tpu.serving.net import ReadClient
 
-    lats: list = [None] * n_readers
+    served = [0] * n_readers
     barrier = threading.Barrier(n_readers)
 
     def body(i: int) -> None:
         c = ReadClient("127.0.0.1", port, timeout=30)
-        mine = []
         barrier.wait()
         for _ in range(reads_each):
-            t0 = time.perf_counter()
             kind, _, _, retry_after, _ = c.request(have_version=0)
             if kind == "retry":
                 time.sleep(retry_after)
                 continue
-            mine.append(time.perf_counter() - t0)
-        lats[i] = mine
+            served[i] += 1
         c.close()
 
     threads = [threading.Thread(target=body, args=(i,))
@@ -109,9 +94,7 @@ def served_quantile(port: int, n_readers: int, reads_each: int,
         t.start()
     for t in threads:
         t.join(timeout=120)
-    flat = [v for sub in lats if sub for v in sub]
-    assert flat, "no reads completed"
-    return float(np.quantile(np.array(flat), q) * 1e3)
+    return sum(served)
 
 
 def main() -> int:
@@ -123,7 +106,6 @@ def main() -> int:
     from pytorch_ps_mpi_tpu.serving.native_read import get_read_lib
     from pytorch_ps_mpi_tpu.utils.native import fast_path_disabled
 
-    t_wall0 = time.perf_counter()
     if fast_path_disabled():
         print("read_native_smoke: SKIP (PS_NO_NATIVE set; the Python "
               "loop is covered by make read-smoke)")
@@ -158,21 +140,19 @@ def main() -> int:
         check(f"reply parity: {label}", a == b,
               f"{len(a)}B native vs {len(b)}B python")
 
-    # -- 3. served p99, same workload through both tiers -------------------
+    py.close()
+
+    # -- 3. concurrent full reads through the native tier ------------------
     n_readers, reads_each = 24, 15
-    nat_p99 = served_quantile(nat.read_port, n_readers, reads_each)
-    py_p99 = served_quantile(py.read_port, n_readers, reads_each)
-    ratio = nat_p99 / max(py_p99, 1e-9)
-    print(f"  served p99: native {nat_p99:.2f} ms, python {py_p99:.2f} ms "
-          f"(ratio {ratio:.2f})")
+    served = concurrent_full_reads(nat.read_port, n_readers, reads_each)
     st = nat.read_server.stats()
     check("native tier answered the workload",
-          st["reads_full"] >= n_readers * reads_each,
-          f"reads_full={st['reads_full']}")
+          served == n_readers * reads_each
+          and st["reads_full"] >= n_readers * reads_each,
+          f"served={served} reads_full={st['reads_full']}")
     check("native zero-copy sends drained",
           st["bytes_sent"] >= n_readers * reads_each * N_ELEMS * 4,
           f"bytes_sent={st['bytes_sent']}")
-    py.close()
 
     # -- 4. admission shedding on the native tier --------------------------
     # the C++ tier sheds on PENDING replies (admitted but not yet
@@ -212,7 +192,6 @@ def main() -> int:
     shed_total = nat.read_server.stats()["reads_shed"]
     check("shed accounting matches the wire",
           shed_total == shed_replies, f"{shed_total} vs {shed_replies}")
-    shed_frac = shed_replies / float(n_burst)
     nat.read_server.set_admission(SERVING_KW["admission_depth"],
                                   SERVING_KW["retry_after_s"])
 
@@ -247,36 +226,13 @@ def main() -> int:
     check("relay accounting is nonzero",
           m["follower_bytes_relayed"] > 0,
           f"relayed={m['follower_bytes_relayed']}")
-    relayed = int(m["follower_bytes_relayed"])
     r.close()
     follower.close()
     rep.close()
     nat.close()
 
-    wall = time.perf_counter() - t_wall0
-    row = {
-        "bench": "read_native_smoke", "t": time.time(),
-        "wall_s": round(wall, 3),
-        "native_p99_ms": round(nat_p99, 3),
-        "python_p99_ms": round(py_p99, 3),
-        "p99_ratio": round(ratio, 3),
-        "shed_frac": round(shed_frac, 4),
-        "relayed_bytes": relayed,
-    }
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"read_native_smoke: all checks green in {wall:.1f}s — {row}")
-
-    rc = subprocess.call([
-        sys.executable, os.path.join(REPO, "tools", "bench_gate.py"),
-        "--trajectory", RESULTS,
-        "--metric", "read_native_smoke.wall_s:lower:1.5",
-        "--metric", "read_native_smoke.native_p99_ms:lower:3.0",
-        "--metric", "read_native_smoke.p99_ratio:lower:1.0",
-        "--metric", "read_native_smoke.shed_frac:lower:2.0",
-    ])
-    return rc
+    print("read_native_smoke: all checks green")
+    return 0
 
 
 if __name__ == "__main__":

@@ -15,19 +15,12 @@ Five checks, each a hard assert:
    gets a full snapshot (counted in ``ring_ageouts``), never an error;
 5. **publish overhead** — the armed read tier's per-publish cost
    (snapshot ring put) stays ≤5% of the transport publish itself, so
-   arming the tier cannot blow the standing telemetry budget (the
-   recorder half is re-asserted by ``tools/telemetry_smoke.py``, which
-   ``make read-smoke`` runs right after this).
-
-Appends a trajectory row to ``benchmarks/results/read_smoke.jsonl`` and
-gates it with ``tools/bench_gate.py --trajectory``.
+   arming the tier cannot blow the standing telemetry budget.
 """
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -35,9 +28,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results", "read_smoke.jsonl")
 
 
 def check(name: str, cond: bool, detail: str = "") -> None:
@@ -52,10 +42,8 @@ def main() -> int:
     from pytorch_ps_mpi_tpu.serving import ServingCore, ServingReader
     from pytorch_ps_mpi_tpu.serving.net import ReadClient
 
-    t_wall0 = time.perf_counter()
     template = {"w0": np.zeros((40_000,), np.float32),
                 "w1": np.zeros((9_000,), np.float32)}
-    full_bytes = 49_000 * 4
     serving_kw = {"ring": 4, "admission_depth": 2, "retry_after_s": 0.01,
                   "delta_bucket_mb": 0.05}
     cfg = {"read_port": 0, "serving_kw": serving_kw}
@@ -168,13 +156,6 @@ def main() -> int:
     fresh.close()
     stale.close()
 
-    # latency + counters for the trajectory row BEFORE teardown
-    m = core.read_metrics()
-    p95_ms = m["read_p95_ms"]
-    reads_total = m["reads_total"]
-    saved = m["delta_bytes_saved"]
-    delta_reduction = full_bytes / max(
-        1.0, full_bytes - saved / max(1, s["reads_delta"]))
     core.close()
 
     # -- 5. armed publish overhead <= 5% of the transport publish ---------
@@ -202,28 +183,8 @@ def main() -> int:
           f"{t_put / n_pub * 1e3:.4f} ms ({overhead:.2%})")
     srv.close()
 
-    wall = time.perf_counter() - t_wall0
-    row = {
-        "bench": "read_smoke", "t": time.time(),
-        "wall_s": round(wall, 3),
-        "reads_total": reads_total,
-        "read_p95_ms": round(p95_ms, 3),
-        "delta_reduction_x": round(delta_reduction, 2),
-        "publish_overhead_pct": round(overhead * 100, 3),
-    }
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"read_smoke: all checks green in {wall:.1f}s — {row}")
-
-    rc = subprocess.call([
-        sys.executable, os.path.join(REPO, "tools", "bench_gate.py"),
-        "--trajectory", RESULTS,
-        "--metric", "read_smoke.wall_s:lower:1.5",
-        "--metric", "read_smoke.read_p95_ms:lower:3.0",
-        "--metric", "read_smoke.delta_reduction_x:higher:0.5",
-    ])
-    return rc
+    print("read_smoke: all checks green")
+    return 0
 
 
 if __name__ == "__main__":

@@ -10,7 +10,7 @@ Accepts recorder JSONL files and/or directories containing them (a
 Spans aggregate into count / total / mean / p50 / p95 / max wall time
 per name; point events are counted. ``--by-worker`` splits rows per
 worker id — the straggler view. ``--json`` emits the same summary as a
-machine-readable dict (what ``bench.py`` embeds).
+machine-readable dict.
 
 Gradient-lineage files (``lineage-*.jsonl``, ``telemetry.lineage``) get
 their own section — exact push-latency/staleness tables per worker,

@@ -15,28 +15,17 @@ injected mid-fold, asserting the tree's load-bearing invariants:
    `agg_mode == 1.0` through the whole degraded run;
 3. **leader-crash recovery** — the crashed group falls back to
    direct-to-root pushes, the supervisor respawns the leader on its
-   pinned port, the group rejoins, and every process exits 0;
-4. **scaling gates at CI scale** — `benchmarks/tree_bench.py --quick`:
-   root ingest bytes/publish near-flat (≤1.3×) growing 8→64 workers at
-   nonzero `TPS_WAN_RTT_MS` vs ≥6× on the star baseline.
-
-Appends a trajectory row to `benchmarks/results/tree_smoke.jsonl` and
-gates it with `tools/bench_gate.py --trajectory`.
+   pinned port, the group rejoins, and every process exits 0.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(REPO, "benchmarks", "results", "tree_smoke.jsonl")
 
 
 def check(name: str, cond: bool, detail: str = "") -> None:
@@ -49,7 +38,6 @@ def check(name: str, cond: bool, detail: str = "") -> None:
 def main() -> int:
     from pytorch_ps_mpi_tpu.parallel.tree import run_tree
 
-    t_all = time.time()
     tdir = tempfile.mkdtemp(prefix="tree_smoke_")
     n_workers, steps = 6, 8
     cfg = {
@@ -67,7 +55,6 @@ def main() -> int:
     print(f"tree_smoke: 2-group/{n_workers}-worker tree, leader-0 crash "
           f"at round 1, {steps} steps/worker  ({tdir})")
     params, m = run_tree(cfg, timeout=280.0)
-    wall = time.time() - t_all
 
     tree = m["tree"]
     check("every worker exited cleanly", tree["worker_codes"] == [0] * 6,
@@ -123,34 +110,7 @@ def main() -> int:
           any(w in (0, 1, 2) for w, _, _ in composed))
     print(f"  accounting: {len(composed)} composed at root + {len(lost)} "
           f"lost with the crashed leader = {len(expect)} worker pushes")
-
-    # -- scaling gates at CI scale (tree_bench --quick) --------------------
-    print("tree_smoke: running tree_bench --quick (8->64 workers, "
-          "star vs tree, rtt 4 ms)")
-    rc = subprocess.call(
-        [sys.executable, os.path.join(REPO, "benchmarks", "tree_bench.py"),
-         "--quick"],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    check("tree_bench --quick gates (flat root ingest, 1 decode/publish, "
-          "0 leader decodes)", rc == 0, f"rc={rc}")
-
-    row = {
-        "bench": "tree_smoke", "t": time.time(),
-        "metrics": {
-            "tree_smoke.wall_total_s": round(time.time() - t_all, 3),
-            "tree_smoke.run_wall_s": round(wall, 3),
-            "tree_smoke.composed": float(len(composed)),
-            "tree_smoke.lost": float(len(lost)),
-            "tree_smoke.loss_final": round(float(m["loss_final"]), 5),
-            "tree_smoke.decodes_per_publish": float(
-                m["decodes_per_publish"]),
-        },
-    }
-    os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
-    with open(RESULTS, "a") as f:
-        f.write(json.dumps(row) + "\n")
-    print(f"tree_smoke: PASS in {time.time() - t_all:.1f}s; row appended "
-          f"to {RESULTS}")
+    print("tree_smoke: PASS")
     return 0
 
 
