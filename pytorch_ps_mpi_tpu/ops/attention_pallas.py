@@ -20,8 +20,21 @@ Design choices:
   same compiled kernel serves dense attention (offsets 0) and ring
   attention's rotating blocks (``parallel/ring.py`` passes the block's
   traced global offset; a fully-future block masks itself to nothing).
-  Fully-masked k tiles are skipped with a predicated ``pl.when`` — the
-  causal dense case does half the work, ring's future blocks cost ~0.
+- **A sub-tile sweep inside each grid step** (``_sweep``, all three
+  kernels). The grid tile sets the DMAs and the number of grid steps;
+  the work inside it is done sub-tile by sub-tile in a rolled loop over
+  ``pl.ds`` slices of the resident blocks, and each sub-tile gets a class
+  from its corner positions, as scalars at run time: DEAD (no allowed
+  pair: skipped, no product, no ``exp``), FULL (every pair allowed: the
+  products and the online softmax with no mask and no select) or CUT
+  (the mask passes through it: masked element by element). So the
+  kernels compute only what the mask leaves, to the sub-tile, and mask
+  only where the mask cuts: at 256 x 256 a causal 1024 x 1024 head is 6
+  dead + 4 cut + 6 full sub-tiles, a block-diffusion head of 2 x 4096
+  positions 736 + 48 + 240 (``tile_census``; one ``attn.flash_tiles``
+  row on the FlightRecorder a trace). A grid tile with no allowed pair
+  is skipped whole by a predicated ``pl.when``: ring's future blocks
+  cost ~0. The score tile in flight is a sub-tile, never the grid tile.
 - **Backward is two Pallas kernels** (dq over k tiles; dk/dv over q
   tiles) recomputing p from the saved logsumexp — no O(L²) residual.
   The custom VJP also accepts a cotangent for the returned logsumexp
@@ -37,11 +50,10 @@ Design choices:
   ``0..half-1`` and the clean copy at ``half..2*half-1``; with
   ``beta(i) = (i mod half) // block``, noised sees noised of its own
   block and clean of earlier blocks, clean sees clean up to its own
-  block, clean never sees noised). All three kernels test a tile's
-  liveness from its corner positions and skip dead tiles; under the
-  block-diffusion mask the index maps also clamp a dead tile onto the
-  nearest live one, so that it costs no DMA either (three quarters of
-  the tiles are dead). ``k``/``v`` may carry fewer heads than ``q``
+  block, clean never sees noised). Under the block-diffusion mask the
+  index maps also clamp a dead grid tile onto the nearest live one, so
+  that it costs no DMA either (40 of 64 grid tiles a head are dead at
+  2 x 4096 positions). ``k``/``v`` may carry fewer heads than ``q``
   (grouped-query attention): query head ``h`` reads key-value head
   ``h // (heads // kv_heads)``, and the dk/dv kernel sums over the
   group. The block-diffusion kernels carry a stable ``name`` each
@@ -70,6 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from pytorch_ps_mpi_tpu.ops._common import LANE as _LANE
 from pytorch_ps_mpi_tpu.ops._common import interpret as _interpret
+from pytorch_ps_mpi_tpu.telemetry.recorder import get_recorder
 
 _MASKED = -1e30        # additive mask value
 _MASK_THRESH = -1e29   # "this score was masked" test (real scores are tiny)
@@ -122,19 +135,47 @@ def _mask_spec(causal: bool, mask: Optional[str], block, half) -> tuple:
     return ("bd", int(block), int(half))
 
 
+def _bd_corners(mask, start, size):
+    """Of a tile under the block-diffusion mask (it lies within one half):
+    is it in the noised half, is it in the clean one, and the blocks of
+    its first and last position."""
+    _, block, half = mask
+    shift = block.bit_length() - 1
+    clean = start >= half
+    lo = start - half * clean
+    return start < half, clean, lo >> shift, (lo + size - 1) >> shift
+
+
+def _bd_rule(q, k, noised_noised, noised_clean, clean_clean):
+    """The rule of the pair of halves the tile lies in (clean never sees
+    noised). Plain comparisons and boolean algebra: the same text serves
+    traced scalars inside a kernel and numpy arrays in ``tile_census``."""
+    return ((q[0] & k[0] & noised_noised) | (q[0] & k[1] & noised_clean)
+            | (q[1] & k[1] & clean_clean))
+
+
 def _tile_live(mask, q_start, k_start, bq, bk):
     """Does the tile with these corner positions hold an allowed pair?"""
     if mask[0] == "none":
         return True
-    causal = k_start <= q_start + bq - 1
     if mask[0] == "causal":
-        return causal
-    _, block, half = mask
-    q_clean, k_clean = q_start >= half, k_start >= half
-    same = (k_start < q_start + bq) & (q_start < k_start + bk)   # noised/noised
-    earlier = k_start - half < q_start + bq - block              # noised/clean
-    return jnp.where(q_clean, k_clean & causal,
-                     jnp.where(k_clean, earlier, same))
+        return k_start <= q_start + bq - 1
+    q, k = _bd_corners(mask, q_start, bq), _bd_corners(mask, k_start, bk)
+    (_, _, qb0, qb1), (_, _, kb0, kb1) = q, k
+    # noised/noised: kb == qb; noised/clean: kb < qb; clean/clean: kb <= qb
+    return _bd_rule(q, k, (kb0 <= qb1) & (qb0 <= kb1), kb0 < qb1, kb0 <= qb1)
+
+
+def _tile_full(mask, q_start, k_start, bq, bk):
+    """Is every pair of the tile allowed? Such a tile needs no mask."""
+    if mask[0] == "none":
+        return True
+    if mask[0] == "causal":
+        return k_start + bk - 1 <= q_start
+    q, k = _bd_corners(mask, q_start, bq), _bd_corners(mask, k_start, bk)
+    (_, _, qb0, qb1), (_, _, kb0, kb1) = q, k
+    return _bd_rule(q, k, (kb0 == qb0) & (kb1 == qb0) & (qb1 == qb0),
+                    kb1 < qb0, kb1 <= qb0)
 
 
 def _mask_scores(mask, s, q_start, k_start, bq, bk):
@@ -200,11 +241,90 @@ def _kernel_name(mask, which):
 
 
 # ---------------------------------------------------------------------------
+# the sub-tile sweep inside a grid step
+# ---------------------------------------------------------------------------
+
+def _loop(n, body):
+    """``body(i)`` for ``i`` in ``range(n)``, rolled: Mosaic compiles one
+    copy of the body however many sub-tiles a tile holds. A single turn
+    is no loop at all: ``i`` is the integer 0 and every slice is static."""
+    if n == 1:
+        body(0)
+        return
+
+    def step(i, carry):
+        body(i)
+        return carry
+
+    jax.lax.fori_loop(0, n, step, 0)
+
+
+def _rows(i, n):
+    """Rows ``i * n .. i * n + n - 1`` of a resident block."""
+    return pl.ds(i * n if isinstance(i, int) else pl.multiple_of(i * n, n), n)
+
+
+def _sweep(mask, q_start, k_start, bq, bk, sq, sk, q_outer, visit):
+    """Walk the ``sq x sk`` sub-tiles of the resident ``bq x bk`` tile and
+    class each from its corner positions, as scalars at run time (the
+    offsets may be traced): a DEAD one (no allowed pair) is skipped, a
+    FULL one (every pair allowed) gets ``visit(i, j, q0, k0, False)``, a
+    CUT one (the mask passes through it) ``visit(i, j, q0, k0, True)``;
+    the last argument is static, so the unmasked body carries no mask."""
+    def one(i, j):
+        q0, k0 = q_start + i * sq, k_start + j * sk
+        if mask[0] == "none":
+            visit(i, j, q0, k0, False)
+            return
+        live = _tile_live(mask, q0, k0, sq, sk)
+        full = _tile_full(mask, q0, k0, sq, sk)
+        pl.when(full)(lambda: visit(i, j, q0, k0, False))
+        pl.when(live & jnp.logical_not(full))(
+            lambda: visit(i, j, q0, k0, True))
+
+    if q_outer:
+        _loop(bq // sq, lambda i: _loop(bk // sk, lambda j: one(i, j)))
+    else:
+        _loop(bk // sk, lambda j: _loop(bq // sq, lambda i: one(i, j)))
+
+
+def _lanes(col, n):
+    """A lane-replicated ``[rows, LANE]`` column stretched to ``n`` lanes
+    by reusing its registers: no move across lanes."""
+    if n % _LANE == 0:
+        return jnp.tile(col, (1, n // _LANE))
+    if n < _LANE:
+        return col[:, :n]
+    return jnp.broadcast_to(col[:, :1], (col.shape[0], n))
+
+
+def _fold_lanes(x):
+    """``[rows, n]`` summed into ``[rows, LANE]`` lane by lane (element-wise
+    adds of whole registers); the sum over its lanes is the row sum. The
+    one move across lanes is left to whoever needs the row sum."""
+    rows, n = x.shape
+    if n % _LANE:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 1)
+        return jnp.where(lane == 0, jnp.sum(x, axis=-1, keepdims=True), 0.0)
+    out = x[:, :_LANE]
+    for c in range(1, n // _LANE):
+        out = out + x[:, c * _LANE:(c + 1) * _LANE]
+    return out
+
+
+def _scores(q, k, scale):
+    """``q @ k.T`` in float32, scaled there (not in the bf16 operands)."""
+    return jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc, m_sc, l_sc, *, mask, scale, bq, bk, nk):
+                acc, m_sc, l_sc, *, mask, scale, bq, bk, sq, sk, nk):
     j = pl.program_id(1)
     kk = pl.program_id(2)
 
@@ -217,49 +337,54 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     q_start = qo_ref[0] + j * bq
     k_start = ko_ref[0] + kk * bk
 
-    # skip tiles the mask empties (causal: the future half)
+    # m and l ride lane-replicated ([bq, LANE]): a sub-tile's visit costs one
+    # move across lanes a row (the row maximum); the row sum is kept
+    # folded lane by lane and reduced once, when the q tile is finished
+    def visit(i, jj, q0, k0, cut):
+        rq, rk = _rows(i, sq), _rows(jj, sk)
+        s = _scores(q_ref[0, rq, :], k_ref[0, rk, :], scale)
+        if cut:
+            s = _mask_scores(mask, s, q0, k0, sq, sk)
+        m_prev = m_sc[rq, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, sk))
+        if cut:
+            # a row with no visible key keeps m == _MASKED; exp(s - m)
+            # would be exp(0) = 1 there: mask p explicitly, never through
+            # the exp. A full sub-tile has no such row and no such score
+            p = jnp.where(s > _MASK_THRESH, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[rq, :] = l_sc[rq, :] * corr + _fold_lanes(p)
+        v = v_ref[0, rk, :]
+        acc[rq, :] = acc[rq, :] * _lanes(corr, acc.shape[1]) + (
+            jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        m_sc[rq, :] = m_new
+
+    # skip tiles the mask empties (causal: the future), without a sweep
     @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
     def _():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        s = _mask_scores(mask, s, q_start, k_start, bq, bk)
-        m_prev = m_sc[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # a row with no visible key keeps m == _MASKED; exp(s - m) would
-        # be exp(0) = 1 there — mask p explicitly, never through the exp
-        p = jnp.where(s > _MASK_THRESH, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[:, :1] = l_sc[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0]
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_sc[:, :1] = m_new
+        _sweep(mask, q_start, k_start, bq, bk, sq, sk, True, visit)
 
     @pl.when(kk == nk - 1)
     def _():
-        l_safe = jnp.maximum(l_sc[:, :1], 1e-30)
+        l_safe = jnp.maximum(jnp.sum(l_sc[:], axis=-1, keepdims=True), 1e-30)
         o_ref[0] = (acc[:] / l_safe).astype(o_ref.dtype)
         # lane-replicated write: lse rides as [bh, lq, LANE] so its block
         # (1, bq, LANE) satisfies Mosaic's (8, 128) tile rule for ANY bh —
         # a (1, bq) block over [bh, lq] only lowers when bh == 1
-        lse_ref[0] = jnp.broadcast_to(
-            m_sc[:, :1] + jnp.log(l_safe), (lse_ref.shape[1], _LANE)
-        )
+        lse_ref[0] = m_sc[:] + jnp.log(l_safe)
 
 
-def _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk):
+def _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk, sq, sk):
     bh, lq, d = q3.shape
     lk = k3.shape[1]
     group = bh // k3.shape[0]       # query heads per key-value head
     nq, nk = lq // bq, lk // bk
     kern = functools.partial(
-        _fwd_kernel, mask=mask, scale=scale, bq=bq, bk=bk, nk=nk
+        _fwd_kernel, mask=mask, scale=scale, bq=bq, bk=bk, sq=sq, sk=sk,
+        nk=nk
     )
 
     def kv_map(i, j, kk):
@@ -297,18 +422,21 @@ def _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk):
 # backward
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q, k, lse_tile, q_start, k_start, mask, scale, bq, bk):
-    """p = exp(s - lse) with masked entries exactly zero.
-    ``lse_tile`` is a [bq, 1] column (lane 0 of the replicated ride)."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    s = _mask_scores(mask, s, q_start, k_start, bq, bk)
-    return jnp.where(s > _MASK_THRESH, jnp.exp(s - lse_tile), 0.0)
+def _recompute_p(q, k, lse_rep, q0, k0, mask, scale, cut):
+    """p = exp(s - lse) of one sub-tile, masked entries exactly zero.
+    ``lse_rep`` is the lane-replicated [rows, LANE] ride; ``cut`` (static)
+    says whether the mask passes through the sub-tile."""
+    s = _scores(q, k, scale)
+    lse = _lanes(lse_rep, s.shape[1])
+    if not cut:
+        return jnp.exp(s - lse)
+    s = _mask_scores(mask, s, q0, k0, *s.shape)
+    return jnp.where(s > _MASK_THRESH, jnp.exp(s - lse), 0.0)
 
 
 def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   dm_ref, dq_ref, dq_acc, *, mask, scale, bq, bk, nk):
+                   dm_ref, dq_ref, dq_acc, *, mask, scale, bq, bk, sq, sk,
+                   nk):
     j = pl.program_id(1)
     kk = pl.program_id(2)
 
@@ -319,29 +447,33 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     q_start = qo_ref[0] + j * bq
     k_start = ko_ref[0] + kk * bk
 
-    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p = _recompute_p(q, k, lse_ref[0][:, :1], q_start, k_start, mask,
-                         scale, bq, bk)
+    def visit(i, jj, q0, k0, cut):
+        rq, rk = _rows(i, sq), _rows(jj, sk)
+        k, v = k_ref[0, rk, :], v_ref[0, rk, :]
+        p = _recompute_p(q_ref[0, rq, :], k, lse_ref[0, rq, :], q0, k0,
+                         mask, scale, cut)
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+            do_ref[0, rq, :], v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dm_ref[0][:, :1])
-        dq_acc[:] += jax.lax.dot_general(
+        ds = p * (dp - _lanes(dm_ref[0, rq, :], sk))
+        dq_acc[rq, :] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale
+        )
+
+    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
+    def _():
+        _sweep(mask, q_start, k_start, bq, bk, sq, sk, True, visit)
 
     @pl.when(kk == nk - 1)
     def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     dm_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, mask, scale, bq, bk, nq, steps):
+                    *, mask, scale, bq, bk, sq, sk, nq, steps):
     jk = pl.program_id(1)
     t = pl.program_id(2)       # (query head of the group, q tile), flattened
     jq = t % nq
@@ -354,33 +486,38 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     q_start = qo_ref[0] + jq * bq
     k_start = ko_ref[0] + jk * bk
 
-    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p = _recompute_p(q, k, lse_ref[0][:, :1], q_start, k_start, mask,
-                         scale, bq, bk)
-        dv_acc[:] += jax.lax.dot_general(
+    def visit(i, jj, q0, k0, cut):
+        rq, rk = _rows(i, sq), _rows(jj, sk)
+        q, do = q_ref[0, rq, :], do_ref[0, rq, :]
+        p = _recompute_p(q, k_ref[0, rk, :], lse_ref[0, rq, :], q0, k0,
+                         mask, scale, cut)
+        dv_acc[rk, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+            do, v_ref[0, rk, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - dm_ref[0][:, :1])
-        dk_acc[:] += jax.lax.dot_general(
+        ds = p * (dp - _lanes(dm_ref[0, rq, :], sk))
+        dk_acc[rk, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale
+        )
+
+    # the accumulators belong to the k side: its sub-tiles are the outer loop
+    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
+    def _():
+        _sweep(mask, q_start, k_start, bq, bk, sq, sk, False, visit)
 
     @pl.when(t == steps - 1)
     def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
-         mask, scale, bq, bk):
+         mask, scale, bq, bk, sq, sk):
     bh, lq, d = q3.shape
     bkv, lk = k3.shape[:2]
     group = bh // bkv
@@ -399,7 +536,7 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
-                          bq=bq, bk=bk, nk=nk),
+                          bq=bq, bk=bk, sq=sq, sk=sk, nk=nk),
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -426,7 +563,8 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
-                          bq=bq, bk=bk, nq=nq, steps=group * nq),
+                          bq=bq, bk=bk, sq=sq, sk=sk, nq=nq,
+                          steps=group * nq),
         grid=(bkv, nk, group * nq),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -461,20 +599,20 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash(q3, k3, v3, q_off, k_off, mask, scale, bq, bk):
-    out, lse = _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk)
+def _flash(q3, k3, v3, q_off, k_off, mask, scale, tile, sub):
+    out, lse = _fwd(q3, k3, v3, q_off, k_off, mask, scale, *tile, *sub)
     return out, lse
 
 
-def _flash_fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk):
-    out, lse = _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk)
+def _flash_fwd(q3, k3, v3, q_off, k_off, mask, scale, tile, sub):
+    out, lse = _fwd(q3, k3, v3, q_off, k_off, mask, scale, *tile, *sub)
     # residual keeps lane 0 only — every lane is identical, and holding
     # the [bh, lq, LANE] ride through the whole model backward would cost
     # 128x the memory; _bwd re-broadcasts (same pattern as dm)
     return (out, lse), (q3, k3, v3, q_off, k_off, out, lse[..., 0])
 
 
-def _flash_bwd(mask, scale, bq, bk, res, g):
+def _flash_bwd(mask, scale, tile, sub, res, g):
     q3, k3, v3, q_off, k_off, out, lse = res
     g_out, g_lse = g
     # lse is returned lane-replicated [bh, lq, LANE]; the adjoint of that
@@ -482,7 +620,7 @@ def _flash_bwd(mask, scale, bq, bk, res, g):
     # so in practice only that column is nonzero)
     g_lse = g_lse.sum(axis=-1)
     dq, dk, dv = _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
-                      mask, scale, bq, bk)
+                      mask, scale, *tile, *sub)
     zero_off = np.zeros((1,), jax.dtypes.float0)  # int inputs: no tangent
     return dq, dk, dv, zero_off, zero_off
 
@@ -535,26 +673,71 @@ def _attention_jnp(q, k, v, q_offset, k_offset, mask, scale):
     return out, lse
 
 
-def _default_block_targets(lq: int, lk: int) -> tuple:
-    """Block-size policy: 128x128 tiles below sequence 1024, 512x1024
-    from there up — larger k/v tiles amortize per-grid-step dispatch and
-    keep the MXU fed once the score block is MXU-shaped on both dims,
-    while below ~1k sequence the grid is too small for tile residency to
-    matter and 128's divisibility into short tails wins (no chip
-    number; see ``PERF.md`` section 7)."""
-    if max(lq, lk) >= 1024:
-        return 512, 1024
-    return 128, 128
+def _default_block_targets(lq: int, lk: int, causal: bool = False) -> tuple:
+    """The grid tile (what a grid step holds resident: its DMAs and the
+    number of grid steps). 128 x 128 below sequence 1024 (no chip number;
+    ``PERF.md`` section 7). From 1024 up, measured on a v5e at GPT-2's
+    shape (8 x 12 heads, 1024 x 64, bf16; forward + dq + dk/dv of one
+    layer, ``PERF.md`` section 6, PR 30): causal 1.215 ms at 1024 x 1024
+    against 1.250 at 512 x 1024 (both swept in 512 x 512 sub-tiles;
+    1.337 against 1.391 at 2 x 16 heads of 2048 x 128): the whole k/v of
+    a 1024-long head stays resident and a head is one grid step, which
+    the scores no longer forbid because only a sub-tile of them is ever in
+    flight. Unmasked: 512 x 1024 (1.451 ms, the tile as one sub-tile;
+    1.531 at 1024 x 1024 swept in 512 x 512)."""
+    if max(lq, lk) < 1024:
+        return 128, 128
+    return (1024, 1024) if causal else (512, 1024)
 
 
 def _bd_block_targets(half: int) -> tuple:
-    """Tiles under the block-diffusion mask. At 2 x 4096 positions, 32
-    query over 4 key-value heads of 128, bf16, on a v5e the three kernels
-    took 30.5 ms forward + backward at 1024x1024, 32.4 at 512x1024, 36.9
-    at 512x512, 37.8 at 1024x512, 47.2 at 256x512 (PERF.md section 6,
-    PR 27): the larger tile wins although fewer of its tiles are dead,
-    because a live tile's cost is dominated by its grid step."""
+    """Grid tiles under the block-diffusion mask: 1024 x 1024. At 2 x 4096
+    positions, 32 query over 4 key-value heads of 128, bf16, 2 rows, on a
+    v5e (``PERF.md`` section 6): before the sub-tile sweep the three
+    kernels took 30.5 ms at 1024 x 1024, 32.4 at 512 x 1024, 36.9 at
+    512 x 512, 47.2 at 256 x 512 (PR 27: smaller GRID tiles lose, each
+    pays its own grid step and DMAs); with the sweep at 256 x 256
+    sub-tiles 51.4 at 1024 x 1024, 51.8 at 2048 x 1024, 50.8 at
+    2048 x 2048 (PR 30: a larger grid tile returns 1 %, not kept)."""
     return (1024, 1024) if half >= 1024 else _default_block_targets(half, half)
+
+
+def _sub_tile_targets(mask: tuple, bq: int, bk: int) -> tuple:
+    """The sub-tile (q rows, k rows) a grid tile is swept in. Measured on
+    a v5e, forward + dq + dk/dv of one layer in ms (``PERF.md`` section 6,
+    PR 30). Causal, 96 heads of 1024 x 64, grid tile 1024 x 1024: 1.215
+    at 512 x 512; 1.478 at 1024 x 512; 1.489 at 256 x 512; 1.492 at
+    1024 x 1024 (nothing skipped); 1.770 at 512 x 256; 2.121 at
+    256 x 256; 4.165 at 128 x 128. Block diffusion, 2 x 32 over 4 heads of
+    8192 x 128, grid tile 1024 x 1024: 23.60 at 512 x 512; 25.10 at
+    1024 x 1024; 25.26 at 1024 x 512; 28.67 at 256 x 512; 33.94 at
+    512 x 256; 43.65 at 256 x 256; 57.94 at 128 x 256. A visit of a
+    sub-tile is a chain of MXU round trips that nothing overlaps across the
+    rolled loop's branches, so under 512 a side the chain's latency costs
+    more than the finer skipping returns, at head_dim 64 and at 128 alike.
+    Unmasked there is nothing to skip and the sweep only costs (1.451
+    whole against 1.555 at 512 x 512): the tile is its own sub-tile."""
+    if mask[0] == "none":
+        return bq, bk
+    return 512, 512
+
+
+def tile_census(mask: tuple, lq: int, lk: int, bq: int, bk: int,
+                sub_q: int, sub_k: int) -> dict:
+    """How many ``sub_q x sub_k`` sub-tiles of one head's ``lq x lk``
+    scores (offsets 0, grid tiles ``bq x bk``) the kernels skip (``dead``),
+    compute under the mask (``cut``) and compute without it (``full``):
+    the classes ``_sweep`` gives them at run time."""
+    if bq % sub_q or bk % sub_k or lq % bq or lk % bk:
+        raise ValueError(f"sub-tiles {sub_q} x {sub_k} do not tile grid "
+                         f"tiles {bq} x {bk} of {lq} x {lk}")
+    q0 = np.arange(0, lq, sub_q)[:, None]
+    k0 = np.arange(0, lk, sub_k)[None, :]
+    shape = (q0.size, k0.size)
+    live = np.broadcast_to(_tile_live(mask, q0, k0, sub_q, sub_k), shape)
+    full = np.broadcast_to(_tile_full(mask, q0, k0, sub_q, sub_k), shape)
+    return {"dead": int((~live).sum()), "cut": int((live & ~full).sum()),
+            "full": int(full.sum())}
 
 
 def flash_attention(
@@ -599,7 +782,7 @@ def flash_attention(
 
     mb = _min_block_for(q.dtype)
     dbq, dbk = (_bd_block_targets(spec[2]) if spec[0] == "bd"
-                else _default_block_targets(lq, lk))
+                else _default_block_targets(lq, lk, spec[0] == "causal"))
     # a tile of the block-diffusion mask lies within one half
     tile_q, tile_k = (spec[2], spec[2]) if spec[0] == "bd" else (lq, lk)
     bq = _pick_block(tile_q, block_q if block_q is not None else dbq, mb)
@@ -609,13 +792,21 @@ def flash_attention(
         out, lse = _attention_jnp(q, k, v, q_offset, k_offset, spec, scale)
         return (out, lse) if return_lse else out
 
+    tsq, tsk = _sub_tile_targets(spec, bq, bk)
+    sq, sk = _pick_block(bq, tsq, mb), _pick_block(bk, tsk, mb)
+    rec = get_recorder()
+    if rec is not None:     # how often the sweep engages, once a trace
+        rec.event("attn.flash_tiles", mask=spec[0], block_q=bq, block_k=bk,
+                  sub_q=sq, sub_k=sk,
+                  **tile_census(spec, lq, lk, bq, bk, sq, sk))
+
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], d)
 
     q_off = jnp.broadcast_to(q_offset, (1,)).astype(jnp.int32)
     k_off = jnp.broadcast_to(k_offset, (1,)).astype(jnp.int32)
     out3, lse3 = _flash(to3(q), to3(k), to3(v), q_off, k_off,
-                        spec, float(scale), bq, bk)
+                        spec, float(scale), (bq, bk), (sq, sk))
     out = out3.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
     if not return_lse:
         return out

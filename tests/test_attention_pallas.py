@@ -246,16 +246,25 @@ def test_multi_tile_backward_both_masks_odd_heads(causal):
 
 
 def test_default_block_targets_tiers():
-    """Tile policy: 128x128 below seq 1024, 512x1024 above."""
+    """Tile policy as measured on the chip (PR 30). Grid tiles: 128x128
+    below seq 1024; from there 512x1024 unmasked, 1024x1024 causal.
+    Sub-tiles: 512x512 under a mask, the tile itself without one."""
     from pytorch_ps_mpi_tpu.ops.attention_pallas import (
-        _default_block_targets, _min_block_for, _pick_block)
+        _bd_block_targets, _default_block_targets, _min_block_for,
+        _pick_block, _sub_tile_targets)
 
     assert _default_block_targets(128, 128) == (128, 128)
-    assert _default_block_targets(512, 512) == (128, 128)
+    assert _default_block_targets(512, 512, causal=True) == (128, 128)
     assert _default_block_targets(1024, 1024) == (512, 1024)
+    assert _default_block_targets(1024, 1024, causal=True) == (1024, 1024)
     assert _default_block_targets(8192, 8192) == (512, 1024)
     # cross-length (ring attention blocks): max drives the tier
     assert _default_block_targets(512, 2048) == (512, 1024)
+    assert _bd_block_targets(4096) == (1024, 1024)
+    assert _bd_block_targets(256) == (128, 128)
+    assert _sub_tile_targets(("causal",), 1024, 1024) == (512, 512)
+    assert _sub_tile_targets(("bd", 4, 4096), 1024, 1024) == (512, 512)
+    assert _sub_tile_targets(("none",), 512, 1024) == (512, 1024)
 
     # divisibility degradation: targets cap, never break tiling
     mb = _min_block_for(jnp.float32)
@@ -371,3 +380,131 @@ def test_mask_arguments_are_checked(kw, match):
     q, k, v, _ = grouped_qkv(64, 2, 2)
     with pytest.raises(ValueError, match=match):
         flash_attention(q, k, v, **kw)
+
+
+# -- the sub-tile sweep inside a grid step (PR 30) -----------------------------
+
+@pytest.mark.parametrize("mask, lq, lk, offsets", [
+    (("none",), 64, 64, [(0, 0)]),
+    (("causal",), 64, 96, [(0, 0), (16, 0), (24, 8), (0, 200), (40, 0)]),
+    (("bd", 4, 32), 64, 64, [(0, 0)]),
+    (("bd", 16, 64), 128, 128, [(0, 0)]),   # sub-tiles smaller than a block
+])
+def test_tile_classes_match_the_dense_mask(mask, lq, lk, offsets):
+    """``_tile_full`` iff every pair of the sub-tile is allowed and
+    ``_tile_live`` iff any is, at every sub-tile position and size, in
+    global coordinates; ``tile_census`` counts the same classes."""
+    from pytorch_ps_mpi_tpu.ops.attention_pallas import (
+        _tile_full, _tile_live, allowed_pairs, tile_census)
+
+    for q_off, k_off in offsets:
+        ok = np.asarray(allowed_pairs(mask, q_off + jnp.arange(lq),
+                                      k_off + jnp.arange(lk)))
+        for sq, sk in [(8, 8), (8, 16), (16, 8), (32, 32), (16, 32)]:
+            seen = {"dead": 0, "cut": 0, "full": 0}
+            for i in range(0, lq, sq):
+                for j in range(0, lk, sk):
+                    sub = ok[i:i + sq, j:j + sk]
+                    live = bool(_tile_live(mask, q_off + i, k_off + j, sq, sk))
+                    full = bool(_tile_full(mask, q_off + i, k_off + j, sq, sk))
+                    assert live == sub.any(), (q_off, k_off, sq, sk, i, j)
+                    assert full == sub.all(), (q_off, k_off, sq, sk, i, j)
+                    seen["full" if full else "cut" if live else "dead"] += 1
+            if (q_off, k_off) == (0, 0):
+                assert tile_census(mask, lq, lk, 32, 32, sq, sk) == seen
+
+
+def _sweep_case(mask, heads, kv_heads, q_off=None, k_off=None):
+    return pytest.param(mask, heads, kv_heads, q_off, k_off,
+                        id=f"{mask[0]}-{heads}over{kv_heads}-{q_off}-{k_off}")
+
+
+@pytest.mark.parametrize("mask, heads, kv_heads, q_off, k_off", [
+    _sweep_case(("none",), 4, 4), _sweep_case(("none",), 4, 2),
+    _sweep_case(("causal",), 4, 4), _sweep_case(("causal",), 4, 2),
+    _sweep_case(("causal",), 4, 4, 24, 8), _sweep_case(("causal",), 4, 2, 24, 8),
+    # a block wholly in the future: nothing allowed, zero output, floor lse
+    _sweep_case(("causal",), 4, 4, 0, 100), _sweep_case(("causal",), 4, 2, 0, 100),
+    _sweep_case(("bd", 4, 32), 4, 4), _sweep_case(("bd", 4, 32), 4, 2),
+])
+def test_sub_tile_sweep_matches_dense(monkeypatch, mask, heads, kv_heads,
+                                      q_off, k_off):
+    """Forward, logsumexp and the three gradients (with a cotangent on the
+    logsumexp) of the three kernels sweeping 32 x 32 grid tiles in 8 x 8
+    sub-tiles, so that one grid tile holds dead, cut and full sub-tiles,
+    against the dense oracle; the offsets arrive traced."""
+    from pytorch_ps_mpi_tpu.ops import attention_pallas as ap
+
+    monkeypatch.setattr(ap, "_sub_tile_targets", lambda *a: (8, 8))
+    census = ap.tile_census(mask, 64, 64, 32, 32, 8, 8)
+    if mask[0] != "none":
+        assert min(census.values()) > 0, census
+    q, k, v, w = grouped_qkv(64, heads, kv_heads)
+    kw = dict(block_q=32, block_k=32, return_lse=True)
+    if mask[0] == "bd":
+        kw.update(mask="block_diffusion", block=mask[1], half=mask[2])
+    else:
+        kw.update(causal=mask[0] == "causal")
+
+    def kernel(q, k, v, q_off, k_off):
+        off = {} if q_off is None else dict(q_offset=q_off, k_offset=k_off)
+        o, lse = flash_attention(q, k, v, **kw, **off)
+        return jnp.sum(w * o) + jnp.sum(jnp.sin(lse)), (o, lse)
+
+    def dense(q, k, v, q_off, k_off):
+        o, lse = _attention_jnp(q, k, v, 0 if q_off is None else q_off,
+                                0 if k_off is None else k_off, mask,
+                                q.shape[-1] ** -0.5)
+        return jnp.sum(w * o) + jnp.sum(jnp.sin(lse)), (o, lse)
+
+    offs = (None, None) if q_off is None else (jnp.int32(q_off),
+                                               jnp.int32(k_off))
+    (_, (o, lse)), got = jax.jit(jax.value_and_grad(
+        kernel, (0, 1, 2), has_aux=True))(q, k, v, *offs)
+    (_, (o_ref, lse_ref)), want = jax.value_and_grad(
+        dense, (0, 1, 2), has_aux=True)(q, k, v, *offs)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                               rtol=1e-5, atol=1e-5)
+    for g, wnt in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(wnt),
+                                   rtol=2e-4, atol=2e-5)
+    if k_off == 100:
+        assert float(jnp.abs(o).max()) == 0.0 and float(lse.max()) < -1e29
+
+
+def test_tile_census_of_the_cells_and_its_recorder_row():
+    """The census of the two cells that run the kernels, at 256 x 256
+    sub-tiles (a head; before the sweep every sub-tile of a live grid
+    tile was computed and masked: 16 and 384), and one ``attn.flash_tiles``
+    row each time ``flash_attention`` is traced with the recorder on."""
+    from pytorch_ps_mpi_tpu import telemetry
+    from pytorch_ps_mpi_tpu.ops.attention_pallas import tile_census
+
+    assert tile_census(("causal",), 1024, 1024, 512, 1024, 256, 256) == {
+        "dead": 6, "cut": 4, "full": 6}
+    assert tile_census(("bd", 4, 4096), 8192, 8192, 1024, 1024, 256, 256) == {
+        "dead": 736, "cut": 48, "full": 240}
+    assert tile_census(("none",), 1024, 1024, 512, 1024, 256, 256) == {
+        "dead": 0, "cut": 0, "full": 16}
+    with pytest.raises(ValueError, match="do not tile"):
+        tile_census(("causal",), 1024, 1024, 512, 1024, 384, 256)
+
+    q, k, v = qkv(l=64)
+    fn = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=32, block_k=32))
+    fn(q, k, v)                       # traced with the recorder off: no row
+    rec = telemetry.configure()
+    try:
+        fn2 = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=32, block_k=64))
+        fn2(q, k, v)
+        fn2(q, k, v)                  # the second call traces nothing
+        rows = [e for e in rec.events() if e["name"] == "attn.flash_tiles"]
+    finally:
+        telemetry.disable()
+    assert len(rows) == 1
+    assert rows[0]["attrs"] == {"mask": "causal", "block_q": 32,
+                                "block_k": 64, "sub_q": 32, "sub_k": 64,
+                                "dead": 0, "cut": 2, "full": 0}

@@ -97,9 +97,10 @@ def test_exact_topk_compiles(v5e):
 @pytest.mark.parametrize("seq,dtype,d,causal", [
     (512, jnp.bfloat16, 64, True),     # 128x128 tiles
     (512, jnp.bfloat16, 64, False),
-    (1024, jnp.bfloat16, 64, True),    # 512x1024 tiles
+    (1024, jnp.bfloat16, 64, True),    # 1024x1024 tiles swept in 512x512
     (1024, jnp.float32, 128, True),
     (1536, jnp.bfloat16, 64, False),   # 512x512: the k target degrades
+    (96, jnp.float32, 64, True),       # 32x32: a sub-tile under 128 lanes
 ])
 def test_flash_forward_and_backward_compile(v5e, seq, dtype, d, causal):
     def loss(q, k, v):
@@ -113,7 +114,7 @@ def test_flash_forward_and_backward_compile(v5e, seq, dtype, d, causal):
 
 
 @pytest.mark.parametrize("half, heads, kv_heads, d", [
-    (4096, 32, 4, 128),    # sdar-30b-a3b.bd4k: 8,192 positions, 512x1024 tiles
+    (4096, 32, 4, 128),    # sdar-30b-a3b.bd4k: 8,192 positions, 1024x1024 tiles
     (512, 4, 2, 128),      # one q tile and one k tile a half
 ])
 def test_block_diffusion_kernels_compile(v5e, half, heads, kv_heads, d):
@@ -151,10 +152,12 @@ def test_grouped_products_compile_to_kernels(v5e):
 
 def test_vmem_overflow_is_a_compile_error(v5e):
     """Negative control: the compiler really runs — tiles that cannot
-    fit VMEM fail here instead of compiling to something else."""
+    fit VMEM fail here instead of compiling to something else. Since the
+    kernels sweep a tile in sub-tiles the scores no longer count (2048 x
+    4096 fits); the resident q, k, v and o blocks alone must overflow."""
     def fwd(q, k, v):
         return attention_pallas.flash_attention(
-            q, k, v, block_q=2048, block_k=4096)
+            q, k, v, causal=True, block_q=8192, block_k=8192)
 
     qkv = ((1, 8192, 2, 128), jnp.float32)
     with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
