@@ -44,8 +44,13 @@ Design choices:
   (``preferred_element_type``); the p@v contraction runs in the input
   dtype (bf16 on TPU) like standard flash implementations.
 
-- **Masks and head counts.** ``mask`` is ``None``, ``'causal'`` or
-  ``'block_diffusion'`` (the training mask of block-diffusion language
+- **Masks and head counts.** ``mask`` is ``None``, ``'causal'``,
+  ``'window'`` (causal within ``window`` positions: ``0 <= q_pos - k_pos <
+  window``; the grid's inner axis then runs over the k tiles (dk/dv: the
+  q tiles) the band crosses and NO OTHER: 2 of 16 at a window of 512 in
+  8,192 positions, the index maps and the kernels' positions both offset
+  by the band's first tile) or ``'block_diffusion'`` (the training mask
+  of block-diffusion language
   models over a doubled sequence: a noised copy at positions
   ``0..half-1`` and the clean copy at ``half..2*half-1``; with
   ``beta(i) = (i mod half) // block``, noised sees noised of its own
@@ -56,11 +61,15 @@ Design choices:
   2 x 4096 positions). ``k``/``v`` may carry fewer heads than ``q``
   (grouped-query attention): query head ``h`` reads key-value head
   ``h // (heads // kv_heads)``, and the dk/dv kernel sums over the
-  group. The block-diffusion kernels carry a stable ``name`` each
-  (``flash_bd_fwd`` / ``flash_bd_dq`` / ``flash_bd_dkv``): it is the HLO
-  instruction's name in a device trace. The unmasked and causal
-  kernels keep the name XLA gives them (the calling module's), which
-  the benchmark's accepted readers match.
+  group. ``v`` may be wider or narrower than ``q`` and ``k`` (differential
+  attention's value is two heads side by side, 128 against 64): the
+  output and ``dv`` take ``v``'s width, the scores ``q``'s. The
+  block-diffusion kernels carry a stable ``name`` each (``flash_bd_fwd``
+  / ``flash_bd_dq`` / ``flash_bd_dkv``): it is the HLO instruction's
+  name in a device trace; so do the window kernels (``flash_win_*``)
+  and those with a value of another width (``flash_wide_*``). The
+  unmasked and causal kernels of one width keep the name XLA gives them
+  (the calling module's), which the benchmark's accepted readers match.
 
 Off-TPU the kernel runs in Pallas interpret mode (CPU test meshes);
 ``flash_attention`` falls back to a jnp oracle for shapes the tiling
@@ -116,19 +125,25 @@ def _min_block_for(dtype) -> int:
 
 
 # ---------------------------------------------------------------------------
-# masks: a static tuple ("none",) | ("causal",) | ("bd", block, half)
+# masks: a static tuple ("none",) | ("causal",) | ("window", w)
+#                       | ("bd", block, half)
 # ---------------------------------------------------------------------------
 
-def _mask_spec(causal: bool, mask: Optional[str], block, half) -> tuple:
+def _mask_spec(causal: bool, mask: Optional[str], block, half,
+               window=None) -> tuple:
     if mask is None:
         mask = "causal" if causal else "none"
     elif causal and mask != "causal":
         raise ValueError(f"causal=True contradicts mask={mask!r}")
     if mask in ("none", "causal"):
         return (mask,)
+    if mask == "window":
+        if not window or window < 1:
+            raise ValueError(f"mask='window' needs window >= 1; got {window}")
+        return ("window", int(window))
     if mask != "block_diffusion":
-        raise ValueError(f"unknown mask={mask!r}: expected None, 'causal' "
-                         "or 'block_diffusion'")
+        raise ValueError(f"unknown mask={mask!r}: expected None, 'causal', "
+                         "'window' or 'block_diffusion'")
     if not block or not half or block & (block - 1) or half % block:
         raise ValueError("mask='block_diffusion' needs block (a power of "
                          f"two) dividing half; got block={block} half={half}")
@@ -160,6 +175,9 @@ def _tile_live(mask, q_start, k_start, bq, bk):
         return True
     if mask[0] == "causal":
         return k_start <= q_start + bq - 1
+    if mask[0] == "window":     # the lower left corner is the nearest pair
+        return (k_start <= q_start + bq - 1) & (
+            q_start - (k_start + bk - 1) < mask[1])
     q, k = _bd_corners(mask, q_start, bq), _bd_corners(mask, k_start, bk)
     (_, _, qb0, qb1), (_, _, kb0, kb1) = q, k
     # noised/noised: kb == qb; noised/clean: kb < qb; clean/clean: kb <= qb
@@ -172,6 +190,9 @@ def _tile_full(mask, q_start, k_start, bq, bk):
         return True
     if mask[0] == "causal":
         return k_start + bk - 1 <= q_start
+    if mask[0] == "window":     # the upper right and the lower left corner
+        return (k_start + bk - 1 <= q_start) & (
+            q_start + bq - 1 - k_start < mask[1])
     q, k = _bd_corners(mask, q_start, bq), _bd_corners(mask, k_start, bk)
     (_, _, qb0, qb1), (_, _, kb0, kb1) = q, k
     return _bd_rule(q, k, (kb0 == qb0) & (kb1 == qb0) & (qb1 == qb0),
@@ -188,6 +209,8 @@ def _mask_scores(mask, s, q_start, k_start, bq, bk):
     cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     if mask[0] == "causal":
         return jnp.where(cols <= rows, s, _MASKED)
+    if mask[0] == "window":
+        return jnp.where((cols <= rows) & (rows - cols < mask[1]), s, _MASKED)
     _, block, half = mask
     shift = block.bit_length() - 1
     q_clean = (q_start >= half).astype(jnp.int32)
@@ -234,10 +257,39 @@ def _bd_q_tile(mask, jk, jq, bq, bk, nq):
                      jnp.minimum(jnp.maximum(jq, lo2), hi2))
 
 
-def _kernel_name(mask, which):
-    """``flash_bd_fwd`` / ``flash_bd_dq`` / ``flash_bd_dkv``; the other
-    masks' kernels keep the name XLA gives them (module docstring)."""
-    return f"flash_bd_{which}" if mask[0] == "bd" else None
+def _band(mask, n_outer, n_inner, b_outer, b_inner, k_outer):
+    """The inner grid axis under the window mask: (its length, the tile
+    that step ``kk`` of outer tile ``j`` stands for, the tile to fetch
+    for it). The outer axis is the q tiles (``k_outer`` False: a q
+    tile's band is the k tiles from the one holding ``q_start - window +
+    1`` to the one holding its last row) or the k tiles (the q tiles
+    from the one holding ``k_start`` to the one holding ``k_end + window
+    - 1``); the axis is as long as the widest band, a step past the last
+    tile stands for a tile that does not exist (the kernels skip it) and
+    fetches the last. Any other mask: every tile, step ``kk`` is tile
+    ``kk``."""
+    if mask[0] != "window":
+        return n_inner, (lambda j, kk: kk), (lambda j, kk: kk)
+    w = mask[1]
+    if k_outer:
+        first = lambda j: (j * b_outer) // b_inner
+        last = lambda j: np.minimum(
+            (j * b_outer + b_outer + w - 2) // b_inner, n_inner - 1)
+    else:
+        first = lambda j, lib=jnp: lib.maximum(j * b_outer - w + 1, 0) // b_inner
+        last = lambda j: (j * b_outer + b_outer - 1) // b_inner
+    j = np.arange(n_outer)
+    steps = int(np.max(last(j) - (first(j) if k_outer else first(j, np)) + 1))
+    return (steps, lambda j, kk: first(j) + kk,
+            lambda j, kk: jnp.minimum(first(j) + kk, n_inner - 1))
+
+
+def _kernel_name(mask, which, wide=False):
+    """``flash_bd_*`` / ``flash_win_*`` / ``flash_wide_*`` (``fwd``,
+    ``dq``, ``dkv``); the unmasked and causal kernels of one width keep
+    the name XLA gives them (module docstring)."""
+    kind = {"bd": "bd", "window": "win"}.get(mask[0], "wide" if wide else None)
+    return kind and f"flash_{kind}_{which}"
 
 
 # ---------------------------------------------------------------------------
@@ -324,9 +376,9 @@ def _scores(q, k, scale):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc, m_sc, l_sc, *, mask, scale, bq, bk, sq, sk, nk):
+                acc, m_sc, l_sc, *, mask, scale, bq, bk, sq, sk, nk, tile):
     j = pl.program_id(1)
-    kk = pl.program_id(2)
+    kk = pl.program_id(2)       # the kk-th k tile of q tile j's band
 
     @pl.when(kk == 0)
     def _():
@@ -335,7 +387,7 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_sc[:] = jnp.zeros_like(l_sc)
 
     q_start = qo_ref[0] + j * bq
-    k_start = ko_ref[0] + kk * bk
+    k_start = ko_ref[0] + tile(j, kk) * bk
 
     # m and l ride lane-replicated ([bq, LANE]): a sub-tile's visit costs one
     # move across lanes a row (the row maximum); the row sum is kept
@@ -382,39 +434,41 @@ def _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk, sq, sk):
     lk = k3.shape[1]
     group = bh // k3.shape[0]       # query heads per key-value head
     nq, nk = lq // bq, lk // bk
+    dv = v3.shape[2]
+    steps, tile, fetched = _band(mask, nq, nk, bq, bk, False)
     kern = functools.partial(
         _fwd_kernel, mask=mask, scale=scale, bq=bq, bk=bk, sq=sq, sk=sk,
-        nk=nk
+        nk=steps, tile=tile
     )
 
     def kv_map(i, j, kk):
-        return (i // group, _bd_k_tile(mask, j, kk, bq, bk), 0)
+        return (i // group, _bd_k_tile(mask, j, fetched(j, kk), bq, bk), 0)
 
     return pl.pallas_call(
         kern,
-        grid=(bh, nq, nk),
+        grid=(bh, nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((1, bq, dv), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bq, _LANE), lambda i, j, kk: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, lq, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, lq, _LANE), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, _LANE), jnp.float32),
             pltpu.VMEM((bq, _LANE), jnp.float32),
         ],
         interpret=_interpret(),
-        name=_kernel_name(mask, "fwd"),
+        name=_kernel_name(mask, "fwd", dv != d),
     )(q_off, k_off, q3, k3, v3)
 
 
@@ -436,7 +490,7 @@ def _recompute_p(q, k, lse_rep, q0, k0, mask, scale, cut):
 
 def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    dm_ref, dq_ref, dq_acc, *, mask, scale, bq, bk, sq, sk,
-                   nk):
+                   nk, tile):
     j = pl.program_id(1)
     kk = pl.program_id(2)
 
@@ -445,7 +499,7 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_start = qo_ref[0] + j * bq
-    k_start = ko_ref[0] + kk * bk
+    k_start = ko_ref[0] + tile(j, kk) * bk
 
     def visit(i, jj, q0, k0, cut):
         rq, rk = _rows(i, sq), _rows(jj, sk)
@@ -473,10 +527,10 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     dm_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, mask, scale, bq, bk, sq, sk, nq, steps):
+                    *, mask, scale, bq, bk, sq, sk, nq, band, tile, steps):
     jk = pl.program_id(1)
     t = pl.program_id(2)       # (query head of the group, q tile), flattened
-    jq = t % nq
+    jq = tile(jk, t % band)    # the q tile: of the k tile's band
 
     @pl.when(t == 0)
     def _():
@@ -505,8 +559,12 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    # the accumulators belong to the k side: its sub-tiles are the outer loop
-    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
+    # the accumulators belong to the k side: its sub-tiles are the outer
+    # loop; under the window mask the last k tiles' bands end past the
+    # last q tile
+    live = _tile_live(mask, q_start, k_start, bq, bk)
+
+    @pl.when(live & (jq < nq) if mask[0] == "window" else live)
     def _():
         _sweep(mask, q_start, k_start, bq, bk, sq, sk, False, visit)
 
@@ -520,8 +578,12 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
          mask, scale, bq, bk, sq, sk):
     bh, lq, d = q3.shape
     bkv, lk = k3.shape[:2]
+    dv = v3.shape[2]
+    wide = dv != d
     group = bh // bkv
     nq, nk = lq // bq, lk // bk
+    k_steps, k_tile, k_fetched = _band(mask, nq, nk, bq, bk, False)
+    q_steps, q_tile, q_fetched = _band(mask, nk, nq, bk, bq, True)
     # D folds the out-cotangent; the lse-cotangent enters with opposite
     # sign in ds = p * (dp - (D - g_lse)). lse arrives lane-replicated
     # [bh, lq, LANE] (see _fwd); dm rides the same layout so both block
@@ -532,19 +594,20 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
     lse = jnp.broadcast_to(lse[..., None], (bh, lq, _LANE))
 
     def kv_map(i, j, kk):
-        return (i // group, _bd_k_tile(mask, j, kk, bq, bk), 0)
+        return (i // group, _bd_k_tile(mask, j, k_fetched(j, kk), bq, bk), 0)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
-                          bq=bq, bk=bk, sq=sq, sk=sk, nk=nk),
-        grid=(bh, nq, nk),
+                          bq=bq, bk=bk, sq=sq, sk=sk, nk=k_steps,
+                          tile=k_tile),
+        grid=(bh, nq, k_steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((1, bk, dv), kv_map),
+            pl.BlockSpec((1, bq, dv), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bq, _LANE), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bq, _LANE), lambda i, j, kk: (i, j, 0)),
         ],
@@ -552,46 +615,46 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_interpret(),
-        name=_kernel_name(mask, "dq"),
+        name=_kernel_name(mask, "dq", wide),
     )(q_off, k_off, q3, k3, v3, g_out, lse, dm)
 
     # one key-value head gathers from the `group` query heads that read
     # it: the innermost grid axis runs over (head of the group, q tile)
     def q_map(i, jk, t):
-        return (i * group + t // nq,
-                _bd_q_tile(mask, jk, t % nq, bq, bk, nq), 0)
+        return (i * group + t // q_steps, _bd_q_tile(
+            mask, jk, q_fetched(jk, t % q_steps), bq, bk, nq), 0)
 
-    dk, dv = pl.pallas_call(
+    dk, dv_out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
-                          bq=bq, bk=bk, sq=sq, sk=sk, nq=nq,
-                          steps=group * nq),
-        grid=(bkv, nk, group * nq),
+                          bq=bq, bk=bk, sq=sq, sk=sk, nq=nq, band=q_steps,
+                          tile=q_tile, steps=group * q_steps),
+        grid=(bkv, nk, group * q_steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
-            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, dv), lambda i, jk, t: (i, jk, 0)),
+            pl.BlockSpec((1, bq, dv), q_map),
             pl.BlockSpec((1, bq, _LANE), q_map),
             pl.BlockSpec((1, bq, _LANE), q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
+            pl.BlockSpec((1, bk, dv), lambda i, jk, t: (i, jk, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bkv, lk, d), k3.dtype),
-            jax.ShapeDtypeStruct((bkv, lk, d), v3.dtype),
+            jax.ShapeDtypeStruct((bkv, lk, dv), v3.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=_interpret(),
-        name=_kernel_name(mask, "dkv"),
+        name=_kernel_name(mask, "dkv", wide),
     )(q_off, k_off, q3, k3, v3, g_out, lse, dm)
-    return dq, dk, dv
+    return dq, dk, dv_out
 
 
 # ---------------------------------------------------------------------------
@@ -640,6 +703,8 @@ def allowed_pairs(mask: tuple, rows, cols):
         return jnp.ones((rows.shape[0], cols.shape[1]), bool)
     if mask[0] == "causal":
         return cols <= rows
+    if mask[0] == "window":
+        return (cols <= rows) & (rows - cols < mask[1])
     _, block, half = mask
     q_clean, k_clean = rows >= half, cols >= half
     qb, kb = (rows % half) // block, (cols % half) // block
@@ -688,6 +753,18 @@ def _default_block_targets(lq: int, lk: int, causal: bool = False) -> tuple:
     if max(lq, lk) < 1024:
         return 128, 128
     return (1024, 1024) if causal else (512, 1024)
+
+
+def _window_block_targets(length: int) -> tuple:
+    """Grid tiles under the window mask: 512 x 512 from 1024 positions up,
+    each its own sub-tile. Measured on a v5e at a window of 512 in 8,192
+    positions, 40 query over 20 key-value heads of 64 with a 128-wide
+    value, bf16, forward + dq + dk/dv of one layer (``PERF.md`` section 6,
+    PR 31, run K1): 7.52 ms (forward 2.26) at 512 x 512, a band of two k
+    tiles a q tile, both cut; 7.73 at 1024 x 1024 swept in 512 x 512 (two
+    tiles too, each twice as long); 8.45 at 512 x 1024; 9.82 at
+    256 x 512."""
+    return _default_block_targets(length, length) if length < 1024 else (512, 512)
 
 
 def _bd_block_targets(half: int) -> tuple:
@@ -740,11 +817,37 @@ def tile_census(mask: tuple, lq: int, lk: int, bq: int, bk: int,
             "full": int(full.sum())}
 
 
+def flash_tiles(spec: tuple, lq: int, lk: int, dtype,
+                block_q: Optional[int] = None,
+                block_k: Optional[int] = None) -> Optional[dict]:
+    """What ``flash_attention`` does with one head of ``lq x lk`` scores
+    under the mask spec ``spec``: the grid tile, the sub-tile, and the
+    census of sub-tiles by class (the ``attn.flash_tiles`` row of a trace
+    is this dictionary); None where the tiling cannot serve the shape and
+    the dense path runs."""
+    mb = _min_block_for(dtype)
+    dbq, dbk = (_bd_block_targets(spec[2]) if spec[0] == "bd"
+                else _window_block_targets(lq) if spec[0] == "window"
+                else _default_block_targets(lq, lk, spec[0] == "causal"))
+    # a tile of the block-diffusion mask lies within one half
+    tile_q, tile_k = (spec[2], spec[2]) if spec[0] == "bd" else (lq, lk)
+    bq = _pick_block(tile_q, block_q if block_q is not None else dbq, mb)
+    bk = _pick_block(tile_k, block_k if block_k is not None else dbk, mb)
+    if bq is None or bk is None or (spec[0] == "bd"
+                                    and min(bq, bk) <= spec[1]):
+        return None
+    tsq, tsk = _sub_tile_targets(spec, bq, bk)
+    sq, sk = _pick_block(bq, tsq, mb), _pick_block(bk, tsk, mb)
+    return dict(mask=spec[0], block_q=bq, block_k=bk, sub_q=sq, sub_k=sk,
+                **tile_census(spec, lq, lk, bq, bk, sq, sk))
+
+
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = False,
     mask: Optional[str] = None,
     block: Optional[int] = None, half: Optional[int] = None,
+    window: Optional[int] = None,
     scale: Optional[float] = None,
     q_offset=None, k_offset=None,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
@@ -753,10 +856,12 @@ def flash_attention(
     """Tiled attention over ``q`` ``[batch, seq, heads, head_dim]`` and
     ``k``/``v`` ``[batch, seq, kv_heads, head_dim]`` (``kv_heads``
     divides ``heads``; query head ``h`` reads key-value head
-    ``h // (heads // kv_heads)``).
+    ``h // (heads // kv_heads)``; ``v``'s last dimension may differ from
+    ``q``'s and ``k``'s, and is the output's).
 
     ``mask`` is ``None`` (all pairs, or causal with ``causal=True``),
-    ``'causal'`` or ``'block_diffusion'`` with static ``block`` and
+    ``'causal'``, ``'window'`` with a static ``window`` (``0 <= q_pos -
+    k_pos < window``) or ``'block_diffusion'`` with static ``block`` and
     ``half`` (see the module docstring). ``q_offset``/``k_offset`` (int
     scalars, may be traced) place the q/k blocks in global sequence
     coordinates for the causal mask — ring attention passes its rotating
@@ -769,7 +874,11 @@ def flash_attention(
     if h % kvh or v.shape[2] != kvh:
         raise ValueError(f"{h} query heads over {kvh} / {v.shape[2]} "
                          "key / value heads")
-    spec = _mask_spec(causal, mask, block, half)
+    spec = _mask_spec(causal, mask, block, half, window)
+    if spec[0] == "window" and (q_offset is not None or k_offset is not None
+                                or lk != lq):
+        raise ValueError("mask='window' covers one whole sequence: q and k "
+                         f"of one length, no offsets; got {lq} and {lk}")
     if spec[0] == "bd" and (q_offset is not None or k_offset is not None
                             or lq != 2 * spec[2] or lk != lq):
         raise ValueError("mask='block_diffusion' covers the whole doubled "
@@ -780,34 +889,24 @@ def flash_attention(
     q_offset = jnp.zeros((), jnp.int32) if q_offset is None else q_offset
     k_offset = jnp.zeros((), jnp.int32) if k_offset is None else k_offset
 
-    mb = _min_block_for(q.dtype)
-    dbq, dbk = (_bd_block_targets(spec[2]) if spec[0] == "bd"
-                else _default_block_targets(lq, lk, spec[0] == "causal"))
-    # a tile of the block-diffusion mask lies within one half
-    tile_q, tile_k = (spec[2], spec[2]) if spec[0] == "bd" else (lq, lk)
-    bq = _pick_block(tile_q, block_q if block_q is not None else dbq, mb)
-    bk = _pick_block(tile_k, block_k if block_k is not None else dbk, mb)
-    if bq is None or bk is None or (spec[0] == "bd"
-                                    and min(bq, bk) <= spec[1]):
+    plan = flash_tiles(spec, lq, lk, q.dtype, block_q, block_k)
+    if plan is None:
         out, lse = _attention_jnp(q, k, v, q_offset, k_offset, spec, scale)
         return (out, lse) if return_lse else out
-
-    tsq, tsk = _sub_tile_targets(spec, bq, bk)
-    sq, sk = _pick_block(bq, tsq, mb), _pick_block(bk, tsk, mb)
+    bq, bk, sq, sk = (plan[key] for key in ("block_q", "block_k", "sub_q",
+                                            "sub_k"))
     rec = get_recorder()
     if rec is not None:     # how often the sweep engages, once a trace
-        rec.event("attn.flash_tiles", mask=spec[0], block_q=bq, block_k=bk,
-                  sub_q=sq, sub_k=sk,
-                  **tile_census(spec, lq, lk, bq, bk, sq, sk))
+        rec.event("attn.flash_tiles", **plan)
 
     def to3(x):
-        return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], x.shape[3])
 
     q_off = jnp.broadcast_to(q_offset, (1,)).astype(jnp.int32)
     k_off = jnp.broadcast_to(k_offset, (1,)).astype(jnp.int32)
     out3, lse3 = _flash(to3(q), to3(k), to3(v), q_off, k_off,
                         spec, float(scale), (bq, bk), (sq, sk))
-    out = out3.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
+    out = out3.reshape(b, h, lq, v.shape[3]).transpose(0, 2, 1, 3)
     if not return_lse:
         return out
     return out, lse3[..., 0].reshape(b, h, lq)

@@ -66,6 +66,7 @@ from pytorch_ps_mpi_tpu.mesh import DATA_AXIS, make_mesh
 from pytorch_ps_mpi_tpu.optim import (
     OPTIMIZERS,
     AdafactorState,
+    AdamState,
     adafactor_check_sharding,
     adafactor_state_specs,
     adafactor_update,
@@ -734,6 +735,9 @@ class MPI_PS:
         self._loss_reduction = loss_reduction
         hyper_cls, init_state, update_fn = OPTIMIZERS[optim]
         self.hyper = hyper_cls(**hyper)
+        if optim == "adam":  # the AMSGrad maximum exists only where it is used
+            init_state = functools.partial(init_state,
+                                           amsgrad=self.hyper.amsgrad)
         self._update_fn = update_fn
         self.params = params
         self.code = code if code is not None else IdentityCodec()
@@ -2112,7 +2116,17 @@ class MPI_PS:
                              if isinstance(v, (int, float, str)))
         return loss, data
 
-    def state_dict(self) -> Dict[str, Any]:
+    def _map_adam(self, fn, opt_state=None):
+        """``opt_state`` (default: this optimizer's) with ``fn`` applied
+        to its ``AdamState``, bare or inside leader mode's state."""
+        state = self.opt_state if opt_state is None else opt_state
+        if isinstance(state, AdamState):
+            return fn(state)
+        if isinstance(getattr(state, "inner", None), AdamState):
+            return state._replace(inner=fn(state.inner))
+        return state
+
+    def state_dict(self, legacy_adam: bool = False) -> Dict[str, Any]:
         """Checkpointable state in this repo's schema (params/opt_state/
         codec_state/aux_state/step_count/rng) — the role of torch's
         ``Optimizer.state_dict()`` (which the reference inherited but never
@@ -2120,10 +2134,15 @@ class MPI_PS:
         ``state``/``param_groups`` layout and the dict holds live array
         references, not copies, so it is not interchangeable with torch
         checkpoints. Pair with ``utils.checkpoint.CheckpointManager`` for
-        sharded on-disk saves."""
+        sharded on-disk saves. ``legacy_adam`` gives the shape of a
+        checkpoint written when Adam's state always held the AMSGrad
+        maximum (a restore template; ``load_state_dict`` drops the dead
+        tree again)."""
         return {
             "params": self.params,
-            "opt_state": tuple(self.opt_state),
+            "opt_state": tuple(self._map_adam(
+                lambda s: s._replace(max_exp_avg_sq=s.exp_avg_sq)
+                if legacy_adam and s.max_exp_avg_sq == () else s)),
             "codec_state": self.codec_state,
             "aux_state": self.aux_state,
             "step_count": self._step_count,
@@ -2164,9 +2183,11 @@ class MPI_PS:
 
     def load_state_dict(self, sd: Dict[str, Any]) -> None:
         self.params = self._decommit_restored(sd["params"])
-        self.opt_state = type(self.opt_state)(
-            *self._decommit_restored(tuple(sd["opt_state"]))
-        )
+        self.opt_state = self._map_adam(
+            lambda s: s if self.hyper.amsgrad else s._replace(
+                max_exp_avg_sq=()),
+            type(self.opt_state)(
+                *self._decommit_restored(tuple(sd["opt_state"]))))
         self.codec_state = self._decommit_restored(sd["codec_state"])
         self.aux_state = self._decommit_restored(sd.get("aux_state"))
         self._place_state()  # or the next step is compiled again

@@ -59,12 +59,12 @@ class Trainer:
         )
 
     # -- checkpoint / resume ------------------------------------------------
-    def _state(self) -> Dict[str, PyTree]:
+    def _state(self, **kw) -> Dict[str, PyTree]:
         # Delegate to the optimizer's own state_dict so checkpoints carry
         # everything it considers state — including the PRNG stream
         # (stochastic codecs replay keys on resume) and aux_state (BN
         # batch_stats), not just params/opt_state.
-        sd = dict(self.opt.state_dict())
+        sd = dict(self.opt.state_dict(**kw))
         sd["trainer_step"] = jnp.asarray(self.step_count)
         if sd.get("aux_state") is None:
             sd.pop("aux_state")  # pytree restore needs a stable structure
@@ -85,7 +85,11 @@ class Trainer:
         if self.ckpt is None or self.ckpt.latest_step() is None:
             return False
         try:
-            state = self.ckpt.restore(self._state())
+            try:
+                state = self.ckpt.restore(self._state())
+            except Exception:
+                # written when Adam's state always held the AMSGrad maximum
+                state = self.ckpt.restore(self._state(legacy_adam=True))
         except Exception as e:
             import sys
 

@@ -373,7 +373,7 @@ def test_grouped_heads_under_the_old_masks(causal):
 @pytest.mark.parametrize("kw, match", [
     (dict(mask="block_diffusion", block=3, half=32), "power of"),
     (dict(mask="block_diffusion", block=4, half=16), "whole doubled"),
-    (dict(mask="window"), "unknown mask"),
+    (dict(mask="band"), "unknown mask"),
     (dict(mask="block_diffusion", block=4, half=32, causal=True), "contradicts"),
 ])
 def test_mask_arguments_are_checked(kw, match):

@@ -133,6 +133,46 @@ def test_block_diffusion_kernels_compile(v5e, half, heads, kv_heads, d):
         assert name in text, name
 
 
+@pytest.mark.parametrize("mask, names", [
+    (dict(mask="window", window=512), "flash_win"),   # 512x512 tiles, band of 2
+    (dict(causal=True), "flash_wide"),                # 1024x1024 swept in 512x512
+    (dict(mask="window", window=512, block_q=1024, block_k=1024), "flash_win"),
+])
+def test_differential_attention_kernels_compile(v5e, mask, names):
+    """phi4-mini-flash.lm8k: 40 softmax maps over 20 of 8,192 x 64 with the
+    128-wide joined value, under the window and the causal mask, each
+    kernel under its own name."""
+    def loss(q, k, v):
+        out = attention_pallas.flash_attention(q, k, v, **mask)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = compile_for(v5e, jax.grad(loss, (0, 1, 2)),
+                       ((1, 8192, 40, 64), jnp.bfloat16),
+                       ((1, 8192, 20, 64), jnp.bfloat16),
+                       ((1, 8192, 20, 128), jnp.bfloat16))
+    for which in ("fwd", "dq", "dkv"):
+        assert f"{names}_{which}" in text, which
+
+
+def test_selective_scan_compiles_at_the_cells_size(v5e):
+    """Forward and backward of the chunked scan at T 8,192, E 5,120, N 16
+    (XLA's loops, no kernel): the state of every step never exists at
+    once — the program's temporaries stay far under the 2.7 GB of
+    ``[T, E, N]``."""
+    from pytorch_ps_mpi_tpu.ops.selective_scan import selective_scan
+
+    steps, width, n = 8192, 5120, 16
+    dev = SingleDeviceSharding(v5e)
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=dev)
+    compiled = jax.jit(jax.grad(
+        lambda *z: jnp.sum(selective_scan(*z)), range(6))).lower(
+        sds((1, steps, width), jnp.bfloat16), sds((1, steps, width)),
+        sds((width, n)), sds((1, steps, n)), sds((1, steps, n)),
+        sds((width,))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 def test_grouped_products_compile_to_kernels(v5e):
     """``jax.lax.ragged_dot`` forward and both transposes at the cell's
     expert widths: XLA:TPU's own Mosaic kernels, no dense fallback."""
