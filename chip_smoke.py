@@ -480,8 +480,9 @@ def phase_kernels(dry_run: bool) -> dict:
     done.append(f"exact_topk[{n}]")
 
     # flash attention forward and backward at the models' shapes: BERT
-    # b16 s512 (128x128 tiles) and GPT-2-small b8 s1024 (unmasked: one
-    # 512x1024 tile a grid step; causal: 1024x1024 swept in 512x512)
+    # b16 s512 (a head is one 512x512 tile) and GPT-2-small b8 s1024
+    # (unmasked: one 512x1024 tile a grid step; causal: 1024x1024 swept
+    # in 512x512)
     for shape in ([(1, 128, 2, 64)] if dry_run
                   else [(16, 512, 12, 64), (8, 1024, 12, 64)]):
         for causal in (False, True):

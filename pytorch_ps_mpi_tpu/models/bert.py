@@ -83,9 +83,12 @@ class SelfAttention(nn.Module):
         elif c.attention in ("full", "flash", "einsum"):
             # 'flash': always the Pallas kernel (interpret mode off-TPU —
             # for tests). 'full': on TPU the kernel for sequences of
-            # FLASH_MIN_SEQ and up that tile, the dense einsum otherwise
-            # (ops/attention_pallas.flash_auto_ok). 'einsum': force the
-            # dense path.
+            # FLASH_MIN_SEQ (512) and up that tile, the dense einsum
+            # otherwise (ops/attention_pallas.flash_auto_ok). 'einsum':
+            # force the dense path. Read on a v5e: at 1024 the kernel
+            # wins (127,316 tokens/s against 100,636, gpt2-small), at 512
+            # the einsum still does (159,413 against 149,534,
+            # bert-base.mlm512, PR 32): the floor is ROADMAP D4 (b)'s.
             from pytorch_ps_mpi_tpu.ops.attention_pallas import (
                 flash_attention,
                 flash_auto_ok,
@@ -103,7 +106,10 @@ class SelfAttention(nn.Module):
                 )
             # 'full' takes the kernel from FLASH_MIN_SEQ up, where the
             # O(L^2) score matrix dominates; below it XLA's fused dense
-            # attention batches the heads' matmuls on the MXU
+            # attention batches the heads' matmuls on the MXU (a layer
+            # of 16 x 12 heads of 512 x 64, forward and backward: 0.796
+            # ms against the kernels' 0.848 plus 0.577 of transposes and
+            # logsumexp rides around them; PERF.md section 6, PR 32)
             use_kernel = c.attention == "flash" or (
                 c.attention == "full" and flash_auto_ok(l, l, c.dtype)
             )

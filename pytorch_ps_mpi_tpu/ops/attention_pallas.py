@@ -20,6 +20,14 @@ Design choices:
   same compiled kernel serves dense attention (offsets 0) and ring
   attention's rotating blocks (``parallel/ring.py`` passes the block's
   traced global offset; a fully-future block masks itself to nothing).
+- **The grid tile** (what one grid step holds resident) is as large as
+  the chip numbers allow, because every grid step pays for its own
+  start and DMAs (~0.5 us, ten times the MXU work of a 128 x 128 tile
+  at head_dim 64): 1024 x 1024 under a mask, 512 x 1024 without one,
+  and under 1024 positions the largest power of two that divides the
+  length, so a head of 512 x 64 is ONE grid step a kernel (0.848 ms a
+  layer of 16 x 12 such heads against 4.608 in 128 x 128 tiles;
+  ``_default_block_targets`` holds every reading).
 - **A sub-tile sweep inside each grid step** (``_sweep``, all three
   kernels). The grid tile sets the DMAs and the number of grid steps;
   the work inside it is done sub-tile by sub-tile in a rolled loop over
@@ -100,9 +108,18 @@ _MASK_THRESH = -1e29   # "this score was masked" test (real scores are tiny)
 # the kernel. XLA's fused dense attention suits short sequences — its
 # matmuls batch across heads on the MXU while the kernel pays a
 # sequential batch*heads grid — and the kernel takes over where O(L^2)
-# score materialization dominates. 512: no chip number; see PERF.md
-# section 7. Overridable for re-measurement on other chip generations
-# (FLASH_MIN_SEQ env var).
+# score materialization dominates. Both sides read on a v5e (PERF.md
+# section 7, D4 (b)): at 1024 the kernels win (gpt2-small.lm1024:
+# 127,316 tokens/s against 100,636 with attention='einsum', PR 30). AT
+# 512 THEY STILL LOSE, by 6 %: bert-base.mlm512 reads 149,534 tokens/s
+# with the kernels at one 512 x 512 tile a head and 159,413 with
+# attention='einsum' (PR 32; 81,913 at the 128 x 128 tile before it);
+# a layer alone, forward and backward, 16 x 12 heads of 64: 1.425 ms
+# (kernels 0.848, the layout transposes and logsumexp rides around
+# them 0.577) against XLA's 0.796; at 256 1.577 against 0.345, at 768
+# 5.321 against 3.419. 512 stays until a PR of its own moves the floor
+# (ROADMAP D4 (b)). Overridable for re-measurement on other chip
+# generations (FLASH_MIN_SEQ env var).
 import os as _os
 
 FLASH_MIN_SEQ = int(_os.environ.get("FLASH_MIN_SEQ", "512"))
@@ -738,45 +755,66 @@ def _attention_jnp(q, k, v, q_offset, k_offset, mask, scale):
     return out, lse
 
 
-def _default_block_targets(lq: int, lk: int, causal: bool = False) -> tuple:
+def _default_block_targets(causal: bool = False) -> tuple:
     """The grid tile (what a grid step holds resident: its DMAs and the
-    number of grid steps). 128 x 128 below sequence 1024 (no chip number;
-    ``PERF.md`` section 7). From 1024 up, measured on a v5e at GPT-2's
-    shape (8 x 12 heads, 1024 x 64, bf16; forward + dq + dk/dv of one
-    layer, ``PERF.md`` section 6, PR 30): causal 1.215 ms at 1024 x 1024
-    against 1.250 at 512 x 1024 (both swept in 512 x 512 sub-tiles;
-    1.337 against 1.391 at 2 x 16 heads of 2048 x 128): the whole k/v of
-    a 1024-long head stays resident and a head is one grid step, which
-    the scores no longer forbid because only a sub-tile of them is ever in
-    flight. Unmasked: 512 x 1024 (1.451 ms, the tile as one sub-tile;
-    1.531 at 1024 x 1024 swept in 512 x 512)."""
-    if max(lq, lk) < 1024:
-        return 128, 128
+    number of grid steps), as TARGETS: ``_pick_block`` clamps each to the
+    largest power of two that divides its length, so the mask alone
+    decides here and the lengths decide there. From 1024 up, measured on
+    a v5e at GPT-2's shape (8 x 12 heads, 1024 x 64, bf16; forward + dq +
+    dk/dv of one layer, ``PERF.md`` section 6, PR 30): causal 1.215 ms at
+    1024 x 1024 against 1.250 at 512 x 1024 (both swept in 512 x 512
+    sub-tiles; 1.337 against 1.391 at 2 x 16 heads of 2048 x 128): the
+    whole k/v of a 1024-long head stays resident and a head is one grid
+    step, which the scores no longer forbid because only a sub-tile of
+    them is ever in flight. Unmasked: 512 x 1024 (1.451 ms, the tile as
+    one sub-tile; 1.531 at 1024 x 1024 swept in 512 x 512).
+
+    Under 1024 the same targets, clamped, are the whole head at 512 and
+    256 and 256 x 256 at 768; there was a 128 x 128 tier here, without a
+    chip number, until PR 32 measured it (``PERF.md`` section 6, run K1:
+    the three kernels' events in a device trace, ms a layer, bf16, heads
+    of 64; the whole program with its transposes and logsumexp rides
+    costs 0.577 more at 512 and 256, 0.865 at 768). 16 x 12 heads of 512,
+    unmasked (``bert-base.mlm512``): **0.848 at 512 x 512**, 1.136 at
+    256 x 512, 1.295 at 512 x 256, 1.961 at 256 x 256, 4.608 at
+    128 x 128 (0.50 us a grid step for 0.05 us of MXU work). Causal
+    (ring's and ulysses' blocks, ``attention='flash'``): **0.882**,
+    1.207, 1.297, 1.906, 4.160: one CUT sub-tile that masks every score
+    beats four tiles of which one is skipped. 32 x 12 heads of 256:
+    **0.999 at 256 x 256**, 1.455 at 256 x 128, 1.470 at 128 x 256, 2.488
+    at 128 x 128 (causal 1.058, 1.492, 1.556, 2.457). 16 x 12 heads of
+    768, where 256 is the largest power of two that divides: **4.456 at
+    256 x 256**, 6.371, 6.495, 10.576 (causal 3.989, 5.782, 5.883,
+    9.170). XLA's own attention reads 0.796 / 0.345 / 3.419 at 512 / 256
+    / 768: see ``FLASH_MIN_SEQ``."""
     return (1024, 1024) if causal else (512, 1024)
 
 
-def _window_block_targets(length: int) -> tuple:
-    """Grid tiles under the window mask: 512 x 512 from 1024 positions up,
-    each its own sub-tile. Measured on a v5e at a window of 512 in 8,192
-    positions, 40 query over 20 key-value heads of 64 with a 128-wide
-    value, bf16, forward + dq + dk/dv of one layer (``PERF.md`` section 6,
-    PR 31, run K1): 7.52 ms (forward 2.26) at 512 x 512, a band of two k
-    tiles a q tile, both cut; 7.73 at 1024 x 1024 swept in 512 x 512 (two
-    tiles too, each twice as long); 8.45 at 512 x 1024; 9.82 at
-    256 x 512."""
-    return _default_block_targets(length, length) if length < 1024 else (512, 512)
+def _window_block_targets() -> tuple:
+    """Grid tiles under the window mask: 512 x 512, each its own
+    sub-tile; under 1024 positions it follows ``_default_block_targets``
+    (the clamp gives the tiles measured there). Measured on a v5e at a
+    window of 512 in 8,192 positions, 40 query over 20 key-value heads of
+    64 with a 128-wide value, bf16, forward + dq + dk/dv of one layer
+    (``PERF.md`` section 6, PR 31, run K1): 7.52 ms (forward 2.26) at
+    512 x 512, a band of two k tiles a q tile, both cut; 7.73 at
+    1024 x 1024 swept in 512 x 512 (two tiles too, each twice as long);
+    8.45 at 512 x 1024; 9.82 at 256 x 512."""
+    return 512, 512
 
 
-def _bd_block_targets(half: int) -> tuple:
-    """Grid tiles under the block-diffusion mask: 1024 x 1024. At 2 x 4096
-    positions, 32 query over 4 key-value heads of 128, bf16, 2 rows, on a
-    v5e (``PERF.md`` section 6): before the sub-tile sweep the three
-    kernels took 30.5 ms at 1024 x 1024, 32.4 at 512 x 1024, 36.9 at
-    512 x 512, 47.2 at 256 x 512 (PR 27: smaller GRID tiles lose, each
-    pays its own grid step and DMAs); with the sweep at 256 x 256
-    sub-tiles 51.4 at 1024 x 1024, 51.8 at 2048 x 1024, 50.8 at
-    2048 x 2048 (PR 30: a larger grid tile returns 1 %, not kept)."""
-    return (1024, 1024) if half >= 1024 else _default_block_targets(half, half)
+def _bd_block_targets() -> tuple:
+    """Grid tiles under the block-diffusion mask: 1024 x 1024; a half
+    under 1024 positions follows ``_default_block_targets`` (the clamp
+    gives the tiles measured there). At 2 x 4096 positions, 32 query over
+    4 key-value heads of 128, bf16, 2 rows, on a v5e (``PERF.md`` section
+    6): before the sub-tile sweep the three kernels took 30.5 ms at
+    1024 x 1024, 32.4 at 512 x 1024, 36.9 at 512 x 512, 47.2 at
+    256 x 512 (PR 27: smaller GRID tiles lose, each pays its own grid
+    step and DMAs); with the sweep at 256 x 256 sub-tiles 51.4 at
+    1024 x 1024, 51.8 at 2048 x 1024, 50.8 at 2048 x 2048 (PR 30: a
+    larger grid tile returns 1 %, not kept)."""
+    return 1024, 1024
 
 
 def _sub_tile_targets(mask: tuple, bq: int, bk: int) -> tuple:
@@ -826,9 +864,9 @@ def flash_tiles(spec: tuple, lq: int, lk: int, dtype,
     is this dictionary); None where the tiling cannot serve the shape and
     the dense path runs."""
     mb = _min_block_for(dtype)
-    dbq, dbk = (_bd_block_targets(spec[2]) if spec[0] == "bd"
-                else _window_block_targets(lq) if spec[0] == "window"
-                else _default_block_targets(lq, lk, spec[0] == "causal"))
+    dbq, dbk = (_bd_block_targets() if spec[0] == "bd"
+                else _window_block_targets() if spec[0] == "window"
+                else _default_block_targets(spec[0] == "causal"))
     # a tile of the block-diffusion mask lies within one half
     tile_q, tile_k = (spec[2], spec[2]) if spec[0] == "bd" else (lq, lk)
     bq = _pick_block(tile_q, block_q if block_q is not None else dbq, mb)

@@ -246,25 +246,23 @@ def test_multi_tile_backward_both_masks_odd_heads(causal):
 
 
 def test_default_block_targets_tiers():
-    """Tile policy as measured on the chip (PR 30). Grid tiles: 128x128
-    below seq 1024; from there 512x1024 unmasked, 1024x1024 causal.
+    """Tile policy as measured on the chip (PRs 30 and 32). Grid tiles:
+    512x1024 unmasked, 1024x1024 causal or block-diffusion, at every
+    length: under 1024 ``_pick_block`` clamps them to the largest power
+    of two that divides the length, so a head of 512 is one grid step.
     Sub-tiles: 512x512 under a mask, the tile itself without one."""
     from pytorch_ps_mpi_tpu.ops.attention_pallas import (
         _bd_block_targets, _default_block_targets, _min_block_for,
-        _pick_block, _sub_tile_targets)
+        _pick_block, _sub_tile_targets, _window_block_targets)
 
-    assert _default_block_targets(128, 128) == (128, 128)
-    assert _default_block_targets(512, 512, causal=True) == (128, 128)
-    assert _default_block_targets(1024, 1024) == (512, 1024)
-    assert _default_block_targets(1024, 1024, causal=True) == (1024, 1024)
-    assert _default_block_targets(8192, 8192) == (512, 1024)
-    # cross-length (ring attention blocks): max drives the tier
-    assert _default_block_targets(512, 2048) == (512, 1024)
-    assert _bd_block_targets(4096) == (1024, 1024)
-    assert _bd_block_targets(256) == (128, 128)
+    assert _default_block_targets(False) == (512, 1024)
+    assert _default_block_targets(True) == (1024, 1024)
+    assert _bd_block_targets() == (1024, 1024)
+    assert _window_block_targets() == (512, 512)
     assert _sub_tile_targets(("causal",), 1024, 1024) == (512, 512)
     assert _sub_tile_targets(("bd", 4, 4096), 1024, 1024) == (512, 512)
     assert _sub_tile_targets(("none",), 512, 1024) == (512, 1024)
+    assert _sub_tile_targets(("none",), 512, 512) == (512, 512)
 
     # divisibility degradation: targets cap, never break tiling
     mb = _min_block_for(jnp.float32)
@@ -272,6 +270,138 @@ def test_default_block_targets_tiers():
     assert _pick_block(1536, 1024, mb) == 512  # largest pow2 divisor
     assert _pick_block(1280, 512, mb) == 256   # 1280 = 5*256
     assert _pick_block(96, 128, mb) == 32
+    assert _pick_block(512, 1024, mb) == 512   # a short head: the whole of it
+
+
+# -- the grid tile under sequence 1024 (PR 32) ---------------------------------
+
+def _spec_id(spec):
+    return "-".join(str(x) for x in spec)
+
+
+@pytest.mark.parametrize("spec", [("none",), ("causal",), ("window", 128),
+                                  ("bd", 4, 512)], ids=_spec_id)
+def test_a_head_of_512_is_one_grid_step(spec):
+    """At 512 positions (a half of 512 under the block-diffusion mask) the
+    grid tile is the whole head, its own 512 x 512 sub-tile: one grid step
+    a head a kernel where 128 x 128 paid sixteen. Unmasked, the cell
+    ``bert-base.mlm512``'s shape, that one sub-tile is FULL: a visit is
+    straight-line code with no mask."""
+    from pytorch_ps_mpi_tpu.ops.attention_pallas import flash_tiles
+
+    length = 1024 if spec[0] == "bd" else 512
+    plan = flash_tiles(spec, length, length, jnp.bfloat16)
+    assert {k: plan[k] for k in ("block_q", "block_k", "sub_q", "sub_k")} == {
+        "block_q": 512, "block_k": 512, "sub_q": 512, "sub_k": 512}
+    assert plan["dead"] + plan["cut"] + plan["full"] == (length // 512) ** 2
+    if spec[0] == "none":
+        assert plan == {"mask": "none", "block_q": 512, "block_k": 512,
+                        "sub_q": 512, "sub_k": 512, "dead": 0, "cut": 0,
+                        "full": 1}
+    if spec[0] == "causal":         # the diagonal passes through the one tile
+        assert (plan["dead"], plan["cut"], plan["full"]) == (0, 1, 0)
+
+
+def test_the_recorder_row_at_mlm512s_shape():
+    """The counter of the mechanism: traced at ``bert-base.mlm512``'s head
+    (512 x 64, bf16, unmasked) with the recorder on, ``flash_attention``
+    writes one ``attn.flash_tiles`` row that reads 512 / 512 / 512 / 512
+    and ``full`` 1."""
+    from pytorch_ps_mpi_tpu import telemetry
+
+    q = jnp.zeros((1, 512, 1, 64), jnp.bfloat16)
+    rec = telemetry.configure()
+    try:
+        jax.eval_shape(lambda q: flash_attention(q, q, q), q)
+        rows = [e["attrs"] for e in rec.events()
+                if e["name"] == "attn.flash_tiles"]
+    finally:
+        telemetry.disable()
+    assert rows == [{"mask": "none", "block_q": 512, "block_k": 512,
+                     "sub_q": 512, "sub_k": 512, "dead": 0, "cut": 0,
+                     "full": 1}]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("lq, lk, want", [
+    (256, 256, (256, 256)), (384, 384, (128, 128)), (768, 768, (256, 256)),
+    (512, 256, (512, 256)),     # ring's and ulysses' blocks may differ
+    (640, 640, (128, 128)), (96, 96, (32, 32)),
+])
+def test_short_lengths_take_tiles_that_divide_them(lq, lk, want, causal):
+    """Under 1024 the targets are clamped to the largest power of two
+    that divides each length, whatever the mask."""
+    from pytorch_ps_mpi_tpu.ops.attention_pallas import flash_tiles
+
+    plan = flash_tiles(("causal",) if causal else ("none",), lq, lk,
+                       jnp.bfloat16)
+    assert (plan["block_q"], plan["block_k"]) == want
+    assert lq % plan["block_q"] == 0 and lk % plan["block_k"] == 0
+    assert plan["block_q"] % plan["sub_q"] == 0
+    assert plan["block_k"] % plan["sub_k"] == 0
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("length", [256, 512])
+def test_default_tile_matches_dense_at_short_lengths(length, causal):
+    """Forward, logsumexp and both gradients at the DEFAULT tile of the
+    tier under 1024 (the whole head at 256 and 512: one visit where the
+    accumulation over k took two or four) against the dense oracle, in
+    bf16 as the cell runs it."""
+    ks = jax.random.split(jax.random.key(length + causal), 4)
+    q, k, v, w = (jax.random.normal(kk, (1, length, 2, 16), jnp.bfloat16)
+                  for kk in ks)
+
+    def run(attend):
+        def total(q, k, v):
+            o, lse = attend(q, k, v)
+            return (jnp.sum(w.astype(jnp.float32) * o)
+                    + jnp.sum(jnp.sin(lse))), (o, lse)
+
+        return jax.jit(jax.value_and_grad(total, (0, 1, 2),
+                                          has_aux=True))(q, k, v)
+
+    (_, (o, lse)), got = run(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, return_lse=True))
+    (_, (o_ref, lse_ref)), want = run(lambda q, k, v: _attention_jnp(
+        q, k, v, 0, 0, causal, q.shape[-1] ** -0.5))
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    np.testing.assert_allclose(f32(o), f32(o_ref), rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                               rtol=1e-4, atol=1e-4)
+    for g, wnt in zip(got, want):
+        np.testing.assert_allclose(f32(g), f32(wnt), rtol=3e-2, atol=3e-2)
+
+
+@pytest.mark.parametrize("spec, length, dtype, want", [
+    # gpt2-small.lm1024
+    (("causal",), 1024, jnp.bfloat16, dict(
+        block_q=1024, block_k=1024, sub_q=512, sub_k=512, dead=1, cut=2,
+        full=1)),
+    # sdar-30b-a3b.bd4k
+    (("bd", 4, 4096), 8192, jnp.bfloat16, dict(
+        block_q=1024, block_k=1024, sub_q=512, sub_k=512, dead=176, cut=24,
+        full=56)),
+    # phi4-mini-flash.lm8k: the two T x T layers, the window layer
+    (("causal",), 8192, jnp.bfloat16, dict(
+        block_q=1024, block_k=1024, sub_q=512, sub_k=512, dead=120, cut=16,
+        full=120)),
+    (("window", 512), 8192, jnp.bfloat16, dict(
+        block_q=512, block_k=512, sub_q=512, sub_k=512, dead=225, cut=31,
+        full=0)),
+    # unmasked from 1024 up (no cell): the tile is its own sub-tile
+    (("none",), 1024, jnp.bfloat16, dict(
+        block_q=512, block_k=1024, sub_q=512, sub_k=1024, dead=0, cut=0,
+        full=2)),
+], ids=lambda x: _spec_id(x) if isinstance(x, tuple) else None)
+def test_the_other_cells_plans_are_the_parents(spec, length, dtype, want):
+    """From 1024 up nothing moved with PR 32: the plans at the shapes of
+    ``gpt2-small.lm1024``, ``sdar-30b-a3b.bd4k`` and
+    ``phi4-mini-flash.lm8k`` are what the parent commit computed."""
+    from pytorch_ps_mpi_tpu.ops.attention_pallas import flash_tiles
+
+    assert flash_tiles(spec, length, length, dtype) == dict(
+        mask=spec[0], **want)
 
 
 def test_flash_auto_ok_false_off_tpu():

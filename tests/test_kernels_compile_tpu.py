@@ -95,8 +95,10 @@ def test_exact_topk_compiles(v5e):
 
 
 @pytest.mark.parametrize("seq,dtype,d,causal", [
-    (512, jnp.bfloat16, 64, True),     # 128x128 tiles
-    (512, jnp.bfloat16, 64, False),
+    (512, jnp.bfloat16, 64, True),     # 512x512: a head is one grid step
+    (512, jnp.bfloat16, 64, False),    # bert-base.mlm512's kernels
+    (512, jnp.float32, 128, True),     # the same tile at 4 bytes, 128 wide
+    (768, jnp.bfloat16, 64, False),    # 256x256: the largest that divides
     (1024, jnp.bfloat16, 64, True),    # 1024x1024 tiles swept in 512x512
     (1024, jnp.float32, 128, True),
     (1536, jnp.bfloat16, 64, False),   # 512x512: the k target degrades
