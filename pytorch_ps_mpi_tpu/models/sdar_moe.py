@@ -138,12 +138,15 @@ def rms_norm(x, gain, eps: float):
     return (y * gain).astype(x.dtype)
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, freq=None):
     """``x [b, s, heads, head_dim]`` rotated at ``positions [s]``: pairs
     (i, i + head_dim / 2) by the angle position * theta^(-2i / head_dim)
-    (the rotate-half form of the family's modelling code)."""
+    (the rotate-half form of the family's modelling code), or by
+    position * ``freq[i]`` where a caller brings its own frequencies
+    (``models/xing.py``: YaRN's)."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freq is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
     x32 = x.astype(jnp.float32)

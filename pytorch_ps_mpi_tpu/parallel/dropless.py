@@ -16,7 +16,9 @@ x top_k x count / n_experts in expectation), never to positions x
 top_k; the moves' work to the buffer's rows (a stated multiple of that
 expectation) and, for the sum back, to the positions' slots:
 
-1. ``route``: float32 softmax over all experts, top-k, renormalised.
+1. ``route``: float32 softmax over all experts, top-k, renormalised; or
+   sigmoid scores, the choice by score + a frozen bias, the gates from
+   the unbiased scores (``scoring='sigmoid'``).
 2. ``dispatch_plan``: the held pairs sorted by expert (two argsorts of
    the positions x top_k expert ids; no scatter), the first ``capacity``
    rows of that order being the buffer. ``capacity`` is a stated multiple
@@ -74,13 +76,32 @@ def capacity_rows(positions: int, top_k: int, n_experts: int, count: int,
     return min(worst, 8 * math.ceil(capacity_factor * expected / 8))
 
 
-def route(x, w_router, top_k: int, norm_topk_prob: bool = True):
+def route(x, w_router, top_k: int, norm_topk_prob: bool = True, *,
+          scoring: str = "softmax", bias=None, scaling: float = 1.0):
     """``x [P, d]`` -> gate weights ``[P, top_k]`` float32 and expert ids
     ``[P, top_k]``. The router runs in float32 at full precision: a bf16
-    product moves which expert is 8th and which 9th."""
+    product moves which expert is 8th and which 9th.
+
+    ``scoring='sigmoid'`` is the bias-corrected router: scores ``s =
+    sigmoid(x W_r)``, the experts chosen by ``top_k(s + bias)`` (``bias
+    [n_experts]`` steers the choice only and takes no gradient), the gate
+    weights the UNBIASED scores of the chosen experts, normalised over
+    them (``norm_topk_prob``) and times ``scaling``."""
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        if scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            choice = scores if bias is None else (
+                scores + jax.lax.stop_gradient(bias.astype(jnp.float32)))
+            _, experts = jax.lax.top_k(choice, top_k)
+            weights = jnp.take_along_axis(scores, experts, axis=-1)
+            if norm_topk_prob:
+                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            return weights * scaling, experts.astype(jnp.int32)
+        if scoring != "softmax" or bias is not None:
+            raise ValueError(f"scoring={scoring!r} with bias "
+                             f"{'given' if bias is not None else 'None'}")
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = jax.lax.top_k(probs, top_k)
         if norm_topk_prob:
@@ -156,10 +177,14 @@ def swiglu_experts(xs, sizes, gate_proj, up_proj, down_proj):
 
 def dropless_moe(x, w_router, gate_proj, up_proj, down_proj, *,
                  top_k: int, experts_held: Tuple[int, int],
-                 capacity_factor: float, norm_topk_prob: bool = True):
+                 capacity_factor: float, norm_topk_prob: bool = True,
+                 scoring: str = "softmax", router_bias=None,
+                 routed_scaling_factor: float = 1.0):
     """``x [P, d]`` -> (this share's part of the layer ``[P, d]``, pairs
     per held expert ``[count]``). The expert matrices are the held ones,
-    in the compute dtype; ``w_router [d, n_experts]`` is whole."""
+    in the compute dtype; ``w_router [d, n_experts]`` is whole;
+    ``scoring``, ``router_bias`` and ``routed_scaling_factor`` are
+    ``route``'s."""
     p, _ = x.shape
     n_experts = w_router.shape[1]
     first, count = experts_held
@@ -167,7 +192,9 @@ def dropless_moe(x, w_router, gate_proj, up_proj, down_proj, *,
         raise ValueError(f"experts_held={experts_held} against "
                          f"{gate_proj.shape[0]} expert matrices and a router "
                          f"over {n_experts}")
-    weights, experts = route(x, w_router, top_k, norm_topk_prob)
+    weights, experts = route(x, w_router, top_k, norm_topk_prob,
+                             scoring=scoring, bias=router_bias,
+                             scaling=routed_scaling_factor)
     with jax.named_scope("moe.dispatch"):
         plan = dispatch_plan(experts, experts_held, capacity_rows(
             p, top_k, n_experts, count, capacity_factor))

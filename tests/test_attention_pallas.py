@@ -389,6 +389,12 @@ def test_default_tile_matches_dense_at_short_lengths(length, causal):
     (("window", 512), 8192, jnp.bfloat16, dict(
         block_q=512, block_k=512, sub_q=512, sub_k=512, dead=225, cut=31,
         full=0)),
+    # xing4-29b-a4b.lm4k (PR 33): latent attention's 192-wide q and k over
+    # a 128-wide value take the causal plan as it is (the widths are not
+    # the plan's matter)
+    (("causal",), 4096, jnp.bfloat16, dict(
+        block_q=1024, block_k=1024, sub_q=512, sub_k=512, dead=28, cut=8,
+        full=28)),
     # unmasked from 1024 up (no cell): the tile is its own sub-tile
     (("none",), 1024, jnp.bfloat16, dict(
         block_q=512, block_k=1024, sub_q=512, sub_k=1024, dead=0, cut=0,

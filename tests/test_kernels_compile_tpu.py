@@ -156,6 +156,27 @@ def test_differential_attention_kernels_compile(v5e, mask, names):
         assert f"{names}_{which}" in text, which
 
 
+@pytest.mark.parametrize("pad", [0, 64], ids=["192", "padded_to_256"])
+def test_latent_attention_kernels_compile(v5e, pad):
+    """xing4-29b-a4b.lm4k: 32 heads of 4,096 positions, causal, q and k
+    192 wide (128 without and 64 with the rotary embedding: one and a
+    half lane tiles) over a 128-wide value; and the same with q and k
+    zero-padded to 256, the K run's second candidate."""
+    def loss(q, k, v):
+        if pad:
+            q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) for x in (q, k))
+        out = attention_pallas.flash_attention(q, k, v, causal=True,
+                                               scale=192 ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = compile_for(v5e, jax.grad(loss, (0, 1, 2)),
+                       ((1, 4096, 32, 192), jnp.bfloat16),
+                       ((1, 4096, 32, 192), jnp.bfloat16),
+                       ((1, 4096, 32, 128), jnp.bfloat16))
+    for which in ("fwd", "dq", "dkv"):
+        assert f"flash_wide_{which}" in text, which
+
+
 def test_selective_scan_compiles_at_the_cells_size(v5e):
     """Forward and backward of the chunked scan at T 8,192, E 5,120, N 16
     (XLA's loops, no kernel): the state of every step never exists at
