@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from pytorch_ps_mpi_tpu.ops import (
     attention_pallas,
+    moe_rows_pallas,
     quant_pallas,
     sign_pallas,
     tern_pallas,
@@ -50,8 +51,8 @@ def v5e(v5e_2x2):
 
 @pytest.fixture(autouse=True)
 def mosaic_not_interpret(monkeypatch):
-    for mod in (attention_pallas, quant_pallas, sign_pallas, tern_pallas,
-                topk_pallas):
+    for mod in (attention_pallas, moe_rows_pallas, quant_pallas, sign_pallas,
+                tern_pallas, topk_pallas):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -211,6 +212,32 @@ def test_grouped_products_compile_to_kernels(v5e):
                        ((16, 2048, 768), jnp.bfloat16),
                        ((16, 768, 2048), jnp.bfloat16), ((16,), jnp.int32))
     assert text.count("%ragged-dot") >= 9      # 3 matrices x 3 passes
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("positions, rows, width, experts", [
+    (16384, 65536, 2048, 16),       # sdar-30b-a3b.bd4k
+    (4096, 16384, 3584, 8),         # xing4-29b-a4b.lm4k
+], ids=["bd4k", "lm4k"])
+def test_the_sum_back_compiles_at_the_cells_shapes(v5e, positions, rows,
+                                                   width, experts, dtype):
+    """``sum_rows`` out of both expert cells' buffers: one Mosaic kernel
+    (2-byte rows as halves of 32-bit words, 4-byte rows as they are), and
+    no copy of the buffer in front of it: it reads the tiles where they
+    are."""
+    block = moe_rows_pallas.block_rows(width)
+
+    def back(y, to, runs):
+        return moe_rows_pallas.sum_rows(y, to, runs, positions, block)
+
+    text = compile_for(v5e, back, ((rows, width), dtype),
+                       ((rows,), jnp.int32),
+                       ((positions // block + 1, experts), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "moe_sum_rows" in text
+    assert f"[{rows},{width}]" not in "".join(
+        ln.split("(")[0] for ln in text.splitlines() if " copy(" in ln)
 
 
 def test_vmem_overflow_is_a_compile_error(v5e):
