@@ -21,7 +21,19 @@ Everything on-device runs under ``jax.jit``/``shard_map`` over a
 ``ppermute``) instead of MPI over Ethernet.
 """
 
+import sys as _sys
+import time as _time
+
+# first line to last of this import, as the set-up log's first row; the
+# log cannot be imported before the package is
+_T0, _JAX_IN = _time.monotonic(), "jax" in _sys.modules
+
 from pytorch_ps_mpi_tpu.ps import MPI_PS, Adafactor, Adam, SGD
 
 __all__ = ["MPI_PS", "Adafactor", "Adam", "SGD"]
 __version__ = "0.1.0"
+
+from pytorch_ps_mpi_tpu.telemetry import setup_event as _setup_event
+
+_setup_event("setup.import", kind="span", ts=_T0,
+             dur=_time.monotonic() - _T0, jax_already_imported=_JAX_IN)

@@ -40,7 +40,7 @@ Design choices:
   only where the mask cuts: at 256 x 256 a causal 1024 x 1024 head is 6
   dead + 4 cut + 6 full sub-tiles, a block-diffusion head of 2 x 4096
   positions 736 + 48 + 240 (``tile_census``; one ``attn.flash_tiles``
-  row on the FlightRecorder a trace). A grid tile with no allowed pair
+  row in the set-up log a trace). A grid tile with no allowed pair
   is skipped whole by a predicated ``pl.when``: ring's future blocks
   cost ~0. The score tile in flight is a sub-tile, never the grid tile.
 - **Backward is two Pallas kernels** (dq over k tiles; dk/dv over q
@@ -99,7 +99,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from pytorch_ps_mpi_tpu.ops._common import LANE as _LANE
 from pytorch_ps_mpi_tpu.ops._common import interpret as _interpret
-from pytorch_ps_mpi_tpu.telemetry.recorder import get_recorder
+from pytorch_ps_mpi_tpu.telemetry.recorder import setup_event
 
 _MASKED = -1e30        # additive mask value
 _MASK_THRESH = -1e29   # "this score was masked" test (real scores are tiny)
@@ -933,9 +933,8 @@ def flash_attention(
         return (out, lse) if return_lse else out
     bq, bk, sq, sk = (plan[key] for key in ("block_q", "block_k", "sub_q",
                                             "sub_k"))
-    rec = get_recorder()
-    if rec is not None:     # how often the sweep engages, once a trace
-        rec.event("attn.flash_tiles", **plan)
+    # how often the sweep engages, once a trace (the set-up log's row)
+    setup_event("attn.flash_tiles", **plan)
 
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], x.shape[3])

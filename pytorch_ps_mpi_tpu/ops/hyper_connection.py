@@ -33,7 +33,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from pytorch_ps_mpi_tpu.telemetry.recorder import get_recorder
+from pytorch_ps_mpi_tpu.telemetry.recorder import setup_event
 
 
 def sinkhorn(m, iters: int, eps: float):
@@ -114,11 +114,9 @@ def depth_mix(streams, y, h_res, h_post, tag: str = ""):
 
 
 def record_plan(streams, iters: int, sub_layers: int) -> None:
-    """One ``hc.plan`` row on the FlightRecorder a trace: how many
+    """One ``hc.plan`` row in the set-up log a trace: how many
     streams, Sinkhorn iterations and hyper-connected sub-layers the
     program holds, and the bytes of the streams at a layer boundary."""
-    rec = get_recorder()
-    if rec is not None:
-        rec.event("hc.plan", streams=int(streams.shape[0]),
-                  iterations=int(iters), sub_layers=int(sub_layers),
-                  stream_bytes=int(streams.size * streams.dtype.itemsize))
+    setup_event("hc.plan", streams=int(streams.shape[0]),
+                iterations=int(iters), sub_layers=int(sub_layers),
+                stream_bytes=int(streams.size * streams.dtype.itemsize))

@@ -48,7 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from pytorch_ps_mpi_tpu.telemetry.recorder import get_recorder
+from pytorch_ps_mpi_tpu.telemetry.recorder import setup_event
 
 CHUNK = 64
 
@@ -118,10 +118,9 @@ def selective_scan(x, dt, a, b_in, c_in, d, *, chunk: int = CHUNK,
     n = a.shape[1]
     chunk = min(chunk, steps)
     chunks = -(-steps // chunk)
-    rec = get_recorder()
-    if rec is not None:     # once a trace
-        rec.event("ssm.scan_plan", T=steps, chunk=chunk, chunks=chunks,
-                  E=width, N=n, state_bytes_carried=4 * chunks * rows * n * width)
+    # once a trace (the set-up log's row)
+    setup_event("ssm.scan_plan", T=steps, chunk=chunk, chunks=chunks,
+                E=width, N=n, state_bytes_carried=4 * chunks * rows * n * width)
 
     f32 = lambda v: v.astype(jnp.float32)
     x, dt, b_in, c_in, a_t = f32(x), f32(dt), f32(b_in), f32(c_in), f32(a).T

@@ -227,14 +227,22 @@ def worker_main(name: str, worker_id: int, cfg: Dict[str, Any]) -> int:
         enable_compilation_cache,
     )
 
+    # the way to the first acknowledged push, in the set-up log:
+    # setup.worker with its phases under it
+    starting = telemetry.SetupPhases("worker", worker=worker_id)
     cache = enable_compilation_cache()
+    with starting.phase("attach"):
+        # the backend's attach, which otherwise hides in make_problem's
+        # first jax call
+        starting.attrs["platform"] = jax.local_devices()[0].platform
     code = None
     if cfg.get("codec"):
         from pytorch_ps_mpi_tpu.codecs import get_codec
 
         code = get_codec(cfg["codec"], **cfg.get("codec_kw", {}))
 
-    _, params0, batch_fn, loss_fn = make_problem(cfg)
+    with starting.phase("problem"):
+        _, params0, batch_fn, loss_fn = make_problem(cfg)
     grad_fn = jax.jit(jax.value_and_grad(loss_fn))  # ONLY grad source
 
     slow_ms, steps = worker_cfg(cfg, worker_id)
@@ -258,22 +266,23 @@ def worker_main(name: str, worker_id: int, cfg: Dict[str, Any]) -> int:
                            frame=frame)
 
     rec = _telemetry_from_cfg(cfg, worker=worker_id)
-    if cfg.get("tree_leader"):
-        # aggregation-tree leaf: push to the group leader, fall back to
-        # the root when the leader dies, rejoin on its respawn — the
-        # tree's own failover IS the resilience layer here
-        from pytorch_ps_mpi_tpu.parallel.tree import TreeWorkerConn
+    with starting.phase("open"):
+        if cfg.get("tree_leader"):
+            # aggregation-tree leaf: push to the group leader, fall back
+            # to the root when the leader dies, rejoin on its respawn —
+            # the tree's own failover IS the resilience layer here
+            from pytorch_ps_mpi_tpu.parallel.tree import TreeWorkerConn
 
-        w = TreeWorkerConn(worker_id, params0, cfg)
-    elif cfg.get("resilient"):
-        from pytorch_ps_mpi_tpu.resilience.worker import ResilientWorker
+            w = TreeWorkerConn(worker_id, params0, cfg)
+        elif cfg.get("resilient"):
+            from pytorch_ps_mpi_tpu.resilience.worker import ResilientWorker
 
-        w = ResilientWorker(make_transport, worker_id=worker_id,
-                            seed=int(cfg.get("fault_seed",
-                                             cfg.get("seed", 0))),
-                            **cfg.get("resilience_kw", {}))
-    else:
-        w = make_transport()
+            w = ResilientWorker(make_transport, worker_id=worker_id,
+                                seed=int(cfg.get("fault_seed",
+                                                 cfg.get("seed", 0))),
+                                **cfg.get("resilience_kw", {}))
+        else:
+            w = make_transport()
 
     from pytorch_ps_mpi_tpu.resilience.faults import (
         CRASH_EXIT_CODE,
@@ -429,17 +438,21 @@ def worker_main(name: str, worker_id: int, cfg: Dict[str, Any]) -> int:
             # one cycle, read to push, as spans (telemetry.span does
             # nothing while the recorder is off); the health beacon's
             # shared durations keep their own clock readings
+            # (starting.phase: a set-up span in the first cycle, the
+            # do-nothing context in every later one)
             with span("worker.step", step=step):
-                with span("worker.read_params"):
+                with span("worker.read_params"), starting.phase("first_read"):
                     params, version = w.read_params()
                 t0 = time.monotonic()
                 with span("worker.grad", version=version):
                     with span("worker.batch"):
                         batch = batch_fn(step, worker_id)
-                    with span("worker.grad_dispatch"):
-                        loss, grads = grad_fn(params, batch)
-                    with span("worker.grad_wait"):
-                        jax.block_until_ready(grads)
+                    # the gradient program's build is inside
+                    with starting.phase("first_grad"):
+                        with span("worker.grad_dispatch"):
+                            loss, grads = grad_fn(params, batch)
+                        with span("worker.grad_wait"):
+                            jax.block_until_ready(grads)
                 compute_s = time.monotonic() - t0
                 if first_grad_s is None:
                     # compile (or cache load) included
@@ -465,7 +478,7 @@ def worker_main(name: str, worker_id: int, cfg: Dict[str, Any]) -> int:
                     # seq joins the span so trace export can tie this
                     # push span to the server's consume span (flow arrow)
                     with span("worker.push_grad", version=version,
-                              seq=push_seq):
+                              seq=push_seq), starting.phase("first_push"):
                         w.push_grad(grads, version, timeout=push_timeout,
                                     lineage=(step, push_seq))
                         push_seq += 1
@@ -474,6 +487,8 @@ def worker_main(name: str, worker_id: int, cfg: Dict[str, Any]) -> int:
                                         lineage=(step, push_seq))
                             push_seq += 1
             pushed += 1
+            if starting.open:  # the first cycle is over: set-up is
+                starting.done()
             if beacon is not None:
                 # step accounting for straggler ATTRIBUTION: the
                 # deliberate slow_ms sleep emulates slow compute, so it
@@ -491,6 +506,7 @@ def worker_main(name: str, worker_id: int, cfg: Dict[str, Any]) -> int:
             rec.event("resilience.summary", worker=worker_id,
                       retries=w.retries, reconnects=w.reconnects)
     finally:
+        starting.done()  # a worker that never pushed says how far it got
         w.close()
         _dump_recorder(cfg, rec, f"worker-{worker_id}.jsonl")
         if prober is not None:
@@ -781,13 +797,19 @@ def serve(
 
     from pytorch_ps_mpi_tpu.optim import OPTIMIZERS
 
-    _, params, batch_fn, loss_fn = make_problem(cfg)
-    hyper_cls, init_state, update_fn = OPTIMIZERS[cfg.get("optim", "sgd")]
-    h = hyper_cls(**cfg.get("hyper", {"lr": 0.05}))
-    state = init_state(params)
-    update = jax.jit(lambda p, g, s: update_fn(p, g, s, h))
-    eval_loss = jax.jit(loss_fn)
-    eval_batch = batch_fn(10**6, 10**6)  # never used by any worker
+    # the way to the first published version, in the set-up log:
+    # setup.serve with its phases under it
+    starting = telemetry.SetupPhases(
+        "serve", workers=server.num_workers, codec=cfg.get("codec"))
+    with starting.phase("problem"):
+        _, params, batch_fn, loss_fn = make_problem(cfg)
+    with starting.phase("optimizer"):
+        hyper_cls, init_state, update_fn = OPTIMIZERS[cfg.get("optim", "sgd")]
+        h = hyper_cls(**cfg.get("hyper", {"lr": 0.05}))
+        state = init_state(params)
+        update = jax.jit(lambda p, g, s: update_fn(p, g, s, h))
+        eval_loss = jax.jit(loss_fn)
+        eval_batch = batch_fn(10**6, 10**6)  # never used by any worker
 
     ckpt = None
     applied_before = 0
@@ -979,6 +1001,7 @@ def serve(
 
     wait_t0 = time.perf_counter()
     round_t0 = time.perf_counter()
+    loop_entered = time.monotonic()  # setup.serve.first_update begins
     next_tick = 0.0
     draining = False
     numerics_stop = False
@@ -1051,6 +1074,14 @@ def serve(
                                  workers=lineage_workers)
         if cadence:
             cadence.maybe_save(params, state, server, applied_before + applied)
+        if starting.open:
+            # the first published version: from the loop's entry through
+            # the wait for the first gradient to this publish
+            telemetry.setup_event(
+                "setup.serve.first_update", kind="span", parent=starting.name,
+                ts=loop_entered, dur=time.monotonic() - loop_entered,
+                wait_s=starting.attrs.pop("first_wait_s", None))
+            starting.done()
         _fire_server_faults()
 
     def _mark_dead_workers() -> None:
@@ -1273,6 +1304,8 @@ def serve(
             server.agg_fallbacks += 1
         wait_s = time.perf_counter() - wait_t0
         h_wait.observe(wait_s)
+        if starting.open:  # until the first publish: the first gradient's
+            starting.attrs.setdefault("first_wait_s", wait_s)
         staleness = max(0, server.version - grad_version)
         if rec is not None:
             rec.event("serve.grad", worker=wid, staleness=staleness,

@@ -74,7 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_ps_mpi_tpu.ops import moe_rows_pallas
-from pytorch_ps_mpi_tpu.telemetry.recorder import get_recorder
+from pytorch_ps_mpi_tpu.telemetry.recorder import setup_event
 
 
 class Plan(NamedTuple):
@@ -240,14 +240,13 @@ def dropless_moe(x, w_router, gate_proj, up_proj, down_proj, *,
                              scoring=scoring, bias=router_bias,
                              scaling=routed_scaling_factor)
     capacity = capacity_rows(p, top_k, n_experts, count, capacity_factor)
-    rec = get_recorder()
-    if rec is not None:     # what the moves move and who moves it, a trace
-        rec.event("moe.row_moves", rows=p, slots=top_k, width=x.shape[1],
-                  dtype=str(x.dtype), buffer_rows=capacity,
-                  expected_held=p * top_k * count // n_experts,
-                  gather_rows="take", sum_rows=_mover(x),
-                  gather_gates="take",
-                  sum_gates=_mover(weights.reshape(-1, 1)))
+    # what the moves move and who moves it, a trace (the set-up log's row)
+    setup_event("moe.row_moves", rows=p, slots=top_k, width=x.shape[1],
+                dtype=str(x.dtype), buffer_rows=capacity,
+                expected_held=p * top_k * count // n_experts,
+                gather_rows="take", sum_rows=_mover(x),
+                gather_gates="take",
+                sum_gates=_mover(weights.reshape(-1, 1)))
     with jax.named_scope("moe.dispatch"):
         plan = dispatch_plan(experts, experts_held, capacity,
                              moe_rows_pallas.block_rows(x.shape[1]))

@@ -61,7 +61,12 @@ from pytorch_ps_mpi_tpu.bucketing import (
     unflatten_from_buckets,
 )
 from pytorch_ps_mpi_tpu.codecs import Codec, ErrorFeedback, IdentityCodec
-from pytorch_ps_mpi_tpu.telemetry import get_recorder, span
+from pytorch_ps_mpi_tpu.telemetry import (
+    get_recorder,
+    setup_event,
+    setup_span,
+    span,
+)
 from pytorch_ps_mpi_tpu.mesh import DATA_AXIS, make_mesh
 from pytorch_ps_mpi_tpu.optim import (
     OPTIMIZERS,
@@ -719,6 +724,7 @@ class MPI_PS:
         loss_reduction: Optional[str] = None,
         **hyper,
     ):
+        entered = time.monotonic()  # the set-up log's setup.state begins
         if optim not in OPTIMIZERS:
             raise ValueError(f"optim must be one of {sorted(OPTIMIZERS)}")
         if mode not in ("allgather", "leader"):
@@ -926,6 +932,12 @@ class MPI_PS:
         self.codec_state = self._init_codec_state()
         self._codec_spec = self._codec_state_spec()
         self._place_state()
+        setup_event(
+            "setup.state", kind="span", ts=entered,
+            dur=time.monotonic() - entered,
+            leaves=len(self._spec_leaves), param_bytes=_tree_bytes(params),
+            state_bytes=_tree_bytes((self.opt_state, self.codec_state)),
+            devices=int(self.mesh.devices.size), mode=mode)
         self.aux_state = None  # mutable model state (e.g. BN batch_stats)
         self._compiled: Dict[Any, Callable] = {}
         self._step_count = 0
@@ -1454,6 +1466,28 @@ class MPI_PS:
             comms.async_allreduce_options(self.mesh, self._agg_axes),
         )
 
+    def _step_program(self, key, path: str, build: Callable[[], Callable]):
+        """The program of ``key``: the ONE place the fused, the
+        grads-only and the accumulating step look theirs up. On a miss
+        what comes back builds the program and makes its FIRST call —
+        the trace, the lowering and the compile or the cache load are
+        all inside it — under one ``setup.step_build`` span of the
+        set-up log (``key``: the ``path``; ``program``: the jit's name,
+        which a device trace's ``XLA Modules`` line shows as
+        ``jit_spmd(<hash>)`` and the log's ``compile.program`` row
+        carries). On a hit, the program and nothing else."""
+        fn = self._compiled.get(key)
+        if fn is not None:
+            return fn
+
+        def first_call(*args):
+            with setup_span("setup.step_build", key=path) as row:
+                fn = self._compiled[key] = build()
+                row["program"] = f"jit({fn.__name__})"
+                return fn(*args)
+
+        return first_call
+
     def _build_instrumented_stages(self, loss_fn, has_aux: bool = False,
                                    accum_steps: int = 0):
         """Pipeline as four separately-dispatched programs so host timers
@@ -1778,7 +1812,7 @@ class MPI_PS:
         program runs and how many of them are asynchronous
         (``comms.count_scheduled_collectives``; 0 of 5 in BERT-base over
         four chips before the overlapping schedule, 51 of 52 with it),
-        with one ``ps.step_program`` row on the FlightRecorder each time
+        with one ``ps.step_program`` row in the set-up log each time
         they are read. Pass ``aux_state`` iff the step does
         (the loss_fn signature changes with it). NOTE the first call
         per loss_fn pays a full AOT compile — ``jitted.lower()`` does
@@ -1821,11 +1855,9 @@ class MPI_PS:
         # whether the overlapping schedule engaged: the program's
         # collectives, and how many of them run beside other work
         out.update(comms.count_scheduled_collectives(self._analysed.as_text()))
-        rec = get_recorder()
-        if rec is not None:
-            rec.event("ps.step_program",
-                      collectives=out["collectives"],
-                      async_collectives=out["async_collectives"])
+        setup_event("ps.step_program",
+                    collectives=out["collectives"],
+                    async_collectives=out["async_collectives"])
         return out
 
     def step_program_text(self) -> Optional[str]:
@@ -1862,14 +1894,14 @@ class MPI_PS:
             data["step_time"] = time.perf_counter() - t0
             self._record_step("ps.step_accumulate", data)
             return loss, data
-        key = ("accum", _fn_cache_key(loss_fn), accum_steps)
-        if key not in self._compiled:
-            self._compiled[key] = self._build_accum_grad_step(loss_fn, accum_steps)
+        fn = self._step_program(
+            ("accum", _fn_cache_key(loss_fn), accum_steps), "accum",
+            lambda: self._build_accum_grad_step(loss_fn, accum_steps))
         t0 = time.perf_counter()
         data = self._schema_dict()
         data["accum_steps"] = float(accum_steps)
         self._rng, rng = jax.random.split(self._rng)
-        out = self._compiled[key](
+        out = fn(
             self.params, self.opt_state, self.codec_state, microbatches, rng
         )
         if self.numerics:
@@ -2043,10 +2075,9 @@ class MPI_PS:
                 if loss_fn is not None:
                     if batch is None:
                         raise ValueError("loss_fn requires batch")
-                    key = ("grad", _fn_cache_key(loss_fn), has_aux)
-                    if key not in self._compiled:
-                        self._compiled[key] = self._build_grad_step(
-                            loss_fn, has_aux)
+                    fn = self._step_program(
+                        ("grad", _fn_cache_key(loss_fn), has_aux), "fused",
+                        lambda: self._build_grad_step(loss_fn, has_aux))
                     args = (self.params, self.opt_state, self.codec_state,
                             batch, rng) + ((aux_state,) if has_aux else ())
                 elif grads is not None:
@@ -2063,14 +2094,13 @@ class MPI_PS:
                             "stack is ambiguous for model-sharded leaves — "
                             "use the loss_fn path"
                         )
-                    key = ("grads-only",)
-                    if key not in self._compiled:
-                        self._compiled[key] = self._build_grads_only_step()
+                    fn = self._step_program(
+                        ("grads-only",), "grads",
+                        self._build_grads_only_step)
                     args = (self.params, self.opt_state, self.codec_state,
                             grads, rng)
                 else:
                     raise ValueError("pass grads or loss_fn+batch")
-                fn = self._compiled[key]
             with span("ps.dispatch"):
                 out = fn(*args)
             # the donated buffers die with their last reference: here,

@@ -81,6 +81,10 @@ summary table; ``make obs-smoke`` gates the
 observability plane end-to-end.
 """
 
+import time as _time
+
+_T0 = _time.monotonic()  # this package's own import: setup.import.telemetry
+
 from typing import Dict, Optional
 
 #: The ONE registry of JSONL sidecar prefixes written under the
@@ -139,6 +143,11 @@ from pytorch_ps_mpi_tpu.telemetry.recorder import (
     install,
     load_jsonl,
     record_event,
+    setup_dropped,
+    setup_event,
+    setup_rows,
+    setup_span,
+    SetupPhases,
     span,
 )
 from pytorch_ps_mpi_tpu.telemetry.registry import (
@@ -226,6 +235,11 @@ __all__ = [
     "install",
     "load_jsonl",
     "record_event",
+    "setup_dropped",
+    "setup_event",
+    "setup_rows",
+    "setup_span",
+    "SetupPhases",
     "span",
     "Counter",
     "Gauge",
@@ -271,3 +285,8 @@ __all__ = [
     "hop_trace_events",
     "load_hop_rows",
 ]
+
+# pulled in through ps.py while the package itself is being imported:
+# the child of its setup.import row
+setup_event("setup.import.telemetry", kind="span", parent="setup.import",
+            ts=_T0, dur=_time.monotonic() - _T0)

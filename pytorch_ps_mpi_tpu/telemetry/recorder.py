@@ -31,6 +31,14 @@ transports' ``push_grad``) open their spans with the module-level
 :func:`span`: besides the row it enters a ``jax.profiler`` annotation of
 the same name, so that under a profiler session the span also sits on
 the ``/host:CPU`` plane of the device trace, on the profiler's clock.
+
+Set-up — imports, placing the state, building and loading programs —
+is over before any caller can have configured a recorder. Its rows go
+to the process's **set-up log** (:func:`setup_span`,
+:func:`setup_event`, read with :func:`setup_rows`): a recorder of 4,096
+rows of its own that is always on, and whose rows a recorder takes over
+when :func:`configure` or :func:`install` installs it, so that a
+``dump_jsonl`` carries the way to the first step in front of the steps.
 """
 
 from __future__ import annotations
@@ -59,8 +67,13 @@ class FlightRecorder:
         self._events: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
         self.dropped = 0
+        self.written = 0  # rows ever appended here; never reset
         self._t0_monotonic = time.monotonic()
         self._t0_wall = time.time()
+        # of the process's set-up log: how many of its rows this recorder
+        # has been handed, and how many it had evicted by then
+        self._setup_seen = 0
+        self.setup_dropped = 0
 
     # -- recording --------------------------------------------------------
     def event(
@@ -102,10 +115,14 @@ class FlightRecorder:
             rec["parent"] = parent
         if attrs:
             rec["attrs"] = attrs
+        self._append(rec)
+
+    def _append(self, rec: Dict[str, Any]) -> None:
         with self._lock:
             if len(self._events) == self.capacity:
                 self.dropped += 1
             self._events.append(rec)
+            self.written += 1
 
     @contextlib.contextmanager
     def span(self, name: str, *, step: Optional[int] = None,
@@ -146,6 +163,7 @@ class FlightRecorder:
             "dropped": self.dropped,
             "n_events": len(rows),
             "worker": self.worker,
+            "setup_dropped": self.setup_dropped,
             "t0_monotonic": self._t0_monotonic,
             "t0_wall": self._t0_wall,
         }
@@ -196,22 +214,65 @@ def load_jsonl(path: str):
 
 _recorder: Optional[FlightRecorder] = None
 
+SETUP_LOG_ROWS = 4096
+
+
+class _SetupLog(FlightRecorder):
+    """The process's set-up log: a ring of ``SETUP_LOG_ROWS`` rows that
+    is written whether or not a recorder is installed (a training
+    process asks for few programs after its set-up, a pytest worker for
+    thousands: the newest rows are kept, the evicted counted in
+    ``dropped``). A row written while a recorder is on goes to it too."""
+
+    def event(self, name: str, *, ts: Optional[float] = None,
+              **kw: Any) -> None:
+        ts = time.monotonic() if ts is None else ts
+        super().event(name, ts=ts, **kw)
+        rec = _recorder
+        if rec is not None:
+            rec.event(name, ts=ts, **kw)
+            rec._setup_seen = self.written
+
+    def hand_over(self, rec: FlightRecorder) -> None:
+        """Copy into ``rec`` the rows it has not been handed yet (all
+        that are held, for a new recorder; none twice)."""
+        with self._lock:
+            rows, written = list(self._events), self.written
+            rec.setup_dropped = self.dropped
+        unseen = written - rec._setup_seen
+        rec._setup_seen = written
+        for row in rows[len(rows) - min(unseen, len(rows)):]:
+            if rec.worker is not None and "worker" not in row:
+                row = dict(row, worker=rec.worker)
+            rec._append(row)
+
+
+_setup_log = _SetupLog(capacity=SETUP_LOG_ROWS)
+setup_event = _setup_log.event  # one point row: setup_event(name, **attrs)
+setup_rows = _setup_log.events
+
+
+def setup_dropped() -> int:
+    """Rows the set-up log has evicted: 0 in a process that trains."""
+    return _setup_log.dropped
+
 
 def configure(capacity: int = 65536,
               worker: Optional[Any] = None) -> FlightRecorder:
     """Install (and return) the process-global recorder. Call sites all
-    over the codebase pick it up via :func:`get_recorder`."""
-    global _recorder
-    _recorder = FlightRecorder(capacity=capacity, worker=worker)
-    return _recorder
+    over the codebase pick it up via :func:`get_recorder`. It starts with
+    the rows of the process's set-up log."""
+    return install(FlightRecorder(capacity=capacity, worker=worker))
 
 
 def install(recorder: FlightRecorder) -> FlightRecorder:
     """Install an existing recorder as the process-global one — the
     re-enable path (``disable()`` then ``install(rec)`` pauses and
     resumes one buffer without discarding it, unlike ``configure``
-    which starts fresh)."""
+    which starts fresh). The recorder is handed the set-up rows written
+    since it was last on."""
     global _recorder
+    _setup_log.hand_over(recorder)
     _recorder = recorder
     return recorder
 
@@ -259,28 +320,41 @@ _NO_SPAN = _NoSpan()
 
 
 class _Span:
-    """One open span of the global recorder: a ``jax.profiler``
-    annotation around the body, one recorder row on exit."""
+    """One open span: a ``jax.profiler`` annotation around the body, one
+    row on exit, in the global recorder or (:func:`setup_span`) in the
+    set-up log. Set-up spans keep a stack of their own beside the hot
+    paths': they lie under whatever is open, and no hot-path span ever
+    has one for its parent."""
 
     __slots__ = ("rec", "name", "step", "attrs", "parent", "stack",
                  "annotation", "t0")
 
     def __init__(self, rec: FlightRecorder, name: str, step: Optional[int],
-                 attrs: Dict[str, Any]) -> None:
+                 attrs: Dict[str, Any], parent: Optional[str] = None) -> None:
         self.rec, self.name, self.step, self.attrs = rec, name, step, attrs
+        self.parent = parent
 
     def __enter__(self) -> Dict[str, Any]:
         try:
             stack = _open_spans.stack
         except AttributeError:
             stack = _open_spans.stack = []
+        under = stack
+        if self.rec is _setup_log:
+            try:
+                stack = _open_spans.setup
+            except AttributeError:
+                stack = _open_spans.setup = []
+            under = stack or under
         self.stack = stack
-        self.parent = None
-        if stack:
-            self.parent = stack[-1].name
+        if under:
+            if self.parent is None:  # a phase of SetupPhases names its own
+                self.parent = under[-1].name
             if self.step is None:
-                self.step = stack[-1].step
-        if self.name in _STEP_SPANS and self.step is not None:
+                self.step = under[-1].step
+        if _recorder is None:  # a set-up span: nobody traces set-up today
+            self.annotation = _NO_SPAN
+        elif self.name in _STEP_SPANS and self.step is not None:
             self.annotation = jax.profiler.StepTraceAnnotation(
                 self.name, step_num=self.step)
         else:
@@ -314,3 +388,47 @@ def span(name: str, *, step: Optional[int] = None, **attrs: Any):
     if rec is None:
         return _NO_SPAN
     return _Span(rec, name, step, attrs)
+
+
+# -- set-up: the way to the first step ---------------------------------------
+
+def setup_span(name: str, **attrs: Any) -> _Span:
+    """A span of set-up (``setup.cache``, ``setup.step_build``, ...):
+    :func:`span`'s class with the set-up log for its sink, so the row is
+    written with the recorder off, also when the body raises, with the
+    span open on this thread for its ``parent``. Never on a path that
+    runs once a step: it makes an object and a row every time."""
+    return _Span(_setup_log, name, None, attrs)
+
+
+def open_setup_span() -> Optional[str]:
+    """Name of the innermost set-up span open on this thread."""
+    stack = getattr(_open_spans, "setup", None)
+    return stack[-1].name if stack else None
+
+
+class SetupPhases:
+    """``setup.<what>``: a process's way to its first unit of work, open
+    from construction to :meth:`done`, which code between two iterations
+    of a loop calls and no ``with`` block can hold. ``phase(name)`` is
+    a set-up span ``setup.<what>.<name>`` under it until then, and the
+    do-nothing context afterwards, so a loop may keep the call."""
+
+    def __init__(self, what: str, **attrs: Any) -> None:
+        self.name = f"setup.{what}"
+        self.attrs = attrs
+        self.open = True
+        self.t0 = time.monotonic()
+
+    def phase(self, name: str, **attrs: Any):
+        if not self.open:
+            return _NO_SPAN
+        return _Span(_setup_log, f"{self.name}.{name}", None, attrs,
+                     parent=self.name)
+
+    def done(self) -> None:
+        """Write the row, once: what comes after is no set-up."""
+        if self.open:
+            self.open = False
+            setup_event(self.name, kind="span", ts=self.t0,
+                        dur=time.monotonic() - self.t0, **self.attrs)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorch_ps_mpi_tpu.ps import MPI_PS
-from pytorch_ps_mpi_tpu.telemetry import span
+from pytorch_ps_mpi_tpu.telemetry import setup_event, span
 from pytorch_ps_mpi_tpu.utils.checkpoint import CheckpointManager
 from pytorch_ps_mpi_tpu.utils.metrics import MetricsAccumulator
 
@@ -54,6 +54,9 @@ class Trainer:
         self.checkpoint_every = checkpoint_every
         self._last_saved_step = 0
         self._eval_compiled: Dict[Any, Callable] = {}
+        # entry of the first fit call, until the first loss is on the host
+        self._first_fit_at: Optional[float] = None
+        self._first_loss_seen = False
         self.ckpt = (
             CheckpointManager(checkpoint_dir) if checkpoint_dir else None
         )
@@ -143,6 +146,16 @@ class Trainer:
                 attrs["loss"] = value
         return value
 
+    def _first_loss(self, step: int) -> bool:
+        """The first loss of this trainer's life is on the host: one row
+        ``setup.first_step`` of the set-up log, from the entry of the
+        first ``fit`` call to now. Gives False, what the loop's
+        ``awaiting`` becomes: no step after this one comes here."""
+        self._first_loss_seen = True
+        setup_event("setup.first_step", kind="span", ts=self._first_fit_at,
+                    dur=time.monotonic() - self._first_fit_at, step=step)
+        return False
+
     def fit(
         self,
         batches: Iterator[PyTree],
@@ -163,6 +176,9 @@ class Trainer:
         metrics is the share of steps that found the device still busy
         when the next program was already queued."""
         t0 = time.perf_counter()
+        if self._first_fit_at is None:
+            self._first_fit_at = time.monotonic()
+        awaiting = not self._first_loss_seen  # the time to the first step
         last_loss = None
         launched = None  # (step, loss) of the step whose loss is not fetched
         done = 0
@@ -181,6 +197,8 @@ class Trainer:
                     self.step_count += self.scan_chunk
                     if attrs is not None:
                         attrs["loss"] = last_loss
+                if awaiting:
+                    awaiting = self._first_loss(self.step_count)
             else:
                 with span("trainer.step", step=self.step_count + 1):
                     with span("trainer.data"):
@@ -190,6 +208,8 @@ class Trainer:
                     # step has waited for the step before: its loss is ready
                     if launched is not None:
                         last_loss = self._fetch_loss(*launched)
+                        if awaiting:
+                            awaiting = self._first_loss(launched[0])
                     self.metrics.add(data)
                     done += 1
                     self.step_count += 1
@@ -197,7 +217,10 @@ class Trainer:
             log_now = log_every and done % log_every == 0
             if launched is not None and (log_now or done == num_steps):
                 # what the caller asked for now: wait for this step
-                last_loss, launched = self._fetch_loss(*launched), None
+                last_loss = self._fetch_loss(*launched)
+                if awaiting:
+                    awaiting = self._first_loss(launched[0])
+                launched = None
             if log_now:
                 rate = done / (time.perf_counter() - t0)
                 print(f"step {self.step_count}: loss={last_loss:.4f} "

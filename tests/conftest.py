@@ -71,6 +71,15 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.slow)
 
 
+@pytest.fixture(autouse=True)
+def fresh_setup_log():
+    """The set-up log is the process's, and a recorder starts with its
+    rows: a test begins with none of those that earlier tests left."""
+    from pytorch_ps_mpi_tpu.telemetry import recorder
+
+    recorder._setup_log.clear()
+
+
 @pytest.fixture(scope="session")
 def mesh8():
     from pytorch_ps_mpi_tpu.mesh import make_mesh

@@ -439,7 +439,9 @@ def test_two_workers_record_the_phase_spans_of_every_cycle(tmp_path):
         server.close()
     for wid in range(2):
         _, rows = load_jsonl(str(tmp_path / f"worker-{wid}.jsonl"))
-        spans = [e for e in rows if e["kind"] == "span"]
+        # the process's set-up rows come first (tests/test_setup_log.py)
+        spans = [e for e in rows if e["kind"] == "span"
+                 and not e["name"].startswith(("setup.", "compile."))]
         assert sorted(e["name"] for e in spans) == sorted(
             steps * list(ASYNC_SPANS))
         for e in spans:
@@ -463,7 +465,8 @@ def test_two_workers_record_the_phase_spans_of_every_cycle(tmp_path):
 def test_recorder_off_worker_makes_no_annotation_and_no_row(
         monkeypatch, annotations_made):
     """``worker_main`` in this process (a thread beside the server) with
-    the recorder off: no annotation object made, no recorder row."""
+    the recorder off: no annotation object made, and no row but those of
+    the set-up log."""
     import threading
 
     from pytorch_ps_mpi_tpu import telemetry
@@ -491,4 +494,6 @@ def test_recorder_off_worker_makes_no_annotation_and_no_row(
     finally:
         server.close()
     assert pushed == [3] and m["grads_received"] == 3
-    assert annotations_made == [] and rows == []
+    assert annotations_made == []
+    assert [n for n in rows if not n.startswith(("setup.", "compile."))] == []
+    assert "setup.worker" in rows and "setup.serve" in rows

@@ -614,7 +614,8 @@ def test_tile_census_of_the_cells_and_its_recorder_row():
     """The census of the two cells that run the kernels, at 256 x 256
     sub-tiles (a head; before the sweep every sub-tile of a live grid
     tile was computed and masked: 16 and 384), and one ``attn.flash_tiles``
-    row each time ``flash_attention`` is traced with the recorder on."""
+    row each time ``flash_attention`` is traced: in the set-up log while
+    the recorder is off, which a recorder takes over as it is installed."""
     from pytorch_ps_mpi_tpu import telemetry
     from pytorch_ps_mpi_tpu.ops.attention_pallas import tile_census
 
@@ -630,7 +631,9 @@ def test_tile_census_of_the_cells_and_its_recorder_row():
     q, k, v = qkv(l=64)
     fn = jax.jit(lambda q, k, v: flash_attention(
         q, k, v, causal=True, block_q=32, block_k=32))
-    fn(q, k, v)                       # traced with the recorder off: no row
+    fn(q, k, v)                       # traced with the recorder off
+    assert [e["attrs"]["block_k"] for e in telemetry.setup_rows()
+            if e["name"] == "attn.flash_tiles"] == [32]
     rec = telemetry.configure()
     try:
         fn2 = jax.jit(lambda q, k, v: flash_attention(
@@ -640,7 +643,7 @@ def test_tile_census_of_the_cells_and_its_recorder_row():
         rows = [e for e in rec.events() if e["name"] == "attn.flash_tiles"]
     finally:
         telemetry.disable()
-    assert len(rows) == 1
-    assert rows[0]["attrs"] == {"mask": "causal", "block_q": 32,
+    assert len(rows) == 2 and rows[0]["attrs"]["block_k"] == 32
+    assert rows[1]["attrs"] == {"mask": "causal", "block_q": 32,
                                 "block_k": 64, "sub_q": 32, "sub_k": 64,
                                 "dead": 0, "cut": 2, "full": 0}
