@@ -346,13 +346,10 @@ def hyper_connected(streams, p, cfg: XingConfig, sub_layer, tag: str = ""):
     """``X' = H_res X + H_post^T F(H_pre X)`` over ``streams [n, b, s,
     d]``; ``sub_layer(u)`` returns F(u), or (F(u), something more)."""
     c = cfg
-    h_pre, h_post, h_res = hc.mixing_weights(
-        streams, p, iters=c.hc_sinkhorn_iters, eps=c.hc_eps,
+    return hc.connect(
+        streams, p, sub_layer, iters=c.hc_sinkhorn_iters, eps=c.hc_eps,
         clamp=(c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max),
         norm_eps=c.rms_norm_eps, tag=tag)
-    out = sub_layer(hc.width_mix(streams, h_pre, tag))
-    y, more = out if isinstance(out, tuple) else (out, None)
-    return hc.depth_mix(streams, y, h_res, h_post, tag), more
 
 
 def decoder_layer(streams, lp, cfg: XingConfig, positions, dense: bool,

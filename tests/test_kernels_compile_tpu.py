@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from pytorch_ps_mpi_tpu.ops import (
     attention_pallas,
+    hyper_connection,
     moe_rows_pallas,
     quant_pallas,
     sign_pallas,
@@ -51,9 +52,14 @@ def v5e(v5e_2x2):
 
 @pytest.fixture(autouse=True)
 def mosaic_not_interpret(monkeypatch):
-    for mod in (attention_pallas, moe_rows_pallas, quant_pallas, sign_pallas,
-                tern_pallas, topk_pallas):
+    for mod in (attention_pallas, hyper_connection, moe_rows_pallas,
+                quant_pallas, sign_pallas, tern_pallas, topk_pallas):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
+    # the hyper-connections' passes are jitted: no trace made in interpret
+    # mode may answer here, and none made here may answer a later test
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def compile_for(dev, fn, *avals):
@@ -238,6 +244,95 @@ def test_the_sum_back_compiles_at_the_cells_shapes(v5e, positions, rows,
     assert "moe_sum_rows" in text
     assert f"[{rows},{width}]" not in "".join(
         ln.split("(")[0] for ln in text.splitlines() if " copy(" in ln)
+
+
+HC_CELL = (4, 4096, 3584)       # xing4-29b-a4b.lm4k: n, b s, d
+HC_CFG = (20, 1e-6, (-30.0, 30.0), 1e-6)
+
+
+def hc_avals(dtype):
+    """(the parameters' and the streams' avals, the tile) of one
+    hyper-connection at the cell's shape."""
+    n, positions, d = HC_CELL
+    p = jax.eval_shape(lambda k: hyper_connection.init(k, n, d),
+                       jax.random.key(0))
+    tile = hyper_connection.tile(
+        jax.ShapeDtypeStruct((n, 1, positions, d), dtype))
+    return p, ((n, positions, d), dtype), tile
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("kernel", ["hc_pre_fwd", "hc_post_fwd",
+                                    "hc_post_bwd", "hc_pre_bwd"])
+def test_hyper_connection_kernels_compile_at_the_cells_shape(v5e, kernel,
+                                                             dtype):
+    """Each of the four passes over ``[4, 1, 4096, 3584]`` alone: one
+    Mosaic kernel under its own name, its tile's streams, double-buffered,
+    and its scratch inside the VMEM it asks for (the compiler says so
+    where they are not)."""
+    hc = hyper_connection
+    p, x, tile = hc_avals(dtype)
+    assert tile == (hc.TILE if dtype == jnp.bfloat16 else 128)
+    y, small = ((x[0][1:]), dtype), ((x[0][1], 128), jnp.float32)
+    sds = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=SingleDeviceSharding(v5e)), tree)
+    fn, avals = {
+        "hc_pre_fwd": (lambda p, x: hc._pre_fwd(x, p, tile, HC_CFG), [x]),
+        "hc_post_fwd": (lambda p, x, y, c: hc._post_fwd(x, y, c, tile),
+                        [x, y, small]),
+        "hc_post_bwd": (lambda p, x, y, c, g: hc._post_bwd(
+            tile, (x, y, c), g)[1:], [x, y, small, x]),
+        "hc_pre_bwd": (lambda p, x, c, z, du, dc, g: hc._pre_bwd(
+            x, p, c, z, tile, HC_CFG, du, dc, g),
+            [x, small, small, y, small, x]),
+    }[kernel]
+    text = jax.jit(fn).lower(sds(p), *(jax.ShapeDtypeStruct(
+        s, d, sharding=SingleDeviceSharding(v5e)) for s, d in avals)
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert kernel in text
+
+
+def test_every_hyper_connection_kernel_sits_under_its_scope(v5e):
+    """``jax.grad`` of a layer of two hyper-connected sub-layers under
+    ``remat`` at the cell's shape: seven kernels (the gradient of a sum
+    needs no forward value, so what is left of the forward passes is the
+    recomputation: the first pair and the second's first half; then the
+    two backward pairs), and the ``op_name`` of each carries ``hc.mix``
+    — what ``hc.mix_ms`` and ``hc.mix_roofline_pct`` find their time
+    by."""
+    import re
+
+    hc = hyper_connection
+    p, _, _ = hc_avals(jnp.bfloat16)
+    n, positions, d = HC_CELL
+
+    @jax.checkpoint
+    def layer(x, p):
+        for _ in range(2):
+            x, _ = hc.connect(x, p, jnp.tanh, iters=20, eps=1e-6,
+                              clamp=(-30.0, 30.0), norm_eps=1e-6, tag="mtp.")
+        return x
+
+    def loss(x, p):
+        return jnp.sum(layer(x, p).astype(jnp.float32))
+
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=SingleDeviceSharding(v5e))
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        on(jax.ShapeDtypeStruct((n, 1, positions, d), jnp.bfloat16)),
+        jax.tree.map(on, p)).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    for ln in calls:
+        (op_name,) = re.findall(r'op_name="([^"]*)"', ln)
+        assert re.search(r"(^|[/(])mtp\.hc\.(mix|sinkhorn)([/)]|$)", op_name), \
+            op_name
+    names = [re.match(r"\s*(?:ROOT )?%([a-z_]+)", ln)[1] for ln in calls]
+    assert {name: names.count(name) for name in set(names)} == {
+        "hc_pre_fwd": 2, "hc_post_fwd": 1, "hc_post_bwd": 2,
+        "hc_pre_bwd": 2}, names
 
 
 def test_vmem_overflow_is_a_compile_error(v5e):

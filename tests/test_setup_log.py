@@ -471,6 +471,13 @@ def trace_hc():
     record_plan(jnp.zeros((4, 1, 8, 16), jnp.bfloat16), iters=20, sub_layers=10)
 
 
+def trace_hc_tiled():
+    from pytorch_ps_mpi_tpu.ops.hyper_connection import record_plan
+
+    record_plan(jax.ShapeDtypeStruct((4, 1, 4096, 3584), jnp.bfloat16),
+                iters=20, sub_layers=10)
+
+
 def read_step_program():
     opt = SGD({"w": jnp.ones((4, 2))}, lr=0.1)
     opt.step_memory_analysis(quad_loss, jnp.ones((8, 4)))
@@ -480,7 +487,11 @@ def read_step_program():
     ("attn.flash_tiles", trace_flash, {"block_q": 512, "full": 1}),
     ("ssm.scan_plan", trace_scan, {"T": 16, "chunk": 8, "chunks": 2}),
     ("moe.row_moves", trace_moe, {"rows": 32, "slots": 2, "width": 128}),
-    ("hc.plan", trace_hc, {"streams": 4, "iterations": 20, "sub_layers": 10}),
+    ("hc.plan", trace_hc, {"streams": 4, "iterations": 20, "sub_layers": 10,
+                           "stream_bytes": 1024, "mover": "jnp", "tile": 0}),
+    ("hc.plan", trace_hc_tiled, {"streams": 4, "iterations": 20,
+                                 "sub_layers": 10, "mover": "kernel",
+                                 "tile": 256}),
     ("ps.step_program", read_step_program, {"async_collectives": 0}),
 ])
 def test_a_plan_row_lands_in_the_log_with_the_recorder_off(name, trace, some):

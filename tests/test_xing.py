@@ -329,6 +329,8 @@ def test_hc_plan_and_flash_tiles_rows_on_the_recorder():
     row = plans[0]["attrs"]
     assert (row["streams"], row["iterations"], row["sub_layers"]) == (4, 20, 8)
     assert row["stream_bytes"] == 4 * 2 * T * cfg.hidden_size * 4
+    # 32 wide: under a lane tile, so the jax.numpy functions mix
+    assert (row["mover"], row["tile"]) == ("jnp", 0)
     tiles = [e for e in events if e["name"] == "attn.flash_tiles"]
     assert len(tiles) == 4      # three layers and the module's
 
