@@ -33,7 +33,9 @@ the noised half only, and the loss is the mean over rows x L of
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 from typing import Any, Tuple
 
 import jax
@@ -155,33 +157,43 @@ def rotary(x, positions, theta: float, freq=None):
                            axis=-1).astype(x.dtype)
 
 
-def gqa_attention(x, lp, cfg: SdarMoeConfig, positions, mask, block=None,
-                  half=None):
+def gqa_attention(x, lp, cfg, positions, mask, block=None, half=None, *,
+                  scope=None, proj_scope=None):
     """Grouped-query attention over ``x [b, s, hidden]`` under ``mask``
     (``None``, ``'causal'`` or ``'block_diffusion'`` with ``block`` and
-    ``half``)."""
+    ``half``). ``cfg`` is this family's, or another's with the same
+    fields (``models/lfm2.py``: ``num_attention_heads``,
+    ``num_key_value_heads``, ``head_dim``, ``rms_norm_eps``,
+    ``rope_theta``, ``attention``, ``dtype``). ``scope`` names the
+    attention itself (``attn.bd`` under the block-diffusion mask, else
+    ``attn``); ``proj_scope`` the four projections with the q/k norm and
+    the rotary embedding, which carry no scope without it."""
     from pytorch_ps_mpi_tpu.ops import attention_pallas as ap
 
     c = cfg
     b, s, _ = x.shape
     dt = c.dtype
-    q = (x @ lp["q_proj"].astype(dt)).reshape(b, s, c.num_attention_heads,
-                                              c.head_dim)
-    k = (x @ lp["k_proj"].astype(dt)).reshape(b, s, c.num_key_value_heads,
-                                              c.head_dim)
-    v = (x @ lp["v_proj"].astype(dt)).reshape(b, s, c.num_key_value_heads,
-                                              c.head_dim)
-    q = rotary(rms_norm(q, lp["q_norm"], c.rms_norm_eps), positions,
-               c.rope_theta)
-    k = rotary(rms_norm(k, lp["k_norm"], c.rms_norm_eps), positions,
-               c.rope_theta)
+    projections = (functools.partial(jax.named_scope, proj_scope)
+                   if proj_scope else contextlib.nullcontext)
+    with projections():
+        q = (x @ lp["q_proj"].astype(dt)).reshape(
+            b, s, c.num_attention_heads, c.head_dim)
+        k = (x @ lp["k_proj"].astype(dt)).reshape(
+            b, s, c.num_key_value_heads, c.head_dim)
+        v = (x @ lp["v_proj"].astype(dt)).reshape(
+            b, s, c.num_key_value_heads, c.head_dim)
+        q = rotary(rms_norm(q, lp["q_norm"], c.rms_norm_eps), positions,
+                   c.rope_theta)
+        k = rotary(rms_norm(k, lp["k_norm"], c.rms_norm_eps), positions,
+                   c.rope_theta)
     if c.attention not in ("full", "flash", "einsum"):
         raise ValueError(f"unknown attention={c.attention!r}")
     # as models/bert.py: 'flash' is always the kernel, 'full' takes it
     # where ops/attention_pallas.flash_auto_ok says so, 'einsum' never
     kernel = c.attention == "flash" or (
         c.attention == "full" and ap.flash_auto_ok(s, s, dt))
-    with jax.named_scope("attn.bd" if mask == "block_diffusion" else "attn"):
+    with jax.named_scope(scope or (
+            "attn.bd" if mask == "block_diffusion" else "attn")):
         if kernel:
             out = ap.flash_attention(q, k, v, mask=mask, block=block,
                                      half=half)
@@ -189,7 +201,8 @@ def gqa_attention(x, lp, cfg: SdarMoeConfig, positions, mask, block=None,
             out, _ = ap._attention_jnp(
                 q, k, v, 0, 0, ap._mask_spec(False, mask, block, half),
                 c.head_dim ** -0.5)
-    return out.reshape(b, s, -1) @ lp["o_proj"].astype(dt)
+    with projections():
+        return out.reshape(b, s, -1) @ lp["o_proj"].astype(dt)
 
 
 def decoder_layer(x, lp, cfg: SdarMoeConfig, positions, mask, block, half):

@@ -184,6 +184,25 @@ def test_latent_attention_kernels_compile(v5e, pad):
         assert f"flash_wide_{which}" in text, which
 
 
+def test_causal_grouped_kernels_compile_at_the_cells_shape(v5e):
+    """lfm2-24b-a2b.lm8kx2: 2 rows of 8,192 positions, 32 query over 8
+    key-value heads of 64, causal. Every part was there (grouped heads
+    under the block-diffusion mask at 128 wide, the causal sweep ungrouped
+    or with unequal widths); the combination is compiled here first:
+    three kernels, and dk/dv come out over the 8 key-value heads."""
+    def loss(q, k, v):
+        out = attention_pallas.flash_attention(q, k, v, mask="causal")
+        return jnp.sum(out.astype(jnp.float32))
+
+    dev = SingleDeviceSharding(v5e)
+    args = [jax.ShapeDtypeStruct((2, 8192, h, 64), jnp.bfloat16, sharding=dev)
+            for h in (32, 8, 8)]
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert [a.shape for a in compiled.out_info] == [
+        (2, 8192, 32, 64), (2, 8192, 8, 64), (2, 8192, 8, 64)]
+
+
 def test_selective_scan_compiles_at_the_cells_size(v5e):
     """Forward and backward of the chunked scan at T 8,192, E 5,120, N 16
     (XLA's loops, no kernel): the state of every step never exists at

@@ -176,3 +176,69 @@ def test_trains_through_mpi_ps_and_trainer():
     first = trainer.fit(iter([batch] * 1), 1)["final_loss"]
     last = trainer.fit(iter([batch] * 8), 8)["final_loss"]
     assert np.isfinite(last) and last < first
+
+
+def _gqa_attention_of_pr_36(x, lp, cfg, positions, mask, block=None,
+                            half=None):
+    """``sdar_moe.gqa_attention`` as it stood before it gained ``scope``
+    and ``proj_scope`` for its second caller (``models/lfm2.py``, PR 37),
+    line for line."""
+    from pytorch_ps_mpi_tpu.ops import attention_pallas as ap
+    from pytorch_ps_mpi_tpu.models.sdar_moe import rms_norm, rotary
+
+    c = cfg
+    b, s, _ = x.shape
+    dt = c.dtype
+    q = (x @ lp["q_proj"].astype(dt)).reshape(b, s, c.num_attention_heads,
+                                              c.head_dim)
+    k = (x @ lp["k_proj"].astype(dt)).reshape(b, s, c.num_key_value_heads,
+                                              c.head_dim)
+    v = (x @ lp["v_proj"].astype(dt)).reshape(b, s, c.num_key_value_heads,
+                                              c.head_dim)
+    q = rotary(rms_norm(q, lp["q_norm"], c.rms_norm_eps), positions,
+               c.rope_theta)
+    k = rotary(rms_norm(k, lp["k_norm"], c.rms_norm_eps), positions,
+               c.rope_theta)
+    kernel = c.attention == "flash" or (
+        c.attention == "full" and ap.flash_auto_ok(s, s, dt))
+    with jax.named_scope("attn.bd" if mask == "block_diffusion" else "attn"):
+        if kernel:
+            out = ap.flash_attention(q, k, v, mask=mask, block=block,
+                                     half=half)
+        else:
+            out, _ = ap._attention_jnp(
+                q, k, v, 0, 0, ap._mask_spec(False, mask, block, half),
+                c.head_dim ** -0.5)
+    return out.reshape(b, s, -1) @ lp["o_proj"].astype(dt)
+
+
+@pytest.mark.parametrize("attention", ["einsum", "flash"])
+def test_the_attention_is_bit_equal_to_what_it_was(attention):
+    """``bd4k``'s shape of call (the block-diffusion mask over a doubled
+    row, grouped heads, bf16 compute, no scope argument): the same output
+    and the same gradients, bit for bit, and the same program."""
+    cfg, params, _ = case(attention=attention, dtype=jnp.bfloat16)
+    lp = params["layer_0"]
+    half = 32
+    x = jax.random.normal(jax.random.key(8), (2, 2 * half, cfg.hidden_size),
+                          jnp.bfloat16)
+    ids = jnp.concatenate([jnp.arange(half)] * 2)
+
+    def run(fn):
+        def total(x, lp):
+            out = fn(x, lp, cfg, ids, "block_diffusion", cfg.block_length,
+                     half)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+        return jax.jit(jax.value_and_grad(total, (0, 1), has_aux=True))
+
+    now, then = run(sdar_moe.gqa_attention), run(_gqa_attention_of_pr_36)
+    ((_, out), grads), ((_, was), grads_then) = now(x, lp), then(x, lp)
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(was, np.float32))
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_then)):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+    # the program itself: the same operations under the same names
+    text = lambda f: f.lower(x, lp).as_text()
+    assert text(now) == text(then)
