@@ -47,7 +47,10 @@ dtype is ``cfg.dtype``; norms, the router, the convolution's gates and tap
 sum, the softmaxes and the logits are float32. Named scopes for a device
 trace: ``conv.proj``, ``conv.mix``, ``attn.gqa_proj``, ``attn.gqa``,
 ``mlp.swiglu``, ``moe.route|dispatch|experts|combine``, ``loss.head``.
-With ``remat`` a layer is a ``jax.checkpoint``.
+With ``remat`` a layer is a checkpoint that keeps the flash kernels'
+output and per-row logsumexp and the expert layer's plan and router
+choice (``ops/_common.checkpoint_layer``) and recomputes the rest: the
+convolutions and the matrix products run forward twice.
 """
 
 from __future__ import annotations
@@ -61,6 +64,7 @@ import jax.numpy as jnp
 from pytorch_ps_mpi_tpu.models.gpt import causal_lm_loss as next_token_loss
 from pytorch_ps_mpi_tpu.models.sdar_moe import gqa_attention, rms_norm
 from pytorch_ps_mpi_tpu.models.xing import swiglu
+from pytorch_ps_mpi_tpu.ops._common import checkpoint_layer
 from pytorch_ps_mpi_tpu.ops.short_conv import gated_short_conv
 from pytorch_ps_mpi_tpu.parallel.dropless import dropless_moe
 
@@ -90,7 +94,7 @@ class Lfm2Config:
     capacity_factor: float = 2.0       # parallel/dropless.py
     dtype: Any = jnp.float32
     attention: str = "full"            # 'full' | 'flash' | 'einsum' (bert.py)
-    remat: bool = False                # jax.checkpoint around each layer
+    remat: bool = False                # checkpoint_layer around each layer
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.layer_index):
@@ -274,7 +278,7 @@ def hidden_states(params, tokens, cfg: Lfm2Config):
         def layer(x, lp, kind=kind, dense=dense):
             return decoder_layer(x, lp, cfg, positions, kind, dense)
 
-        x, n = (jax.checkpoint(layer) if cfg.remat else layer)(
+        x, n = (checkpoint_layer(layer) if cfg.remat else layer)(
             x, params[f"layer_{i}"])
         if not dense:
             loads.append(n)

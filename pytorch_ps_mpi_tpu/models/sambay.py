@@ -40,7 +40,10 @@ layer's kind (``LAYER_KINDS``):
 
 Two tensors are handed ACROSS layers — the memory and the shared keys and
 values — so their gradients are sums over all readers; with ``remat``
-each layer is a ``jax.checkpoint`` with the two as explicit inputs.
+each layer is a checkpoint with the two as explicit inputs, which keeps
+the flash kernels' output and per-row logsumexp
+(``ops/_common.checkpoint_layer``) and recomputes the rest: the scans,
+the projections and the MLPs run forward twice, the kernels once.
 
 Parameters are a plain pytree (float32); the compute dtype is
 ``cfg.dtype``; norms, ``Dt``, the scan, lambda, the softmaxes and the
@@ -58,6 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_ps_mpi_tpu.models.gpt import causal_lm_loss as next_token_loss
+from pytorch_ps_mpi_tpu.ops._common import checkpoint_layer
 from pytorch_ps_mpi_tpu.ops.selective_scan import selective_scan
 
 ATTENTION_KINDS = ("sliding_attention", "full_attention", "cross_attention")
@@ -95,7 +99,7 @@ class SambaYConfig:
     mamba_dt_rank: int = 160
     dtype: Any = jnp.float32
     attention: str = "full"            # 'full' | 'flash' | 'einsum' (bert.py)
-    remat: bool = False                # jax.checkpoint around each layer
+    remat: bool = False                # checkpoint_layer around each layer
 
     def __post_init__(self):
         kinds = self.layer_types
@@ -358,7 +362,7 @@ def hidden_states(params, tokens, cfg: SambaYConfig):
     memory = kv = None
     layer = decoder_layer
     if cfg.remat:
-        layer = jax.checkpoint(decoder_layer, static_argnums=(0, 1, 2))
+        layer = checkpoint_layer(decoder_layer, static_argnums=(0, 1, 2))
     for i, (kind, index) in enumerate(zip(cfg.layer_types, cfg.layer_index)):
         x, memory, kv = layer(kind, index, cfg, x, params[f"layer_{i}"],
                               memory, kv)

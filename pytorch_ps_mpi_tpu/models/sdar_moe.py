@@ -22,6 +22,11 @@ compute dtype is ``cfg.dtype``; norms, the router, the softmaxes and the
 logits are float32. Every named scope (``attn.bd``, ``moe.route``,
 ``moe.dispatch``, ``moe.experts``, ``moe.combine``, ``loss.head``) is in
 the step program's instruction metadata for a device trace to read.
+With ``remat`` a layer is a checkpoint that keeps what is dear to
+recompute and small to hold (``ops/_common.checkpoint_layer``: the flash
+kernels' output and per-row logsumexp, the expert layer's plan) and
+recomputes the rest: the backward pass runs no flash forward kernel and
+no sort a second time.
 
 **Training by diffusion over blocks** (``block_diffusion_loss``): a row
 of L tokens runs as 2L positions — the noised copy at 0..L-1, the clean
@@ -42,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_ps_mpi_tpu.models.bert import target_log_likelihood
+from pytorch_ps_mpi_tpu.ops._common import checkpoint_layer
 from pytorch_ps_mpi_tpu.parallel.dropless import dropless_moe
 
 
@@ -64,7 +70,7 @@ class SdarMoeConfig:
     capacity_factor: float = 2.0       # parallel/dropless.py
     dtype: Any = jnp.float32
     attention: str = "full"            # 'full' | 'flash' | 'einsum' (bert.py)
-    remat: bool = False                # jax.checkpoint around each layer
+    remat: bool = False                # checkpoint_layer around each layer
 
     @staticmethod
     def from_source(config: dict) -> "SdarMoeConfig":
@@ -232,7 +238,7 @@ def hidden_states(params, tokens, positions, cfg: SdarMoeConfig, *,
         return decoder_layer(x, lp, cfg, positions, mask, block, half)
 
     if cfg.remat:
-        layer = jax.checkpoint(layer)
+        layer = checkpoint_layer(layer)
     loads = []
     for i in range(cfg.num_hidden_layers):
         x, n = layer(x, params[f"layer_{i}"])

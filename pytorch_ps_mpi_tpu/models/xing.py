@@ -48,8 +48,11 @@ float32. Named scopes for a device trace: ``attn.mla_proj``,
 ``moe.shared``, ``moe.route|dispatch|experts|combine``, ``loss.head``;
 inside the prediction module the same behind ``mtp.`` (``mtp.attn.mla``,
 ...; ``dropless``'s own keep their names), and ``mtp.block``,
-``loss.mtp``. With ``remat`` a layer is a ``jax.checkpoint`` with the
-n-stream carry as its explicit input.
+``loss.mtp``. With ``remat`` a layer is a checkpoint with the n-stream
+carry as its explicit input, which keeps the flash kernels' output and
+per-row logsumexp and the expert layer's plan and router choice
+(``ops/_common.checkpoint_layer``) and recomputes the rest: the
+hyper-connections' forward pair and the matrix products run twice.
 """
 
 from __future__ import annotations
@@ -65,6 +68,7 @@ import numpy as np
 from pytorch_ps_mpi_tpu.models.gpt import causal_lm_loss as next_token_loss
 from pytorch_ps_mpi_tpu.models.sdar_moe import rms_norm, rotary
 from pytorch_ps_mpi_tpu.ops import hyper_connection as hc
+from pytorch_ps_mpi_tpu.ops._common import checkpoint_layer
 from pytorch_ps_mpi_tpu.parallel.dropless import dropless_moe
 
 
@@ -103,7 +107,7 @@ class XingConfig:
     capacity_factor: float = 2.0       # parallel/dropless.py
     dtype: Any = jnp.float32
     attention: str = "full"            # 'full' | 'flash' | 'einsum' (bert.py)
-    remat: bool = False                # jax.checkpoint around each layer
+    remat: bool = False                # checkpoint_layer around each layer
 
     def __post_init__(self):
         if self.num_nextn_predict_layers not in (0, 1):
@@ -376,7 +380,7 @@ def _layer_fn(cfg: XingConfig, positions, dense: bool, tag: str = ""):
     def layer(streams, lp):
         return decoder_layer(streams, lp, cfg, positions, dense, tag)
 
-    return jax.checkpoint(layer) if cfg.remat else layer
+    return checkpoint_layer(layer) if cfg.remat else layer
 
 
 def _spread(x, cfg: XingConfig):

@@ -97,7 +97,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_ps_mpi_tpu.ops._common import LANE as _LANE
+from pytorch_ps_mpi_tpu.ops._common import LANE as _LANE, keep
 from pytorch_ps_mpi_tpu.ops._common import interpret as _interpret
 from pytorch_ps_mpi_tpu.telemetry.recorder import setup_event
 
@@ -686,10 +686,16 @@ def _flash(q3, k3, v3, q_off, k_off, mask, scale, tile, sub):
 
 def _flash_fwd(q3, k3, v3, q_off, k_off, mask, scale, tile, sub):
     out, lse = _fwd(q3, k3, v3, q_off, k_off, mask, scale, *tile, *sub)
-    # residual keeps lane 0 only — every lane is identical, and holding
+    # the residual keeps lane 0 only — every lane is identical, and holding
     # the [bh, lq, LANE] ride through the whole model backward would cost
-    # 128x the memory; _bwd re-broadcasts (same pattern as dm)
-    return (out, lse), (q3, k3, v3, q_off, k_off, out, lse[..., 0])
+    # 128x the memory; _bwd re-broadcasts (same pattern as dm). `out` and
+    # that column are what a layer's checkpoint keeps (`_common.KEPT`), so
+    # the kernel does not run again in the backward pass; the NAMED `out`
+    # is both the primal output and the residual, so nothing downstream
+    # asks for an unnamed twin and brings the kernel back
+    out = keep(out, "flash.out")
+    return (out, lse), (q3, k3, v3, q_off, k_off, out,
+                        keep(lse[..., 0], "flash.lse"))
 
 
 def _flash_bwd(mask, scale, tile, sub, res, g):

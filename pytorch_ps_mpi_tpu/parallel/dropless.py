@@ -33,7 +33,10 @@ to positions x top_k; the gather into the buffer's to the buffer's rows
    caller counts a failed step) — never a silently smaller sum — and the
    groups handed to the products are cut at the buffer's end. At
    ``capacity_factor >= n_experts * min(top_k, count) / (top_k * count)``
-   it cannot overflow.
+   it cannot overflow. Every leaf of the plan, and the sigmoid router's
+   choice, is named for a layer's checkpoint to keep
+   (``ops/_common.KEPT``: integers, under 2 MB a layer): under ``remat``
+   the backward pass sorts nothing again.
 3. Grouped matrix products over the sorted rows: ``jax.lax.ragged_dot``
    with the per-expert group sizes. XLA:TPU lowers it to its own Mosaic
    kernels (forward, and both transposes for the backward pass) whose
@@ -74,6 +77,7 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_ps_mpi_tpu.ops import moe_rows_pallas
+from pytorch_ps_mpi_tpu.ops._common import keep
 from pytorch_ps_mpi_tpu.telemetry.recorder import setup_event
 
 
@@ -115,6 +119,10 @@ def route(x, w_router, top_k: int, norm_topk_prob: bool = True, *,
             choice = scores if bias is None else (
                 scores + jax.lax.stop_gradient(bias.astype(jnp.float32)))
             _, experts = jax.lax.top_k(choice, top_k)
+            # the choice also gathers the gates out of the scores: kept
+            # with the plan, a layer's checkpoint runs no second top_k
+            # (under softmax top_k's VALUES are the gates: it runs again)
+            experts = keep(experts, "moe.plan")
             weights = jnp.take_along_axis(scores, experts, axis=-1)
             if norm_topk_prob:
                 weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
@@ -248,8 +256,12 @@ def dropless_moe(x, w_router, gate_proj, up_proj, down_proj, *,
                 gather_gates="take",
                 sum_gates=_mover(weights.reshape(-1, 1)))
     with jax.named_scope("moe.dispatch"):
-        plan = dispatch_plan(experts, experts_held, capacity,
-                             moe_rows_pallas.block_rows(x.shape[1]))
+        # integer-only and under 2 MB: a layer's checkpoint keeps the plan
+        # (`_common.KEPT`) and the backward pass does not sort again
+        plan = jax.tree.map(
+            lambda leaf: keep(leaf, "moe.plan"),
+            dispatch_plan(experts, experts_held, capacity,
+                          moe_rows_pallas.block_rows(x.shape[1])))
         xs = gather_rows(x, plan.src, plan.dest, plan.runs)
         ws = gather_rows(weights.reshape(-1, 1), plan.pair,
                          plan.dest.reshape(-1, 1), plan.runs)
