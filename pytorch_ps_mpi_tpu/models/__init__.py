@@ -11,10 +11,10 @@ The config-driven decoders are plain functions over a parameter pytree,
 one module a family, each read from its source ``config.json``'s key names
 (``<module>.<Config>.from_source``, ``init``, ``apply``, a loss,
 ``router_loads``): ``sdar_moe`` (SDAR-30B-A3B-Chat), ``sambay``
-(Phi-4-mini-flash-reasoning), ``xing`` (Xing4.0-29B-A4B) and ``lfm2``
-(LFM2-24B-A2B). They are imported by module, ``from
-pytorch_ps_mpi_tpu.models import lfm2``, and only ``lfm2``'s config class
-is exported by name beside the flax modules'.
+(Phi-4-mini-flash-reasoning), ``xing`` (Xing4.0-29B-A4B, and on one plain
+residual stream JoyAI-LLM-Flash) and ``lfm2`` (LFM2-24B-A2B). They are
+imported by module, ``from pytorch_ps_mpi_tpu.models import lfm2``, and
+only ``lfm2``'s config class is exported by name beside the flax modules'.
 """
 
 from pytorch_ps_mpi_tpu.models.mlp import MLP

@@ -1,22 +1,32 @@
-"""Config-driven decoder of the ``xing4_0`` family (Xing4.0-29B-A4B): a
-DeepSeek-V3-style block — latent attention, leading dense layers, then
-expert layers with a sigmoid bias-corrected router and a shared expert —
-on n residual streams mixed through Sinkhorn-projected hyper-connections
-(``ops/hyper_connection.py``), with a multi-token-prediction module on
-the trunk. Read from the source ``config.json``'s key names; one function
-a mechanism; ``rms_norm`` and ``rotary`` are ``models/sdar_moe.py``'s, the
-routed experts ``parallel/dropless.py``'s, the loss ``models/gpt.py``'s.
+"""Config-driven decoder of the DeepSeek-V3-style block — latent
+attention, leading dense layers, then expert layers with a sigmoid
+bias-corrected router and a shared expert, with a multi-token-prediction
+module on the trunk — on either residual path, chosen by the
+configuration: n residual streams mixed through Sinkhorn-projected
+hyper-connections (``ops/hyper_connection.py``; ``hc_mult`` >= 2:
+Xing4.0-29B-A4B, ``xing4_0``), or ONE plain pre-norm residual stream (a
+source without ``hc_mult``: JoyAI-LLM-Flash, ``joyai_llm_flash``). Read
+from the source ``config.json``'s key names; one function a mechanism;
+``rms_norm`` and ``rotary`` are ``models/sdar_moe.py``'s, the routed
+experts ``parallel/dropless.py``'s, the loss ``models/gpt.py``'s.
 
-With d = ``hidden_size``, n = ``hc_mult`` streams X in R^{n x d} a
+With d = ``hidden_size``, every layer is two sub-layers F, the attention,
+then the feed-forward. On n = ``hc_mult`` streams X in R^{n x d} a
 position (the embedding copied n times before layer 0, the streams
-summed before the final norm), every layer is two hyper-connected
-sub-layers, ``X <- H_res X + H_post^T F(RMSNorm(H_pre X))`` with F the
-attention, then the feed-forward:
+summed before the final norm) each is hyper-connected, ``X <- H_res X +
+H_post^T F(RMSNorm(H_pre X))``; on the plain path (``hc_mult`` 0: no
+stream axis, no hyper-connection leaf, scope or kernel; ``hc_mult`` 1
+would still scale the one stream by ``H_pre`` and ``H_post`` and is
+refused) it is ``x <- x + F(RMSNorm(x))``:
 
 - *Latent attention.* ``c_q = RMSNorm(u W_qa)``; ``q = c_q W_qb`` ->
   heads of ``[q_nope | q_rope]``; ``[c_kv | k_rope] = u W_kva``;
   ``RMSNorm(c_kv) W_kvb`` -> heads of ``[k_nope | v]``; ``q_rope`` and
-  the ONE ``k_rope`` (shared by all heads) rotated at YaRN's frequencies;
+  the ONE ``k_rope`` (shared by all heads) rotated at YaRN's frequencies
+  (the plain ones where ``rope_scaling`` is null), in pairs ``(i, i +
+  rope / 2)``, or, with ``rope_interleave``, the published ``(2i, 2i +
+  1)``: the pairs are brought side to side first, as the DeepSeek-V3
+  modelling code does, and the scores are the interleaved rotation's;
   ``k = [k_nope | k_rope]``; causal ``softmax(q k^T scale) v`` with
   ``scale = (nope + rope)^-0.5 m^2``, ``m = 0.1 mscale_all_dim ln(factor)
   + 1``; then ``W_o``. Keys and values are materialised per head (the
@@ -32,8 +42,9 @@ attention, then the feed-forward:
   of the source family is non-gradient state this package does not
   carry).
 - *Multi-token prediction, depth 1.* ``h'_i = [RMSNorm(h_i) ;
-  RMSNorm(Emb(t_{i+1}))] W_eh`` with h the trunk's summed streams before
-  the final norm; one expert layer over n copies of ``h'``; the module's
+  RMSNorm(Emb(t_{i+1}))] W_eh`` with h the trunk's summed streams (the
+  one stream) before the final norm; one expert layer over n copies of
+  ``h'`` (over ``h'``); the module's
   own final norm and the SHARED head give logits for ``t_{i+2}``; ``loss =
   CE(main, t_{i+1}) + mtp_loss_weight * CE(mtp, t_{i+2})``. The embedding
   and the head have two readers: their gradients are sums.
@@ -48,11 +59,13 @@ float32. Named scopes for a device trace: ``attn.mla_proj``,
 ``moe.shared``, ``moe.route|dispatch|experts|combine``, ``loss.head``;
 inside the prediction module the same behind ``mtp.`` (``mtp.attn.mla``,
 ...; ``dropless``'s own keep their names), and ``mtp.block``,
-``loss.mtp``. With ``remat`` a layer is a checkpoint with the n-stream
-carry as its explicit input, which keeps the flash kernels' output and
-per-row logsumexp and the expert layer's plan and router choice
-(``ops/_common.checkpoint_layer``) and recomputes the rest: the
-hyper-connections' forward pair and the matrix products run twice.
+``loss.mtp``. One set-up log row ``model.plan`` a trace says what the
+program holds (``record_plan``). With ``remat`` a layer is a checkpoint
+with the carry (n streams, or ``x``) as its explicit input, which keeps
+the flash kernels' output and per-row logsumexp and the expert layer's
+plan and router choice (``ops/_common.checkpoint_layer``) and recomputes
+the rest: the hyper-connections' forward pair and the matrix products
+run twice.
 """
 
 from __future__ import annotations
@@ -70,11 +83,12 @@ from pytorch_ps_mpi_tpu.models.sdar_moe import rms_norm, rotary
 from pytorch_ps_mpi_tpu.ops import hyper_connection as hc
 from pytorch_ps_mpi_tpu.ops._common import checkpoint_layer
 from pytorch_ps_mpi_tpu.parallel.dropless import dropless_moe
+from pytorch_ps_mpi_tpu.telemetry.recorder import setup_event
 
 
 @dataclasses.dataclass(frozen=True)
 class XingConfig:
-    vocab_size: int
+    vocab_size: int                    # the rows held here
     hidden_size: int
     intermediate_size: int             # the leading dense layers' SwiGLU
     moe_intermediate_size: int
@@ -93,7 +107,7 @@ class XingConfig:
     num_nextn_predict_layers: int = 0
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
-    hc_mult: int = 4
+    hc_mult: int = 4                   # 0: the plain residual stream
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
@@ -104,6 +118,8 @@ class XingConfig:
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None  # sorted items
+    rope_interleave: bool = False      # rotary pairs (2i, 2i + 1)
+    published_vocab_size: int = 0      # where vocab_size is a slice
     capacity_factor: float = 2.0       # parallel/dropless.py
     dtype: Any = jnp.float32
     attention: str = "full"            # 'full' | 'flash' | 'einsum' (bert.py)
@@ -115,6 +131,10 @@ class XingConfig:
                              f"{self.num_nextn_predict_layers}")
         if self.rope_scaling and dict(self.rope_scaling).get("type") != "yarn":
             raise ValueError(f"rope_scaling {dict(self.rope_scaling)}")
+        if self.hc_mult == 1 or self.hc_mult < 0:
+            raise ValueError(f"hc_mult {self.hc_mult}: 0 is the plain "
+                             "residual stream, hyper-connections take 2 or "
+                             "more (one stream would still be scaled)")
 
     @property
     def layers_dense(self) -> Tuple[bool, ...]:
@@ -126,7 +146,8 @@ class XingConfig:
         chip holds a share, ``n_routed_experts`` counts the experts held
         (first ``first_expert``) and ``published_n_routed_experts`` is the
         router's width; ``num_hidden_layers`` counts the layers held and
-        ``published_layer_index`` gives each one's index in the model."""
+        ``published_layer_index`` gives each one's index in the model. A
+        source without ``hc_mult`` has one plain residual stream."""
         for key, only in (("scoring_func", "sigmoid"), ("n_group", 1),
                           ("topk_method", "noaux_tc"), ("topk_group", 1)):
             if config.get(key, only) != only:
@@ -146,7 +167,7 @@ class XingConfig:
             n_routed_experts=int(config.get("published_n_routed_experts",
                                             held)),
             experts_held=(int(config.get("first_expert", 0)), held),
-            layer_index=index,
+            layer_index=index, hc_mult=int(config.get("hc_mult", 0)),
             rope_scaling=tuple(sorted(scaling.items())) if scaling else None,
             capacity_factor=float(config.get("moe_capacity_factor", 2.0)),
             dtype=jnp.dtype(config.get("dtype", "float32")).type)
@@ -174,7 +195,8 @@ def init(key, cfg: XingConfig, scale: float = 0.02, embed_scale: float = 1.0):
     """Seeded float32 parameters: normal(0, ``scale``) matrices, unit norm
     gains, embedding rows at ``embed_scale`` (``models/sdar_moe.py::init``
     says why a random router wants unit rows), a zero router bias, the
-    hyper-connections as ``ops/hyper_connection.py::init``."""
+    hyper-connections (where there are streams) as
+    ``ops/hyper_connection.py::init``."""
     c = cfg
     d, f, n = c.hidden_size, c.moe_intermediate_size, c.hc_mult
     h, held = c.num_attention_heads, c.experts_held[1]
@@ -196,7 +218,6 @@ def init(key, cfg: XingConfig, scale: float = 0.02, embed_scale: float = 1.0):
                                         bias=c.hc_init_bias)
         p = {"input_layernorm": ones(d),
              "post_attention_layernorm": ones(d),
-             "hc_attn": connection(k[0]), "hc_mlp": connection(k[1]),
              "self_attn": {
                  "q_a_proj": normal(k[2], d, c.q_lora_rank),
                  "q_a_layernorm": ones(c.q_lora_rank),
@@ -208,6 +229,8 @@ def init(key, cfg: XingConfig, scale: float = 0.02, embed_scale: float = 1.0):
                  "kv_b_proj": normal(k[5], c.kv_lora_rank, h * (
                      c.qk_nope_head_dim + c.v_head_dim)),
                  "o_proj": normal(k[6], h * c.v_head_dim, d)}}
+        if n:
+            p.update(hc_attn=connection(k[0]), hc_mlp=connection(k[1]))
         if dense:
             p["mlp"] = swiglu(k[7], c.intermediate_size)
         else:
@@ -273,6 +296,14 @@ def yarn_frequencies(cfg: XingConfig):
             _yarn_mscale(factor, s.get("mscale", 1)) / m_all, m_all * m_all)
 
 
+def _pairs_to_halves(x):
+    """The last axis's pairs ``(2i, 2i + 1)`` brought to ``(i, i + d / 2)``,
+    where ``rotary`` turns them: both q and the one k take the same
+    permutation, so their products are the interleaved rotation's."""
+    *lead, d = x.shape
+    return jnp.swapaxes(x.reshape(*lead, d // 2, 2), -1, -2).reshape(*lead, d)
+
+
 def latent_attention(u, p, cfg: XingConfig, positions, tag: str = ""):
     """``u [b, s, d]`` -> ``[b, s, d]``: causal latent attention at
     ``positions [s]``."""
@@ -293,8 +324,10 @@ def latent_attention(u, p, cfg: XingConfig, positions, tag: str = ""):
         c_kv = rms_norm(kv_a[..., :rank], p["kv_a_layernorm"], c.rms_norm_eps)
         kv = (c_kv @ p["kv_b_proj"].astype(dt)).reshape(
             b, s, heads, nope + c.v_head_dim)
+        side_to_side = _pairs_to_halves if c.rope_interleave else (lambda x: x)
         turn = lambda x: (on_cos_sin * rotary(
-            x, positions, c.rope_theta, freq=jnp.asarray(freq))).astype(dt)
+            side_to_side(x), positions, c.rope_theta,
+            freq=jnp.asarray(freq))).astype(dt)
         k_rope = turn(kv_a[..., rank:].reshape(b, s, 1, rope))
         q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
@@ -343,7 +376,7 @@ def expert_ffn(u, lp, cfg: XingConfig, tag: str = ""):
 
 
 # ---------------------------------------------------------------------------
-# a layer on n streams
+# a layer on n streams, or on one plain residual stream
 # ---------------------------------------------------------------------------
 
 def hyper_connected(streams, p, cfg: XingConfig, sub_layer, tag: str = ""):
@@ -356,15 +389,25 @@ def hyper_connected(streams, p, cfg: XingConfig, sub_layer, tag: str = ""):
         norm_eps=c.rms_norm_eps, tag=tag)
 
 
-def decoder_layer(streams, lp, cfg: XingConfig, positions, dense: bool,
+def residual(x, sub_layer):
+    """The plain path: ``x' = x + F(x)`` over ``x [b, s, d]``;
+    ``sub_layer`` as ``hyper_connected``'s (it norms its input itself)."""
+    out = sub_layer(x)
+    y, more = out if isinstance(out, tuple) else (out, None)
+    return x + y, more
+
+
+def decoder_layer(carry, lp, cfg: XingConfig, positions, dense: bool,
                   tag: str = ""):
-    """One layer: (streams ``[n, b, s, d]``, pairs per held expert
-    ``[count]``: zeros from a dense layer)."""
+    """One layer: (the carry — streams ``[n, b, s, d]``, or ``x [b, s,
+    d]`` on the plain path — and the pairs per held expert ``[count]``:
+    zeros from a dense layer)."""
     c = cfg
-    streams, _ = hyper_connected(
-        streams, lp["hc_attn"], c, lambda u: latent_attention(
+
+    def attention(u):
+        return latent_attention(
             rms_norm(u, lp["input_layernorm"], c.rms_norm_eps),
-            lp["self_attn"], c, positions, tag), tag)
+            lp["self_attn"], c, positions, tag)
 
     def feed_forward(u):
         u = rms_norm(u, lp["post_attention_layernorm"], c.rms_norm_eps)
@@ -373,36 +416,70 @@ def decoder_layer(streams, lp, cfg: XingConfig, positions, dense: bool,
                 jnp.zeros((c.experts_held[1],), jnp.int32)
         return expert_ffn(u, lp, c, tag)
 
+    if not c.hc_mult:
+        return residual(residual(carry, attention)[0], feed_forward)
+    streams, _ = hyper_connected(carry, lp["hc_attn"], c, attention, tag)
     return hyper_connected(streams, lp["hc_mlp"], c, feed_forward, tag)
 
 
 def _layer_fn(cfg: XingConfig, positions, dense: bool, tag: str = ""):
-    def layer(streams, lp):
-        return decoder_layer(streams, lp, cfg, positions, dense, tag)
+    def layer(carry, lp):
+        return decoder_layer(carry, lp, cfg, positions, dense, tag)
 
     return checkpoint_layer(layer) if cfg.remat else layer
 
 
 def _spread(x, cfg: XingConfig):
-    return jnp.broadcast_to(x[None], (cfg.hc_mult, *x.shape))
+    """``x [b, s, d]`` copied into the n streams; itself on the plain
+    path."""
+    return jnp.broadcast_to(x[None], (cfg.hc_mult, *x.shape)) \
+        if cfg.hc_mult else x
+
+
+def _joined(carry, cfg: XingConfig):
+    """The streams' sum ``[b, s, d]``; the one stream as it is."""
+    if not cfg.hc_mult:
+        return carry
+    return jnp.sum(carry.astype(jnp.float32), axis=0).astype(cfg.dtype)
+
+
+def record_plan(cfg: XingConfig) -> None:
+    """One ``model.plan`` row in the set-up log a trace: the layers held
+    by kind at their published indices, the residual path (``plain``, or
+    ``hc`` with its streams), the prediction modules, the experts held of
+    the router's width, the vocabulary rows held of the published."""
+    c = cfg
+    setup_event(
+        "model.plan",
+        dense_layers=[i for i, d in zip(c.layer_index, c.layers_dense) if d],
+        expert_layers=[i for i, d in zip(c.layer_index, c.layers_dense)
+                       if not d],
+        residual="hc" if c.hc_mult else "plain", streams=int(c.hc_mult),
+        prediction_modules=int(c.num_nextn_predict_layers),
+        first_expert=int(c.experts_held[0]),
+        experts_held=int(c.experts_held[1]), experts=int(c.n_routed_experts),
+        vocab_rows=int(c.vocab_size),
+        vocab_published=int(c.published_vocab_size or c.vocab_size))
 
 
 def hidden_states(params, tokens, cfg: XingConfig):
-    """``tokens [b, s]`` -> (the summed streams ``[b, s, d]`` before the
-    final norm, pairs per held expert ``[expert layers, count]``)."""
+    """``tokens [b, s]`` -> (the summed streams, or the one, ``[b, s, d]``
+    before the final norm, pairs per held expert ``[expert layers,
+    count]``)."""
     x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(cfg.dtype)
-    streams = _spread(x, cfg)
-    hc.record_plan(streams, cfg.hc_sinkhorn_iters, 2 * (
-        len(cfg.layer_index) + cfg.num_nextn_predict_layers))
+    carry = _spread(x, cfg)
+    record_plan(cfg)
+    if cfg.hc_mult:
+        hc.record_plan(carry, cfg.hc_sinkhorn_iters, 2 * (
+            len(cfg.layer_index) + cfg.num_nextn_predict_layers))
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     loads = []
     for i, dense in enumerate(cfg.layers_dense):
-        streams, n = _layer_fn(cfg, positions, dense)(
-            streams, params[f"layer_{i}"])
+        carry, n = _layer_fn(cfg, positions, dense)(
+            carry, params[f"layer_{i}"])
         if not dense:
             loads.append(n)
-    h = jnp.sum(streams.astype(jnp.float32), axis=0).astype(cfg.dtype)
-    return h, jnp.stack(loads) if loads else jnp.zeros(
+    return _joined(carry, cfg), jnp.stack(loads) if loads else jnp.zeros(
         (0, cfg.experts_held[1]), jnp.int32)
 
 
@@ -429,11 +506,10 @@ def mtp_hidden(params, h, tokens, cfg: XingConfig):
                                  axis=-1)
         x = joined @ mp["eh_proj"].astype(c.dtype)
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    streams, loads = _layer_fn(c, positions, False, "mtp.")(
+    carry, loads = _layer_fn(c, positions, False, "mtp.")(
         _spread(x, c), mp["layer"])
     with jax.named_scope("mtp.block"):
-        return jnp.sum(streams.astype(jnp.float32), axis=0).astype(c.dtype), \
-            loads[None]
+        return _joined(carry, c), loads[None]
 
 
 def apply(params, tokens, cfg: XingConfig):

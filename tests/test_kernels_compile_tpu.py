@@ -184,6 +184,27 @@ def test_latent_attention_kernels_compile(v5e, pad):
         assert f"flash_wide_{which}" in text, which
 
 
+def test_latent_attention_kernels_compile_at_8k(v5e):
+    """joyai-llm-flash.lm8k: the causal ``flash_wide_*`` kernels at 32 heads
+    of 8,192 positions (twice ``lm4k``'s rows), q and k 192 wide over a
+    128-wide value, bf16: forward and both backward kernels, and the
+    gradients come out at the inputs' shapes."""
+    def loss(q, k, v):
+        out = attention_pallas.flash_attention(q, k, v, causal=True,
+                                               scale=192 ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    dev = SingleDeviceSharding(v5e)
+    shapes = [(1, 8192, 32, 192), (1, 8192, 32, 192), (1, 8192, 32, 128)]
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=dev)
+            for s in shapes]
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile()
+    text = compiled.as_text()
+    for which in ("fwd", "dq", "dkv"):
+        assert f"flash_wide_{which}" in text, which
+    assert [a.shape for a in compiled.out_info] == shapes
+
+
 def test_causal_grouped_kernels_compile_at_the_cells_shape(v5e):
     """lfm2-24b-a2b.lm8kx2: 2 rows of 8,192 positions, 32 query over 8
     key-value heads of 64, causal. Every part was there (grouped heads
