@@ -14,7 +14,7 @@ SUBLANE = 8     # float32 sublane tile
 # What a layer's checkpoint saves: values dear to recompute and small to
 # hold, each named by the operator that produces it (``keep``). The flash
 # forward kernel's output and per-row logsumexp (a second run of the
-# kernel otherwise, only to hand them to the backward kernels), and the
+# kernel otherwise, only to hand them to the backward kernel), and the
 # expert layer's plan (two sorts and a handful of int32 vectors).
 KEPT = ("flash.out", "flash.lse", "moe.plan")
 

@@ -28,7 +28,7 @@ Design choices:
   length, so a head of 512 x 64 is ONE grid step a kernel (0.848 ms a
   layer of 16 x 12 such heads against 4.608 in 128 x 128 tiles;
   ``_default_block_targets`` holds every reading).
-- **A sub-tile sweep inside each grid step** (``_sweep``, all three
+- **A sub-tile sweep inside each grid step** (``_sweep``, both
   kernels). The grid tile sets the DMAs and the number of grid steps;
   the work inside it is done sub-tile by sub-tile in a rolled loop over
   ``pl.ds`` slices of the resident blocks, and each sub-tile gets a class
@@ -43,8 +43,27 @@ Design choices:
   row in the set-up log a trace). A grid tile with no allowed pair
   is skipped whole by a predicated ``pl.when``: ring's future blocks
   cost ~0. The score tile in flight is a sub-tile, never the grid tile.
-- **Backward is two Pallas kernels** (dq over k tiles; dk/dv over q
-  tiles) recomputing p from the saved logsumexp — no O(L²) residual.
+- **Backward is ONE Pallas kernel** (``_bwd_kernel``, PR 40) on the
+  forward's grid, recomputing p from the saved logsumexp — no O(L²)
+  residual. A sub-tile's visit computes ``p``, ``dp = do·vᵀ`` and ``ds =
+  p·(dp - Dm)`` once and feeds all three gradients from them: ``dv +=
+  pᵀ·do``, ``dk += dsᵀ·q``, ``dq += ds·k`` — five products, one
+  ``exp``, one mask, where a dq kernel and a dk/dv kernel did seven and two of
+  each. dq is summed over a q tile's k sweep in a ``(bq, d)`` scratch,
+  like the forward's output; dk and dv are summed over q tiles, so they
+  stay resident: float32 accumulators of one WHOLE key-value head
+  (``lk·(d + dv)·4`` bytes: 10.5 MB at 8,192 x (192 + 128)), zeroed at
+  the head's first grid step, scaled, cast and written to output blocks
+  ``(1, lk, d)`` / ``(1, lk, dv)`` at its last; the blocks' index moves
+  only with the key-value head, so Pallas writes them to HBM once a
+  head. With their output blocks and a tile's that is over Mosaic's 16
+  MiB default, so the call asks for ``_VMEM_BYTES``; a head too long to
+  fit (about 45,000 positions of 128-wide bf16) is refused when the
+  backward is traced (``_backward_vmem``). Measured on a v5e, a layer's
+  backward alone, two kernels -> one (``PERF.md`` section 6, PR 40, run
+  K1): 22.59 -> 15.20 ms at 32 heads of 8,192 x 192 over 128; 17.88 ->
+  12.68 under the block-diffusion mask at 2 x 32 over 4 heads of 8,192 x
+  128; 0.642 -> 0.475 at 16 x 12 unmasked heads of 512 x 64.
   The custom VJP also accepts a cotangent for the returned logsumexp
   (folded into ``Dm = D - g_lse``), which is what lets ring attention
   combine per-block normalized outputs differentiably.
@@ -54,10 +73,10 @@ Design choices:
 
 - **Masks and head counts.** ``mask`` is ``None``, ``'causal'``,
   ``'window'`` (causal within ``window`` positions: ``0 <= q_pos - k_pos <
-  window``; the grid's inner axis then runs over the k tiles (dk/dv: the
-  q tiles) the band crosses and NO OTHER: 2 of 16 at a window of 512 in
-  8,192 positions, the index maps and the kernels' positions both offset
-  by the band's first tile) or ``'block_diffusion'`` (the training mask
+  window``; the grid's inner axis then runs over the k tiles the band
+  crosses and NO OTHER: 2 of 16 at a window of 512 in 8,192 positions,
+  the index maps and the kernels' positions both offset by the band's
+  first tile) or ``'block_diffusion'`` (the training mask
   of block-diffusion language
   models over a doubled sequence: a noised copy at positions
   ``0..half-1`` and the clean copy at ``half..2*half-1``; with
@@ -68,13 +87,16 @@ Design choices:
   that it costs no DMA either (40 of 64 grid tiles a head are dead at
   2 x 4096 positions). ``k``/``v`` may carry fewer heads than ``q``
   (grouped-query attention): query head ``h`` reads key-value head
-  ``h // (heads // kv_heads)``, and the dk/dv kernel sums over the
-  group. ``v`` may be wider or narrower than ``q`` and ``k`` (differential
-  attention's value is two heads side by side, 128 against 64): the
-  output and ``dv`` take ``v``'s width, the scores ``q``'s. The
+  ``h // (heads // kv_heads)``, and dk and dv sum over the group: its
+  query heads are consecutive grid steps, through which the key-value
+  head's accumulators stay resident. ``v`` may be wider or narrower than
+  ``q`` and ``k`` (differential attention's value is two heads side by
+  side, 128 against 64): the output and ``dv`` take ``v``'s width, the
+  scores ``q``'s. The
   block-diffusion kernels carry a stable ``name`` each (``flash_bd_fwd``
-  / ``flash_bd_dq`` / ``flash_bd_dkv``): it is the HLO instruction's
-  name in a device trace; so do the window kernels (``flash_win_*``)
+  / ``flash_bd_dqkv``: the benchmark's readers match ``flash_bd_(fwd|dq|
+  dkv)`` and whatever follows): it is the HLO instruction's name in a
+  device trace; so do the window kernels (``flash_win_*``)
   and those with a value of another width (``flash_wide_*``). The
   unmasked and causal kernels of one width keep the name XLA gives them
   (the calling module's), which the benchmark's accepted readers match.
@@ -259,52 +281,28 @@ def _bd_k_tile(mask, j, kk, bq, bk):
                      jnp.minimum(jnp.maximum(kk, lo2), jnp.maximum(hi2, lo2)))
 
 
-def _bd_q_tile(mask, jk, jq, bq, bk, nq):
-    """The q tile to fetch for (k tile ``jk``, q tile ``jq``), as above."""
-    if mask[0] != "bd":
-        return jq
-    _, block, half = mask
-    ks = jk * bk
-    noised = ks < half
-    lo1 = jnp.where(noised, ks // bq, (ks - half + block) // bq)
-    hi1 = jnp.where(noised, (ks + bk - 1) // bq, half // bq - 1)
-    lo2 = jnp.where(noised, hi1, ks // bq)
-    hi2 = jnp.where(noised, hi1, nq - 1)
-    return jnp.where(jq <= hi1, jnp.maximum(jq, lo1),
-                     jnp.minimum(jnp.maximum(jq, lo2), hi2))
-
-
-def _band(mask, n_outer, n_inner, b_outer, b_inner, k_outer):
-    """The inner grid axis under the window mask: (its length, the tile
-    that step ``kk`` of outer tile ``j`` stands for, the tile to fetch
-    for it). The outer axis is the q tiles (``k_outer`` False: a q
-    tile's band is the k tiles from the one holding ``q_start - window +
-    1`` to the one holding its last row) or the k tiles (the q tiles
-    from the one holding ``k_start`` to the one holding ``k_end + window
-    - 1``); the axis is as long as the widest band, a step past the last
-    tile stands for a tile that does not exist (the kernels skip it) and
-    fetches the last. Any other mask: every tile, step ``kk`` is tile
-    ``kk``."""
+def _band(mask, nq, nk, bq, bk):
+    """The inner grid axis under the window mask: (its length, the k tile
+    that step ``kk`` of q tile ``j`` stands for, the tile to fetch for
+    it). A q tile's band is the k tiles from the one holding ``q_start -
+    window + 1`` to the one holding its last row; the axis is as long as
+    the widest band, a step past the last tile stands for a tile that does
+    not exist (the kernels skip it) and fetches the last. Any other mask:
+    every tile, step ``kk`` is tile ``kk``."""
     if mask[0] != "window":
-        return n_inner, (lambda j, kk: kk), (lambda j, kk: kk)
-    w = mask[1]
-    if k_outer:
-        first = lambda j: (j * b_outer) // b_inner
-        last = lambda j: np.minimum(
-            (j * b_outer + b_outer + w - 2) // b_inner, n_inner - 1)
-    else:
-        first = lambda j, lib=jnp: lib.maximum(j * b_outer - w + 1, 0) // b_inner
-        last = lambda j: (j * b_outer + b_outer - 1) // b_inner
-    j = np.arange(n_outer)
-    steps = int(np.max(last(j) - (first(j) if k_outer else first(j, np)) + 1))
+        return nk, (lambda j, kk: kk), (lambda j, kk: kk)
+    first = lambda j, lib=jnp: lib.maximum(j * bq - mask[1] + 1, 0) // bk
+    last = lambda j: (j * bq + bq - 1) // bk
+    j = np.arange(nq)
+    steps = int(np.max(last(j) - first(j, np) + 1))
     return (steps, lambda j, kk: first(j) + kk,
-            lambda j, kk: jnp.minimum(first(j) + kk, n_inner - 1))
+            lambda j, kk: jnp.minimum(first(j) + kk, nk - 1))
 
 
 def _kernel_name(mask, which, wide=False):
     """``flash_bd_*`` / ``flash_win_*`` / ``flash_wide_*`` (``fwd``,
-    ``dq``, ``dkv``); the unmasked and causal kernels of one width keep
-    the name XLA gives them (module docstring)."""
+    ``dqkv``); the unmasked and causal kernels of one width keep the name
+    XLA gives them (module docstring)."""
     kind = {"bd": "bd", "window": "win"}.get(mask[0], "wide" if wide else None)
     return kind and f"flash_{kind}_{which}"
 
@@ -333,13 +331,14 @@ def _rows(i, n):
     return pl.ds(i * n if isinstance(i, int) else pl.multiple_of(i * n, n), n)
 
 
-def _sweep(mask, q_start, k_start, bq, bk, sq, sk, q_outer, visit):
-    """Walk the ``sq x sk`` sub-tiles of the resident ``bq x bk`` tile and
-    class each from its corner positions, as scalars at run time (the
-    offsets may be traced): a DEAD one (no allowed pair) is skipped, a
-    FULL one (every pair allowed) gets ``visit(i, j, q0, k0, False)``, a
-    CUT one (the mask passes through it) ``visit(i, j, q0, k0, True)``;
-    the last argument is static, so the unmasked body carries no mask."""
+def _sweep(mask, q_start, k_start, bq, bk, sq, sk, visit):
+    """Walk the ``sq x sk`` sub-tiles of the resident ``bq x bk`` tile (a
+    q sub-tile's k sub-tiles in turn) and class each from its corner
+    positions, as scalars at run time (the offsets may be traced): a DEAD
+    one (no allowed pair) is skipped, a FULL one (every pair allowed) gets
+    ``visit(i, j, q0, k0, False)``, a CUT one (the mask passes through it)
+    ``visit(i, j, q0, k0, True)``; the last argument is static, so the
+    unmasked body carries no mask."""
     def one(i, j):
         q0, k0 = q_start + i * sq, k_start + j * sk
         if mask[0] == "none":
@@ -351,10 +350,7 @@ def _sweep(mask, q_start, k_start, bq, bk, sq, sk, q_outer, visit):
         pl.when(live & jnp.logical_not(full))(
             lambda: visit(i, j, q0, k0, True))
 
-    if q_outer:
-        _loop(bq // sq, lambda i: _loop(bk // sk, lambda j: one(i, j)))
-    else:
-        _loop(bk // sk, lambda j: _loop(bq // sq, lambda i: one(i, j)))
+    _loop(bq // sq, lambda i: _loop(bk // sk, lambda j: one(i, j)))
 
 
 def _lanes(col, n):
@@ -434,7 +430,7 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # skip tiles the mask empties (causal: the future), without a sweep
     @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
     def _():
-        _sweep(mask, q_start, k_start, bq, bk, sq, sk, True, visit)
+        _sweep(mask, q_start, k_start, bq, bk, sq, sk, visit)
 
     @pl.when(kk == nk - 1)
     def _():
@@ -452,7 +448,7 @@ def _fwd(q3, k3, v3, q_off, k_off, mask, scale, bq, bk, sq, sk):
     group = bh // k3.shape[0]       # query heads per key-value head
     nq, nk = lq // bq, lk // bk
     dv = v3.shape[2]
-    steps, tile, fetched = _band(mask, nq, nk, bq, bk, False)
+    steps, tile, fetched = _band(mask, nq, nk, bq, bk)
     kern = functools.partial(
         _fwd_kernel, mask=mask, scale=scale, bq=bq, bk=bk, sq=sq, sk=sk,
         nk=steps, tile=tile
@@ -505,90 +501,109 @@ def _recompute_p(q, k, lse_rep, q0, k0, mask, scale, cut):
     return jnp.where(s > _MASK_THRESH, jnp.exp(s - lse), 0.0)
 
 
-def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   dm_ref, dq_ref, dq_acc, *, mask, scale, bq, bk, sq, sk,
-                   nk, tile):
-    j = pl.program_id(1)
-    kk = pl.program_id(2)
+def _when(cond):
+    """``pl.when`` that also takes a condition known when the kernel is
+    traced (a head that is one grid step has nothing to carry)."""
+    if isinstance(cond, bool):
+        return lambda fn: fn() if cond else None
+    return pl.when(cond)
 
-    @pl.when(kk == 0)
+
+def _bwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                dm_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                *, mask, scale, bq, bk, sq, sk, nq, nk, tile, group):
+    """dq, dk and dv from ONE recomputation of a sub-tile's p and dp, on
+    the forward kernel's grid and visits: dq is summed over a q tile's k
+    sweep like the forward's output, dk and dv of the whole key-value head
+    stay resident in float32 across the head's sweep and the sweeps of
+    the ``group`` query heads that read it. A row of dq, dk or dv receives
+    its terms in the order the two kernels before PR 40 gave them (head of
+    the group, q tile, q sub-tile; k tile, k sub-tile): interpreted, the
+    gradients equal theirs to the last bit."""
+    head = pl.program_id(0) % group if group > 1 else 0
+    j = pl.program_id(1) if nq > 1 else 0
+    kk = pl.program_id(2) if nk > 1 else 0
+    t = tile(j, kk)             # the k tile this step stands for
+    lk = dk_acc.shape[0]
+
+    @_when((head == 0) & (j == 0) & (kk == 0))
+    def _():
+        def zero(c):
+            dk_acc[_rows(c, bk), :] = jnp.zeros((bk, dk_acc.shape[1]),
+                                                jnp.float32)
+            dv_acc[_rows(c, bk), :] = jnp.zeros((bk, dv_acc.shape[1]),
+                                                jnp.float32)
+
+        _loop(lk // bk, zero)
+
+    @_when(kk == 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_start = qo_ref[0] + j * bq
-    k_start = ko_ref[0] + tile(j, kk) * bk
+    k_start = ko_ref[0] + t * bk
 
     def visit(i, jj, q0, k0, cut):
         rq, rk = _rows(i, sq), _rows(jj, sk)
-        k, v = k_ref[0, rk, :], v_ref[0, rk, :]
-        p = _recompute_p(q_ref[0, rq, :], k, lse_ref[0, rq, :], q0, k0,
-                         mask, scale, cut)
-        dp = jax.lax.dot_general(
-            do_ref[0, rq, :], v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - _lanes(dm_ref[0, rq, :], sk))
-        dq_acc[rq, :] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
-    def _():
-        _sweep(mask, q_start, k_start, bq, bk, sq, sk, True, visit)
-
-    @pl.when(kk == nk - 1)
-    def _():
-        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    dm_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, mask, scale, bq, bk, sq, sk, nq, band, tile, steps):
-    jk = pl.program_id(1)
-    t = pl.program_id(2)       # (query head of the group, q tile), flattened
-    jq = tile(jk, t % band)    # the q tile: of the k tile's band
-
-    @pl.when(t == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    q_start = qo_ref[0] + jq * bq
-    k_start = ko_ref[0] + jk * bk
-
-    def visit(i, jj, q0, k0, cut):
-        rq, rk = _rows(i, sq), _rows(jj, sk)
+        ra = _rows(t * (bk // sk) + jj, sk)     # of the resident dk and dv
         q, do = q_ref[0, rq, :], do_ref[0, rq, :]
-        p = _recompute_p(q, k_ref[0, rk, :], lse_ref[0, rq, :], q0, k0,
-                         mask, scale, cut)
-        dv_acc[rk, :] += jax.lax.dot_general(
+        k, v = k_ref[0, rk, :], v_ref[0, rk, :]
+        p = _recompute_p(q, k, lse_ref[0, rq, :], q0, k0, mask, scale, cut)
+        dv_acc[ra, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, v_ref[0, rk, :], (((1,), (1,)), ((), ())),
+            do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - _lanes(dm_ref[0, rq, :], sk))
-        dk_acc[rk, :] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        ds = (p * (dp - _lanes(dm_ref[0, rq, :], sk))).astype(q.dtype)
+        dk_acc[ra, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_acc[rq, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    # the accumulators belong to the k side: its sub-tiles are the outer
-    # loop; under the window mask the last k tiles' bands end past the
-    # last q tile
-    live = _tile_live(mask, q_start, k_start, bq, bk)
-
-    @pl.when(live & (jq < nq) if mask[0] == "window" else live)
+    # a step past the band's last tile stands for a tile that is not
+    # there: it is not live, so no row of dk or dv past lk is touched
+    @pl.when(_tile_live(mask, q_start, k_start, bq, bk))
     def _():
-        _sweep(mask, q_start, k_start, bq, bk, sq, sk, False, visit)
+        _sweep(mask, q_start, k_start, bq, bk, sq, sk, visit)
 
-    @pl.when(t == steps - 1)
+    @_when(kk == nk - 1)
     def _():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+    @_when((head == group - 1) & (j == nq - 1) & (kk == nk - 1))
+    def _():
+        def write(c):
+            rows = _rows(c, bk)
+            dk_ref[0, rows, :] = (dk_acc[rows, :] * scale).astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+
+        _loop(lk // bk, write)
+
+
+_VMEM_BYTES = 100 << 20     # of the chip's 128 MiB, as ops/hyper_connection.py
+
+
+def _backward_vmem(lk, d, dv, dtype, bq, bk, sq, sk) -> int:
+    """The VMEM the backward call holds, from the shape alone and counted a
+    little high: the float32 dk and dv of one key-value head and their
+    output blocks at whole lane tiles (Pallas keeps two of each output
+    block), the tile's q, k, v, do, dq and the two rides, two of each,
+    dq's accumulator, and four float32 sub-tiles of scores in flight (on a
+    v5e Mosaic reports 34.0 MB used where this counts 36.7, at 8,192 x
+    (192 + 128) bf16)."""
+    lanes = lambda n: -(-n // _LANE) * _LANE
+    item = jnp.dtype(dtype).itemsize
+    heads = lk * (lanes(d) + lanes(dv)) * (4 + 2 * item)
+    tile = (2 * item * ((2 * bq + bk) * lanes(d) + (bq + bk) * lanes(dv))
+            + 4 * bq * (4 * _LANE + lanes(d)) + 16 * sq * sk)
+    return heads + tile
 
 
 def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
@@ -596,11 +611,18 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
     bh, lq, d = q3.shape
     bkv, lk = k3.shape[:2]
     dv = v3.shape[2]
-    wide = dv != d
     group = bh // bkv
     nq, nk = lq // bq, lk // bk
-    k_steps, k_tile, k_fetched = _band(mask, nq, nk, bq, bk, False)
-    q_steps, q_tile, q_fetched = _band(mask, nk, nq, bk, bq, True)
+    steps, tile, fetched = _band(mask, nq, nk, bq, bk)
+    held = _backward_vmem(lk, d, dv, q3.dtype, bq, bk, sq, sk)
+    if held > _VMEM_BYTES:
+        # no cell and no test comes near (10.5 MB of dk and dv in the
+        # largest cell; about 45,000 positions of 128-wide bf16 heads fit)
+        raise ValueError(
+            f"the backward flash kernel keeps dk and dv of one key-value "
+            f"head of {lk} positions in VMEM: about {held} bytes with a "
+            f"tile's blocks, over the {_VMEM_BYTES} it may ask for; shard "
+            "the sequence further (ring attention's blocks)")
     # D folds the out-cotangent; the lse-cotangent enters with opposite
     # sign in ds = p * (dp - (D - g_lse)). lse arrives lane-replicated
     # [bh, lq, LANE] (see _fwd); dm rides the same layout so both block
@@ -610,68 +632,56 @@ def _bwd(q3, k3, v3, q_off, k_off, out, lse, g_out, g_lse,
     dm = jnp.broadcast_to(dm[..., None], (bh, lq, _LANE))
     lse = jnp.broadcast_to(lse[..., None], (bh, lq, _LANE))
 
+    def q_map(i, j, kk):
+        return (i, j, 0)
+
     def kv_map(i, j, kk):
-        return (i // group, _bd_k_tile(mask, j, k_fetched(j, kk), bq, bk), 0)
+        return (i // group, _bd_k_tile(mask, j, fetched(j, kk), bq, bk), 0)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
-                          bq=bq, bk=bk, sq=sq, sk=sk, nk=k_steps,
-                          tile=k_tile),
-        grid=(bh, nq, k_steps),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, dv), kv_map),
-            pl.BlockSpec((1, bq, dv), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, bq, _LANE), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, bq, _LANE), lambda i, j, kk: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, lq, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=_interpret(),
-        name=_kernel_name(mask, "dq", wide),
-    )(q_off, k_off, q3, k3, v3, g_out, lse, dm)
+    # dk and dv of a whole key-value head are one block each, whose index
+    # moves only with the key-value head: Pallas writes them back once the
+    # `group` query heads that read the head are through
+    def whole(i, j, kk):
+        return (i // group, 0, 0)
 
-    # one key-value head gathers from the `group` query heads that read
-    # it: the innermost grid axis runs over (head of the group, q tile)
-    def q_map(i, jk, t):
-        return (i * group + t // q_steps, _bd_q_tile(
-            mask, jk, q_fetched(jk, t % q_steps), bq, bk, nq), 0)
-
-    dk, dv_out = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
-                          bq=bq, bk=bk, sq=sq, sk=sk, nq=nq, band=q_steps,
-                          tile=q_tile, steps=group * q_steps),
-        grid=(bkv, nk, group * q_steps),
+    # the forward kernel's grid: a q tile's blocks arrive once a q tile, k
+    # and v once a visit
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, mask=mask, scale=scale, bq=bq, bk=bk,
+                          sq=sq, sk=sk, nq=nq, nk=steps, tile=tile,
+                          group=group),
+        grid=(bh, nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
-            pl.BlockSpec((1, bk, dv), lambda i, jk, t: (i, jk, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, dv), kv_map),
             pl.BlockSpec((1, bq, dv), q_map),
             pl.BlockSpec((1, bq, _LANE), q_map),
             pl.BlockSpec((1, bq, _LANE), q_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0)),
-            pl.BlockSpec((1, bk, dv), lambda i, jk, t: (i, jk, 0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, lk, d), whole),
+            pl.BlockSpec((1, lk, dv), whole),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, lq, d), q3.dtype),
             jax.ShapeDtypeStruct((bkv, lk, d), k3.dtype),
             jax.ShapeDtypeStruct((bkv, lk, dv), v3.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((lk, d), jnp.float32),
+            pltpu.VMEM((lk, dv), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_VMEM_BYTES),
         interpret=_interpret(),
-        name=_kernel_name(mask, "dkv", wide),
+        name=_kernel_name(mask, "dqkv", dv != d),
     )(q_off, k_off, q3, k3, v3, g_out, lse, dm)
-    return dq, dk, dv_out
 
 
 # ---------------------------------------------------------------------------
@@ -863,12 +873,17 @@ def tile_census(mask: tuple, lq: int, lk: int, bq: int, bk: int,
 
 def flash_tiles(spec: tuple, lq: int, lk: int, dtype,
                 block_q: Optional[int] = None,
-                block_k: Optional[int] = None) -> Optional[dict]:
+                block_k: Optional[int] = None,
+                d: Optional[int] = None,
+                dv: Optional[int] = None) -> Optional[dict]:
     """What ``flash_attention`` does with one head of ``lq x lk`` scores
-    under the mask spec ``spec``: the grid tile, the sub-tile, and the
-    census of sub-tiles by class (the ``attn.flash_tiles`` row of a trace
-    is this dictionary); None where the tiling cannot serve the shape and
-    the dense path runs."""
+    under the mask spec ``spec``: the grid tile, the sub-tile, the census
+    of sub-tiles by class and, where the widths of q / k (``d``) and of v
+    (``dv``) are given, the ``backward`` pass (``fused``: one kernel gives
+    dq, dk and dv) with its ``resident_bytes``, the float32 dk and dv of
+    one key-value head that stay in VMEM across the head's sweep: the
+    ``attn.flash_tiles`` row of a trace is this dictionary. None where
+    the tiling cannot serve the shape and the dense path runs."""
     mb = _min_block_for(dtype)
     dbq, dbk = (_bd_block_targets() if spec[0] == "bd"
                 else _window_block_targets() if spec[0] == "window"
@@ -882,8 +897,12 @@ def flash_tiles(spec: tuple, lq: int, lk: int, dtype,
         return None
     tsq, tsk = _sub_tile_targets(spec, bq, bk)
     sq, sk = _pick_block(bq, tsq, mb), _pick_block(bk, tsk, mb)
-    return dict(mask=spec[0], block_q=bq, block_k=bk, sub_q=sq, sub_k=sk,
+    plan = dict(mask=spec[0], block_q=bq, block_k=bk, sub_q=sq, sub_k=sk,
                 **tile_census(spec, lq, lk, bq, bk, sq, sk))
+    if d is not None:
+        plan.update(backward="fused",
+                    resident_bytes=lk * (d + (d if dv is None else dv)) * 4)
+    return plan
 
 
 def flash_attention(
@@ -933,7 +952,7 @@ def flash_attention(
     q_offset = jnp.zeros((), jnp.int32) if q_offset is None else q_offset
     k_offset = jnp.zeros((), jnp.int32) if k_offset is None else k_offset
 
-    plan = flash_tiles(spec, lq, lk, q.dtype, block_q, block_k)
+    plan = flash_tiles(spec, lq, lk, q.dtype, block_q, block_k, d, v.shape[3])
     if plan is None:
         out, lse = _attention_jnp(q, k, v, q_offset, k_offset, spec, scale)
         return (out, lse) if return_lse else out

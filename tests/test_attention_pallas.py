@@ -306,7 +306,8 @@ def test_the_recorder_row_at_mlm512s_shape():
     """The counter of the mechanism: traced at ``bert-base.mlm512``'s head
     (512 x 64, bf16, unmasked) with the recorder on, ``flash_attention``
     writes one ``attn.flash_tiles`` row that reads 512 / 512 / 512 / 512
-    and ``full`` 1."""
+    and ``full`` 1, and since PR 40 which backward runs: ``fused``, with
+    the 262,144 bytes of one head's float32 dk and dv resident."""
     from pytorch_ps_mpi_tpu import telemetry
 
     q = jnp.zeros((1, 512, 1, 64), jnp.bfloat16)
@@ -319,7 +320,8 @@ def test_the_recorder_row_at_mlm512s_shape():
         telemetry.disable()
     assert rows == [{"mask": "none", "block_q": 512, "block_k": 512,
                      "sub_q": 512, "sub_k": 512, "dead": 0, "cut": 0,
-                     "full": 1}]
+                     "full": 1, "backward": "fused",
+                     "resident_bytes": 512 * (64 + 64) * 4}]
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -646,4 +648,137 @@ def test_tile_census_of_the_cells_and_its_recorder_row():
     assert len(rows) == 2 and rows[0]["attrs"]["block_k"] == 32
     assert rows[1]["attrs"] == {"mask": "causal", "block_q": 32,
                                 "block_k": 64, "sub_q": 32, "sub_k": 64,
-                                "dead": 0, "cut": 2, "full": 0}
+                                "dead": 0, "cut": 2, "full": 0,
+                                "backward": "fused",
+                                "resident_bytes": 64 * (16 + 16) * 4}
+
+
+# -- one backward kernel a layer (PR 40) ----------------------------------------
+
+def _flash_kw(mask, **kw):
+    """``flash_attention``'s arguments for a mask spec."""
+    if mask[0] == "bd":
+        return dict(kw, mask="block_diffusion", block=mask[1], half=mask[2])
+    if mask[0] == "window":
+        return dict(kw, mask="window", window=mask[1])
+    return dict(kw, causal=mask[0] == "causal")
+
+
+def _backward_and_oracle(monkeypatch, mask, q, k, v, w, offsets=(None, None),
+                         **kw):
+    """The gradients of ``sum(w * out) + sum(sin(lse))`` (so the logsumexp
+    carries a cotangent that is not zero) by the kernels and by the dense
+    oracle; the offsets arrive traced. Sub-tiles of 8 x 8: a grid tile
+    holds dead, cut and full ones."""
+    from pytorch_ps_mpi_tpu.ops import attention_pallas as ap
+
+    monkeypatch.setattr(ap, "_sub_tile_targets", lambda *a: (8, 8))
+    kw = _flash_kw(mask, return_lse=True, **kw)
+    given = {} if offsets[0] is None else dict(
+        zip(("q_offset", "k_offset"), map(jnp.int32, offsets)))
+
+    def total(attend):
+        def f(q, k, v, given):
+            o, lse = attend(q, k, v, given)
+            return jnp.sum(w * o) + jnp.sum(jnp.sin(lse))
+        return jax.jit(jax.grad(f, (0, 1, 2)))(q, k, v, given)
+
+    got = total(lambda q, k, v, given: flash_attention(q, k, v, **kw, **given))
+    want = total(lambda q, k, v, given: _attention_jnp(
+        q, k, v, given.get("q_offset", 0), given.get("k_offset", 0), mask,
+        q.shape[-1] ** -0.5))
+    for name, g, wnt in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(wnt),
+                                   rtol=2e-4, atol=3e-5, err_msg=name)
+    return got
+
+
+@pytest.mark.parametrize("dv", [16, 32, 8], ids=["equal", "wider", "narrower"])
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("mask", [("none",), ("causal",), ("window", 24),
+                                  ("bd", 4, 32)], ids=_spec_id)
+def test_the_one_backward_kernel_matches_the_oracle(monkeypatch, mask, group,
+                                                    dv):
+    """dq, dk and dv of the ONE backward kernel against the dense oracle's,
+    over the masks, grouped heads (dk and dv stay resident across the
+    group's sweeps) and a value as wide as, wider and narrower than the
+    keys. (Before the dq and dk/dv kernels were deleted, every case here,
+    the offset cases below and the window cases of ``test_flash_window.py``
+    also read bit-equal to that pair, interpreted: a row receives its
+    terms in the order the pair gave them.)"""
+    ks = jax.random.split(jax.random.key(group + dv), 4)
+    q = jax.random.normal(ks[0], (1, 64, 8, 16))
+    k = jax.random.normal(ks[1], (1, 64, 8 // group, 16))
+    v = jax.random.normal(ks[2], (1, 64, 8 // group, dv))
+    w = jax.random.normal(ks[3], (1, 64, 8, dv))
+    _backward_and_oracle(monkeypatch, mask, q, k, v, w, block_q=32,
+                         block_k=32)
+
+
+@pytest.mark.parametrize("lq, lk, bq, bk, q_off, k_off", [
+    (32, 64, 16, 32, 24, 8),      # ring's call: a block against a longer one
+    (64, 32, 32, 16, 40, 0),
+    (32, 64, 32, 32, 16, 0),      # one q tile: nothing of dq is carried
+    (32, 64, 16, 32, 0, 100),     # wholly in the future: every gradient zero
+])
+@pytest.mark.parametrize("group", [1, 4])
+def test_the_one_backward_kernel_under_traced_offsets(
+        monkeypatch, group, lq, lk, bq, bk, q_off, k_off):
+    """Ring attention's call: traced offsets, q and k blocks of unequal
+    length (the resident dk and dv are ``lk`` rows, whatever ``lq``)."""
+    ks = jax.random.split(jax.random.key(lq + k_off), 4)
+    q = jax.random.normal(ks[0], (2, lq, 4, 16))
+    k = jax.random.normal(ks[1], (2, lk, 4 // group, 16))
+    v = jax.random.normal(ks[2], (2, lk, 4 // group, 32))
+    w = jax.random.normal(ks[3], (2, lq, 4, 32))
+    got = _backward_and_oracle(monkeypatch, ("causal",), q, k, v, w,
+                               (q_off, k_off), block_q=bq, block_k=bk)
+    if k_off == 100:
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in got)
+
+
+def test_the_resident_head_its_row_and_its_limit(monkeypatch):
+    """The gradient's jaxpr holds ONE backward kernel with three outputs;
+    the ``attn.flash_tiles`` row says so and with how many bytes of dk and
+    dv resident; every cell's head fits the VMEM the call asks for, and a
+    head that does not is refused when the backward is traced, not by
+    Mosaic and not in the forward pass."""
+    from pytorch_ps_mpi_tpu import telemetry
+    from pytorch_ps_mpi_tpu.ops import attention_pallas as ap
+
+    # the cells (lk, d, dv; bf16, tiles of 1024 swept in 512): resident
+    # bytes as the row gives them, and the whole call's count under the limit
+    for lk, d, dv, want in [(8192, 192, 128, 10_485_760),     # joyai
+                            (8192, 128, 128, 8_388_608),      # bd4k
+                            (4096, 192, 128, 5_242_880),      # lm4k
+                            (8192, 64, 64, 4_194_304),        # lm8kx2
+                            (8192, 64, 128, 6_291_456),       # lm8k
+                            (1024, 64, 64, 524_288), (512, 64, 64, 262_144)]:
+        plan = ap.flash_tiles(("causal",), lk, lk, jnp.bfloat16, d=d, dv=dv)
+        assert (plan["backward"], plan["resident_bytes"]) == ("fused", want)
+        held = ap._backward_vmem(lk, d, dv, jnp.bfloat16, 1024, 1024, 512, 512)
+        assert want < held < 40 << 20 < ap._VMEM_BYTES
+    # ring's long blocks are what can pass it
+    assert ap._backward_vmem(65536, 128, 128, jnp.bfloat16, 1024, 1024, 512,
+                             512) > ap._VMEM_BYTES
+    # a caller that gives no widths (the benchmark's window reader) gets
+    # the plan it always got
+    assert "backward" not in ap.flash_tiles(("causal",), 1024, 1024,
+                                            jnp.bfloat16)
+
+    q, k, v = qkv(l=64)
+    fn = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=32, block_k=32) ** 2), (0, 1, 2))
+    calls = [e for e in jax.make_jaxpr(fn)(q, k, v).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert [len(e.outvars) for e in calls] == [2, 3]    # forward; dq, dk, dv
+    (row,) = [e["attrs"] for e in telemetry.setup_rows()
+              if e["name"] == "attn.flash_tiles"]
+    assert (row["backward"], row["resident_bytes"]) == (
+        "fused", 64 * (16 + 16) * 4)
+
+    monkeypatch.setattr(ap, "_VMEM_BYTES", 60_000)
+    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+    assert out.shape == q.shape                         # no backward, no limit
+    with pytest.raises(ValueError, match="dk and dv of one key-value head"):
+        fn(q, k, v)

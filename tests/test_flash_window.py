@@ -1,4 +1,4 @@
-"""The window mask and a value wider than the keys in the three flash
+"""The window mask and a value wider than the keys in the two flash
 kernels (interpreted), against the dense masked softmax; the census of
 sub-tiles under the window; the band of grid steps; the kernels' names."""
 
@@ -87,22 +87,20 @@ def test_census_under_the_window(window, sub):
 def test_the_cells_window_layer():
     """8,192 positions, window 512, sub-tiles of 512: 2 live sub-tiles a
     q sub-tile (1 in the first), none full; and the grid's inner axis is
-    2 k tiles a q tile (and 2 q tiles a k tile), not 16 or 8."""
+    2 k tiles a q tile, not 16 or 8 (both kernels walk the q tiles' bands
+    since PR 40: the transposed band went with the dk/dv kernel)."""
     plan = ap.flash_tiles(("window", 512), 8192, 8192, jnp.bfloat16)
     assert plan == {"mask": "window", "block_q": 512, "block_k": 512,
                     "sub_q": 512, "sub_k": 512, "dead": 225, "cut": 31,
                     "full": 0}
     for bq, bk in ((512, 512), (1024, 1024)):
         n = 8192 // bq
-        steps, tile, fetched = ap._band(("window", 512), n, n, bq, bk, False)
+        steps, tile, fetched = ap._band(("window", 512), n, n, bq, bk)
         assert steps == 2
         assert [int(tile(j, 0)) for j in (0, 1, 5)] == [0, 0, 4]
         assert int(fetched(n - 1, 1)) == n - 1
-        steps, tile, fetched = ap._band(("window", 512), n, n, bk, bq, True)
-        assert steps == 2
-        assert int(tile(n - 1, 1)) == n and int(fetched(n - 1, 1)) == n - 1
     # any other mask: every tile, step kk is tile kk
-    steps, tile, fetched = ap._band(("causal",), 8, 8, 1024, 1024, False)
+    steps, tile, fetched = ap._band(("causal",), 8, 8, 1024, 1024)
     assert steps == 8 and tile(3, 5) == 5 and fetched(3, 5) == 5
 
 
@@ -115,9 +113,11 @@ def test_flash_tiles_says_where_the_dense_path_runs():
 
 def test_kernel_names():
     assert ap._kernel_name(("window", 512), "fwd") == "flash_win_fwd"
-    assert ap._kernel_name(("window", 512), "dkv", True) == "flash_win_dkv"
-    assert ap._kernel_name(("causal",), "dq", True) == "flash_wide_dq"
-    assert ap._kernel_name(("bd", 4, 64), "dq") == "flash_bd_dq"
+    # the one backward kernel: "dq" + "kv" to the benchmark's readers
+    assert ap._kernel_name(("bd", 4, 64), "dqkv") == "flash_bd_dqkv"
+    assert ap._kernel_name(("window", 512), "dqkv", True) == "flash_win_dqkv"
+    assert ap._kernel_name(("causal",), "dqkv", True) == "flash_wide_dqkv"
+    assert ap._kernel_name(("causal",), "dqkv") is None
     # the accepted readers match the name XLA gives these
     assert ap._kernel_name(("causal",), "fwd") is None
     assert ap._kernel_name(("none",), "fwd") is None

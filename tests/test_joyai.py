@@ -409,11 +409,15 @@ def test_latent_attention_is_the_references_at_plain_frequencies():
 # bytes (leaves in path order), computed with the PARENT's models/xing.py
 # (commit 8445f6a) by the statements of ``hc_digest`` on this machine's
 # CPU backend; a backend whose parent reads otherwise skips the digest
-# and the test still holds the lowered text's scopes
+# and the test still holds the lowered text's scopes. PR 40 put ONE backward
+# flash kernel where two were (``ops/attention_pallas.py``): the loss and
+# every gradient through the four attention layers kept 8445f6a's digests
+# (the kernel adds a row's terms in the pair's order), the text is PR 40's
+# (7c44feb20892304c... before it)
 PARENT = {
     "loss": "187eab777a1d3d0623eae0731fba64905837d7c403db407a4376708ba9c41666",
     "grads": "9e5806610d3a1e61acd3036323cc69a26f31409c5bd042cce4a3c9b577651ea1",
-    "text": "7c44feb20892304c25e5437b0b9e0b15be2024af97355d373e7bc064b63c720d",
+    "text": "ddbf732c853c9bae1bdcf5b06e63694d2cdce6276da87fcf8f0a646e21b2cad3",
 }
 
 

@@ -13,6 +13,7 @@ pass as the kernel. Execution and numerics on the chip are
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,8 +119,8 @@ def test_flash_forward_and_backward_compile(v5e, seq, dtype, d, causal):
 
     qkv = ((2, seq, 4, d), dtype)
     text = compile_for(v5e, jax.grad(loss, (0, 1, 2)), qkv, qkv, qkv)
-    # forward, dq, and dk/dv: three kernels
-    assert text.count("tpu_custom_call") >= 3
+    # the forward kernel and the ONE backward kernel (dq, dk, dv)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 @pytest.mark.parametrize("half, heads, kv_heads, d", [
@@ -127,8 +128,9 @@ def test_flash_forward_and_backward_compile(v5e, seq, dtype, d, causal):
     (512, 4, 2, 128),      # one q tile and one k tile a half
 ])
 def test_block_diffusion_kernels_compile(v5e, half, heads, kv_heads, d):
-    """The three masked kernels with grouped heads at the cell's widths,
-    each under its own name."""
+    """The masked forward and backward kernels with grouped heads (dk and
+    dv of a key-value head resident across its eight query heads' sweeps)
+    at the cell's widths, each under its own name."""
     def loss(q, k, v):
         out = attention_pallas.flash_attention(
             q, k, v, mask="block_diffusion", block=4, half=half)
@@ -138,8 +140,9 @@ def test_block_diffusion_kernels_compile(v5e, half, heads, kv_heads, d):
                        ((1, 2 * half, heads, d), jnp.bfloat16),
                        ((1, 2 * half, kv_heads, d), jnp.bfloat16),
                        ((1, 2 * half, kv_heads, d), jnp.bfloat16))
-    for name in ("flash_bd_fwd", "flash_bd_dq", "flash_bd_dkv"):
+    for name in ("flash_bd_fwd", "flash_bd_dqkv"):
         assert name in text, name
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 @pytest.mark.parametrize("mask, names", [
@@ -159,7 +162,7 @@ def test_differential_attention_kernels_compile(v5e, mask, names):
                        ((1, 8192, 40, 64), jnp.bfloat16),
                        ((1, 8192, 20, 64), jnp.bfloat16),
                        ((1, 8192, 20, 128), jnp.bfloat16))
-    for which in ("fwd", "dq", "dkv"):
+    for which in ("fwd", "dqkv"):
         assert f"{names}_{which}" in text, which
 
 
@@ -180,15 +183,19 @@ def test_latent_attention_kernels_compile(v5e, pad):
                        ((1, 4096, 32, 192), jnp.bfloat16),
                        ((1, 4096, 32, 192), jnp.bfloat16),
                        ((1, 4096, 32, 128), jnp.bfloat16))
-    for which in ("fwd", "dq", "dkv"):
+    for which in ("fwd", "dqkv"):
         assert f"flash_wide_{which}" in text, which
 
 
 def test_latent_attention_kernels_compile_at_8k(v5e):
     """joyai-llm-flash.lm8k: the causal ``flash_wide_*`` kernels at 32 heads
     of 8,192 positions (twice ``lm4k``'s rows), q and k 192 wide over a
-    128-wide value, bf16: forward and both backward kernels, and the
-    gradients come out at the inputs' shapes."""
+    128-wide value, bf16: the forward and the one backward kernel, and the
+    gradients come out at the inputs' shapes. The largest cell: 10.5 MB of
+    float32 dk and dv resident a head, over Mosaic's default of 16 MiB
+    with their output blocks and the tile's, so the call states its own
+    ``vmem_limit_bytes`` (and the forward, which needs none, states
+    none)."""
     def loss(q, k, v):
         out = attention_pallas.flash_attention(q, k, v, causal=True,
                                                scale=192 ** -0.5)
@@ -200,17 +207,28 @@ def test_latent_attention_kernels_compile_at_8k(v5e):
             for s in shapes]
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile()
     text = compiled.as_text()
-    for which in ("fwd", "dq", "dkv"):
+    for which in ("fwd", "dqkv"):
         assert f"flash_wide_{which}" in text, which
     assert [a.shape for a in compiled.out_info] == shapes
+    # (the scoped memory a call may use, what Mosaic used of it)
+    vmem = {name: (int(limit), int(used)) for name, limit, used in re.findall(
+        r'%\S*flash_wide_(fwd|dqkv)\S* = .*?"scoped_memory_configs":\[\{[^}]*'
+        r'"size":"(\d+)".*?"used_scoped_memory_configs":\[\{[^}]*'
+        r'"size":"(\d+)"', text)}
+    assert vmem["fwd"][0] == 16 << 20 and vmem["fwd"][1] < 16 << 20
+    assert vmem["dqkv"][0] == attention_pallas._VMEM_BYTES
+    assert 16 << 20 < vmem["dqkv"][1] < attention_pallas._VMEM_BYTES
+    # the shape rule counts more than Mosaic uses: it errs towards the pair
+    assert vmem["dqkv"][1] < 8192 * (256 + 128) * 8 + (12 << 20)
 
 
 def test_causal_grouped_kernels_compile_at_the_cells_shape(v5e):
     """lfm2-24b-a2b.lm8kx2: 2 rows of 8,192 positions, 32 query over 8
     key-value heads of 64, causal. Every part was there (grouped heads
     under the block-diffusion mask at 128 wide, the causal sweep ungrouped
-    or with unequal widths); the combination is compiled here first:
-    three kernels, and dk/dv come out over the 8 key-value heads."""
+    or with unequal widths); the combination is compiled here first: the
+    forward and the one backward kernel, and dk/dv come out over the 8
+    key-value heads."""
     def loss(q, k, v):
         out = attention_pallas.flash_attention(q, k, v, mask="causal")
         return jnp.sum(out.astype(jnp.float32))
@@ -219,9 +237,106 @@ def test_causal_grouped_kernels_compile_at_the_cells_shape(v5e):
     args = [jax.ShapeDtypeStruct((2, 8192, h, 64), jnp.bfloat16, sharding=dev)
             for h in (32, 8, 8)]
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
     assert [a.shape for a in compiled.out_info] == [
         (2, 8192, 32, 64), (2, 8192, 8, 64), (2, 8192, 8, 64)]
+
+
+def _kernel_events(text):
+    """The Pallas custom calls of an optimized program as a device trace
+    names them: ``chipbench.trace_reduce.short`` of the instruction."""
+    from chipbench.trace_reduce import PALLAS_TAG, short
+
+    names = [short(ln.strip().removeprefix("ROOT ")) for ln in
+             text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert names and all(n.endswith(PALLAS_TAG) for n in names), names
+    return names
+
+
+@pytest.mark.parametrize("kw, widths, reader, names", [
+    (dict(mask="block_diffusion", block=4, half=1024), (128, 128),
+     "scope_time.BD_KERNELS", ["flash_bd_fwd", "flash_bd_dqkv"]),
+    (dict(mask="window", window=512), (64, 128),
+     "sambay_trace.DIFF_KERNELS", ["flash_win_fwd", "flash_win_dqkv"]),
+    (dict(causal=True), (64, 128),
+     "sambay_trace.DIFF_KERNELS", ["flash_wide_fwd", "flash_wide_dqkv"]),
+    (dict(causal=True, scale=192 ** -0.5), (192, 128),
+     "xing_trace.MLA_KERNELS", ["flash_wide_fwd", "flash_wide_dqkv"]),
+], ids=["bd", "win", "wide", "mla"])
+def test_the_benchmarks_readers_find_the_one_backward_kernel(v5e, kw, widths,
+                                                             reader, names):
+    """``attn.bd_kernel_ms``, ``attn.diff_kernel_ms`` and
+    ``attn.mla_kernel_ms`` sum the events their regular expressions match:
+    ``flash_<kind>_dqkv`` matches as ``dq`` + ``kv``. Under a layer's
+    checkpoint, as the four ``remat`` cells run the kernels. A kernel the
+    readers missed would make a roofline read over 100 %."""
+    import importlib
+
+    from pytorch_ps_mpi_tpu.ops._common import checkpoint_layer
+
+    module, _, pattern = reader.partition(".")
+    rx = re.compile(getattr(importlib.import_module(f"chipbench.{module}"),
+                            pattern))
+
+    @checkpoint_layer
+    def layer(q, k, v):
+        return attention_pallas.flash_attention(q * 2, k, v, **kw)
+
+    def loss(q, k, v):
+        return jnp.sum(layer(q, k, v).astype(jnp.float32))
+
+    d, dv = widths
+    text = compile_for(v5e, jax.grad(loss, (0, 1, 2)),
+                       ((1, 2048, 4, d), jnp.bfloat16),
+                       ((1, 2048, 2, d), jnp.bfloat16),
+                       ((1, 2048, 2, dv), jnp.bfloat16))
+    events = _kernel_events(text)
+    assert len(events) == 2 and all(rx.match(e) for e in events), events
+    assert [n in e.split(" = ")[0] for n, e in zip(names, events)] == [
+        True, True], events
+
+
+def test_the_unnamed_kernels_keep_the_names_their_readers_match(v5e):
+    """The causal and unmasked kernels of one width carry no name of their
+    own: under ``models/bert.py``'s attention module XLA calls both
+    ``%SelfAttention_0.<n>`` (``trace_reduce.ATTENTION_KERNEL``:
+    ``attn.kernel_ms`` and ``attn.roofline_pct`` in ``lm1024`` and
+    ``mlm512``), and under the scope ``attn.gqa`` their ``op_name`` carries
+    it (``lfm2_trace.kernel_seconds`` goes by the scope table)."""
+    from chipbench.trace_reduce import ATTENTION_KERNEL
+    from pytorch_ps_mpi_tpu.models.bert import BertConfig, EncoderLayer
+
+    cfg = BertConfig.tiny(attention="flash", hidden_size=256, num_heads=4,
+                          dtype=jnp.bfloat16)
+    layer = EncoderLayer(cfg)
+    x = jax.ShapeDtypeStruct((2, 512, 256), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=SingleDeviceSharding(v5e)),
+        jax.eval_shape(layer.init, jax.random.key(0), x))
+    text = jax.jit(jax.grad(lambda p, x: jnp.sum(layer.apply(p, x).astype(
+        jnp.float32)))).lower(params, x).compile().as_text()
+    events = _kernel_events(text)
+    assert len(events) == 2, events
+    assert all(re.match(ATTENTION_KERNEL, e) for e in events), events
+
+    def gqa(q, k, v):
+        with jax.named_scope("attn.gqa"):
+            out = attention_pallas.flash_attention(q, k, v, mask="causal")
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = compile_for(v5e, jax.grad(gqa, (0, 1, 2)),
+                       ((1, 2048, 8, 64), jnp.bfloat16),
+                       ((1, 2048, 2, 64), jnp.bfloat16),
+                       ((1, 2048, 2, 64), jnp.bfloat16))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 2
+    for ln in calls:
+        (op_name,) = re.findall(r'op_name="([^"]*)"', ln)
+        assert re.search(r"(^|[/(])attn\.gqa([/)]|$)", op_name), op_name
 
 
 def test_selective_scan_compiles_at_the_cells_size(v5e):
@@ -342,8 +457,6 @@ def test_every_hyper_connection_kernel_sits_under_its_scope(v5e):
     two backward pairs), and the ``op_name`` of each carries ``hc.mix``
     — what ``hc.mix_ms`` and ``hc.mix_roofline_pct`` find their time
     by."""
-    import re
-
     hc = hyper_connection
     p, _, _ = hc_avals(jnp.bfloat16)
     n, positions, d = HC_CELL

@@ -101,10 +101,21 @@ def is_flash_forward(eqn) -> bool:
             and lse.dtype == jnp.float32)
 
 
+def is_flash_backward(eqn) -> bool:
+    """A flash kernel that is not the forward one. The ONE backward kernel
+    returns (dq, dk, dv): two outputs of q's width and one of v's, none of
+    them a float32 ride (the pair it replaces was a call with one output
+    and a call with two). These presets run no other Pallas kernel."""
+    return eqn.primitive.name == "pallas_call" and not is_flash_forward(eqn)
+
+
 def census(loss, params):
     eqns = primitives(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
     count = collections.Counter(e.primitive.name for e in eqns)
+    backward = [e for e in eqns if is_flash_backward(e)]
+    assert all(len(e.outvars) == 3 for e in backward), backward
     return dict(flash_forward=sum(map(is_flash_forward, eqns)),
+                flash_backward=len(backward),
                 sort=count["sort"], top_k=count["top_k"])
 
 
@@ -125,11 +136,15 @@ def test_the_backward_pass_runs_the_kept_work_once(family, monkeypatch):
 
     # one forward kernel an attention layer, one pair of sorts an expert
     # layer; under the sigmoid router the second top_k goes with the plan,
-    # under softmax its values are the gate weights and it stays
-    assert off == dict(flash_forward=attn_layers, sort=2 * expert_layers,
-                       top_k=expert_layers)
+    # under softmax its values are the gate weights and it stays. ONE
+    # backward kernel an attention layer (dq, dk and dv from one
+    # recomputation of the scores; two before PR 40), whatever the
+    # checkpoint
+    assert off == dict(flash_forward=attn_layers, flash_backward=attn_layers,
+                       sort=2 * expert_layers, top_k=expert_layers)
     assert kept == dict(off, top_k=expert_layers * (1 if sigmoid else 2))
     assert plain == dict(flash_forward=2 * attn_layers,
+                         flash_backward=attn_layers,
                          sort=4 * expert_layers, top_k=2 * expert_layers)
 
     # the saved values are the first forward's own arrays: the two
