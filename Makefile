@@ -23,10 +23,10 @@ test:
 	$(MAKE) fresh-smoke
 	$(MAKE) hop-smoke
 
-# Flat-bucket aggregation gate: bit-exact parity of bucketed vs per-leaf
-# steps (identity/cast codecs, both topologies) plus the CPU-backend
-# launch-count assertion (bucketed step lowers to >=5x fewer collective
-# ops than per-leaf), and the serialization wire-format tests.
+# Flat-bucket aggregation gate: psum codecs (identity/cast) lower to the
+# per-leaf step whatever bucket_mb says, a payload codec's bucketed step
+# lowers to >=5x fewer collective ops than per-leaf (CPU backend), and the
+# serialization wire-format tests.
 bucket-smoke:
 	python -m pytest tests/test_bucketing.py tests/test_utils.py -q
 

@@ -137,8 +137,10 @@ def main(argv=None):
     ap.add_argument("--codec-arg", action="append", default=[],
                     help="k=v passed to the codec (repeatable)")
     ap.add_argument("--bucket-mb", type=float, default=0.0,
-                    help="fuse per-leaf collectives into ~N MB "
-                         "dtype-grouped flat buckets (0 = per-leaf; see "
+                    help="a payload codec (int8, sign, qsgd, terngrad, "
+                         "f16, randomk fraction) exchanges ~N MB "
+                         "dtype-grouped flat buckets (0 = per-leaf; "
+                         "identity and bf16 ignore it; see "
                          "docs/OPERATIONS.md 'Gradient bucketing')")
     ap.add_argument("--bf16-comm", action="store_true",
                     help="bfloat16 gradient collectives")

@@ -21,16 +21,22 @@ jit — ``pack`` is one concatenate per bucket, ``unpack`` one slice per leaf
 
 Consumers:
 
-- ``ps.MPI_PS(bucket_mb=...)`` — psums / psum_scatters buckets instead of
-  leaves in both topologies (``mode='allgather'`` and the ZeRO-1
-  ``mode='leader'``, where each worker owns a contiguous bucket shard);
-- ``parallel.dp.make_sync_train_step(bucket_mb=...)`` — the functional API;
+- ``ps.MPI_PS(bucket_mb=...)`` and
+  ``parallel.dp.make_sync_train_step(bucket_mb=...)`` — the device
+  exchange of a PAYLOAD codec (sign, int8, qsgd, terngrad, f16, randomk's
+  fraction form) encodes, all-gathers and decodes one bucket at a time, in
+  ``MPI_PS`` in both topologies (``mode='allgather'`` and the ZeRO-1
+  ``mode='leader'``, where each worker owns a contiguous bucket shard).
+  :func:`exchange_plan` is the one place that decides it;
 - ``parallel.dcn.CodecWire(bucket_mb=...)`` — the host wire ships one
-  contiguous per-bucket payload per push instead of per-leaf fragments.
+  contiguous per-bucket payload per push instead of per-leaf fragments,
+  for every ``Codec.bucketable`` codec, identity and cast included.
 
-Shape-agnostic stateless codecs (``Codec.bucketable``) encode per bucket;
-per-tensor codecs (PowerSGD, top-k) keep the per-leaf path. ``bucket_mb=0``
-everywhere preserves today's per-leaf behavior exactly.
+A psum codec (identity, the bf16 cast) gets no plan on the device: its
+exchange is one ``psum`` a leaf, and the compiler groups those itself
+(``comms.ALONE_BYTES``; flat buckets were ~25 % slower on four v5e chips,
+PERF.md section 6). Per-tensor codecs (PowerSGD, top-k) keep the
+per-leaf path. ``bucket_mb=0`` everywhere is the per-leaf path.
 """
 
 from __future__ import annotations
@@ -203,6 +209,28 @@ def plan_buckets(tree: PyTree, bucket_mb: float) -> Optional[BucketPlan]:
             cur_size += size
             buckets[cur_bucket] = BucketSpec(dt, cur_size)
     return BucketPlan(treedef, [s for s in slots], buckets, bucket_mb)
+
+
+def exchange_plan(code, tree: PyTree, bucket_mb: float) -> Optional[BucketPlan]:
+    """The bucket plan the device exchange of ``code`` uses for ``tree``,
+    or None for the per-leaf exchange.
+
+    Only a payload codec is bucketed: ``code.bucketable``, neither
+    ``supports_psum`` (its per-leaf psums are grouped by the compiler)
+    nor ``supports_fused_allreduce`` (its protocol is per tensor), and
+    ``bucket_mb > 0``. A bucketable codec must be stateless (per-bucket
+    state has no home): one that is not raises TypeError."""
+    if bucket_mb <= 0 or not code.bucketable:
+        return None
+    if jax.tree.leaves(code.init_state((1,), jnp.float32)):
+        raise TypeError(
+            f"{type(code).__name__}.bucketable=True but init_state is "
+            "non-empty — bucketable codecs must be stateless (see "
+            "codecs.base.Codec.bucketable)"
+        )
+    if code.supports_psum or code.supports_fused_allreduce:
+        return None
+    return plan_buckets(tree, bucket_mb)
 
 
 def flatten_into_buckets(plan: BucketPlan, tree: PyTree) -> List[jax.Array]:

@@ -122,7 +122,10 @@ class Codec:
     needs_rng: bool = False
     #: shape-agnostic AND stateless codecs set this so flat-bucket
     #: aggregation (``bucketing.BucketPlan``) may encode one dtype-uniform
-    #: ~MB-scale bucket instead of hundreds of per-leaf fragments.
+    #: ~MB-scale bucket instead of hundreds of per-leaf fragments. The
+    #: host wire (``parallel.dcn.CodecWire``) buckets every such codec;
+    #: the device exchange (``bucketing.exchange_plan``) only those that
+    #: are not ``supports_psum``, whose per-leaf psums the compiler groups.
     #: Contract: ``init_state`` returns ``()`` (per-bucket state has no
     #: home — bucket boundaries are a transport detail, not a training
     #: one) and ``encode``/``decode``/``decode_sum`` treat the input as an

@@ -61,25 +61,6 @@ def allreduce_sum_tree(tree: PyTree, axis_name: str) -> PyTree:
     return jax.tree.map(lambda x: lax.psum(x, axis_name), tree)
 
 
-def allreduce_sum_buckets(
-    buckets, axis_name, wire_dtype=None
-) -> list:
-    """One ``psum`` per flat dtype-grouped bucket (``bucketing.BucketPlan``
-    output) — the launch-fused form of :func:`allreduce_sum_tree`: a
-    BERT-size tree goes from hundreds of per-leaf collectives to a handful
-    of ~MB-scale ones. ``wire_dtype`` narrows each bucket on the wire and
-    casts back (same contract as ``MPI_PS(comm_dtype=...)``; applied
-    unconditionally so numerics match the per-leaf psum path bit for
-    bit)."""
-    out = []
-    for b in buckets:
-        if wire_dtype is not None:
-            out.append(lax.psum(b.astype(wire_dtype), axis_name).astype(b.dtype))
-        else:
-            out.append(lax.psum(b, axis_name))
-    return out
-
-
 # -- the exchange's schedule on more than one TPU ----------------------------
 #
 # On this runtime (libtpu 0.0.34) an all-reduce is an operation of the core's

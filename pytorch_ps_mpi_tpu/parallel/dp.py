@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pytorch_ps_mpi_tpu.bucketing import plan_buckets
+from pytorch_ps_mpi_tpu.bucketing import exchange_plan
 from pytorch_ps_mpi_tpu.codecs import Codec, IdentityCodec
 from pytorch_ps_mpi_tpu.mesh import DATA_AXIS
 from pytorch_ps_mpi_tpu.optim import OPTIMIZERS
@@ -54,9 +54,11 @@ def make_sync_train_step(
     (params, opt_state, codec_state, loss)`` — one fused XLA program,
     batch sharded over ``axis_name``, params replicated.
 
-    ``bucket_mb > 0`` fuses the aggregation collectives into dtype-grouped
-    flat buckets (``bucketing.BucketPlan``) for ``mode='allgather'`` with a
-    bucketable codec — bit-exact for identity/cast, one launch per bucket.
+    ``bucket_mb > 0`` runs a payload codec's exchange (sign, int8, qsgd,
+    terngrad, f16, randomk's fraction form) on dtype-grouped flat buckets
+    for ``mode='allgather'``: one encode, all-gather and decode a bucket,
+    as ``bucketing.exchange_plan`` decides for ``MPI_PS``. Psum codecs
+    (identity, bf16) keep one psum a leaf, which the compiler groups.
     The functional leader mode keeps the per-leaf path (its ZeRO-1 state
     layout is built by ``init_fn`` per leaf); use ``MPI_PS(mode='leader',
     bucket_mb=...)`` for bucket-sharded ZeRO-1.
@@ -79,26 +81,14 @@ def make_sync_train_step(
             return leader_init_state(params, init_state, size), codec_state
         return init_state(params), codec_state
 
-    bucketed = (
-        bucket_mb > 0 and mode == "allgather"
-        and code.bucketable and not code.supports_fused_allreduce
-    )
-    if bucketed and jax.tree.leaves(code.init_state((1,), jnp.float32)):
-        # same contract MPI_PS enforces: a bucketable codec must be
-        # stateless, or the bucketed branch would silently freeze its
-        # state (see codecs.base.Codec.bucketable)
-        raise TypeError(
-            f"{type(code).__name__}.bucketable=True but init_state is "
-            "non-empty — bucketable codecs must be stateless"
-        )
-
     def spmd(params, opt_state, codec_state, batch, rng):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         loss = lax.pmean(loss, axis_name)
-        if bucketed:
+        plan = (exchange_plan(code, grads, bucket_mb)
+                if mode == "allgather" else None)
+        if plan is not None:
             # one collective per dtype-grouped flat bucket; the codec is
             # stateless by the bucketable contract, state passes through
-            plan = plan_buckets(grads, bucket_mb)
             summed = bucketed_aggregate(
                 code, grads, plan, axis_name, average, size, rng=rng
             )

@@ -56,8 +56,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from pytorch_ps_mpi_tpu import comms
 from pytorch_ps_mpi_tpu.bucketing import (
     BucketPlan,
+    exchange_plan,
     flatten_into_buckets,
-    plan_buckets,
     unflatten_from_buckets,
 )
 from pytorch_ps_mpi_tpu.codecs import Codec, ErrorFeedback, IdentityCodec
@@ -534,38 +534,26 @@ def bucketed_aggregate(
     axis_name,
     average: bool,
     size: int,
-    comm_dtype=None,
     rng=None,
 ) -> PyTree:
-    """Flat-bucket form of :func:`aggregate` (mode='allgather' and the
-    leader payload-gather lowering): flatten the gradient tree into
-    dtype-grouped buckets, run ONE collective per bucket instead of one
-    per leaf, and unflatten the summed buckets back to the tree. Runs
-    inside shard_map.
-
-    psum-capable codecs psum each bucket (wire-narrowed exactly as the
-    per-leaf path would be, so numerics are bit-identical — a bucket is a
-    permutation-into-concatenation of the leaves and psum is elementwise).
-    Non-psum ``bucketable`` codecs encode each bucket as if it were one
-    large leaf (stateless by the ``bucketable`` contract), all-gather the
-    per-bucket payloads, and decode_sum per bucket — per-input statistics
-    (sign's mean|g|, int8's absmax) then apply per bucket, the documented
-    semantics shift for those lossy codecs."""
+    """Flat-bucket form of :func:`aggregate` for a payload codec
+    (``bucketing.exchange_plan``; mode='allgather' and the leader
+    payload-gather lowering): flatten the gradient tree into
+    dtype-grouped buckets, encode each bucket as if it were one large
+    leaf (stateless by the ``bucketable`` contract), all-gather the
+    per-bucket payloads, decode_sum per bucket, and unflatten the summed
+    buckets back to the tree. Runs inside shard_map. Per-input
+    statistics (sign's mean|g|, int8's absmax) then apply per bucket,
+    the documented semantics shift for those lossy codecs."""
     buckets = flatten_into_buckets(plan, grads)
-    if code.supports_psum:
-        wire = comm_dtype if comm_dtype is not None else getattr(
-            code, "wire_dtype", None
+    payloads = _encode_buckets(code, buckets, rng, axis_name)
+    summed_b = []
+    for b, payload in zip(buckets, payloads):
+        gathered = jax.tree.map(
+            lambda x: lax.all_gather(x, axis_name), payload
         )
-        summed_b = comms.allreduce_sum_buckets(buckets, axis_name, wire)
-    else:
-        payloads = _encode_buckets(code, buckets, rng, axis_name)
-        summed_b = []
-        for b, payload in zip(buckets, payloads):
-            gathered = jax.tree.map(
-                lambda x: lax.all_gather(x, axis_name), payload
-            )
-            summed_b.append(
-                decode_sum_payloads(code, gathered, b.shape, b.dtype))
+        summed_b.append(
+            decode_sum_payloads(code, gathered, b.shape, b.dtype))
     if average:
         summed_b = [x / size for x in summed_b]
     return unflatten_from_buckets(plan, summed_b)
@@ -653,22 +641,24 @@ class MPI_PS:
         gradient-sum semantics — a pmean would deflate it by the world
         size). Default None: fully-replicated params (pure DP, the
         reference's regime, ``ps.py:54-59``).
-      bucket_mb: if > 0, fuse per-leaf collectives into dtype-grouped
-        flat buckets of about this many megabytes (``bucketing.BucketPlan``)
-        — one psum (allgather mode) / psum_scatter (leader mode, each
-        worker owning a contiguous bucket shard) per BUCKET instead of
-        per leaf, cutting a BERT-size tree's collective launch count by
-        an order of magnitude. Bit-exact vs. the per-leaf path for
-        identity/cast codecs; shape-agnostic stateless codecs
-        (``Codec.bucketable``: sign, int8, qsgd, terngrad, and randomk's
-        fraction form) encode per bucket (their per-input statistics
-        then apply per bucket); per-tensor codecs (PowerSGD, top-k,
-        absolute-k randomk) keep the per-leaf path automatically. ``0`` (default) preserves per-leaf behavior
-        exactly. Requires pure-DP layouts (no ``param_specs``).
+      bucket_mb: if > 0, a PAYLOAD codec's exchange runs on dtype-grouped
+        flat buckets of about this many megabytes (``bucketing.BucketPlan``,
+        decided by ``bucketing.exchange_plan``): shape-agnostic stateless
+        codecs (``Codec.bucketable``: sign, int8, qsgd, terngrad, f16 and
+        randomk's fraction form) encode, all-gather and decode one bucket
+        at a time, so the all-gathers and per-leaf scale sidecars drop to
+        one a bucket, and their per-input statistics apply per bucket; in
+        leader mode each worker owns a contiguous bucket shard. It does
+        not apply to the psum codecs (identity, bf16) or ``comm_dtype``:
+        their exchange is one psum a leaf, which the compiler groups
+        (each leaf of ``comms.ALONE_BYTES`` or more alone, the rest
+        merged). Per-tensor codecs (PowerSGD, top-k, absolute-k randomk)
+        keep the per-leaf path too. ``0`` (default) is the per-leaf
+        path. Requires pure-DP layouts (no ``param_specs``).
       numerics: if True, fuse on-device gradient statistics into the
         lowered step programs (``telemetry.numerics``): global finite
         grad norm, NaN/Inf element count, update-to-weight ratio
-        ``||dp||/||p||``, per-BUCKET grad norms when ``bucket_mb`` is
+        ``||dp||/||p||``, per-BUCKET grad norms when a bucket plan is
         active, and the error-feedback residual norm when ``code`` is an
         :class:`~pytorch_ps_mpi_tpu.codecs.ErrorFeedback`. All
         reductions run inside the jit (XLA fuses them into the step for
@@ -843,26 +833,16 @@ class MPI_PS:
         if bucket_mb < 0:
             raise ValueError(f"bucket_mb must be >= 0, got {bucket_mb}")
         self.bucket_mb = float(bucket_mb)
-        self._bucket_plan: Optional[BucketPlan] = None
-        if self.bucket_mb > 0:
-            if self._model_parallel or not self._uniform_agg:
-                raise NotImplementedError(
-                    "bucket_mb > 0 requires pure-DP layouts: model-sharded "
-                    "or expert-parallel leaves aggregate over per-leaf axis "
-                    "sets that one flat bucket cannot represent. Drop "
-                    "param_specs or set bucket_mb=0"
-                )
-            if (self.code.bucketable
-                    and not self.code.supports_fused_allreduce):
-                if jax.tree.leaves(self.code.init_state((1,), jnp.float32)):
-                    raise TypeError(
-                        f"{type(self.code).__name__}.bucketable=True but "
-                        "init_state is non-empty — bucketable codecs must "
-                        "be stateless (see codecs.base.Codec.bucketable)"
-                    )
-                self._bucket_plan = plan_buckets(params, self.bucket_mb)
-            # else: per-tensor codec — keep the per-leaf path (the
-            # documented Codec.bucketable opt-out), no error
+        if self.bucket_mb > 0 and (self._model_parallel
+                                   or not self._uniform_agg):
+            raise NotImplementedError(
+                "bucket_mb > 0 requires pure-DP layouts: model-sharded "
+                "or expert-parallel leaves aggregate over per-leaf axis "
+                "sets that one flat bucket cannot represent. Drop "
+                "param_specs or set bucket_mb=0"
+            )
+        self._bucket_plan: Optional[BucketPlan] = exchange_plan(
+            self.code, params, self.bucket_mb)
         self._bucket_templates = (
             self._bucket_plan.bucket_templates()
             if self._bucket_plan is not None else None
@@ -905,9 +885,9 @@ class MPI_PS:
             # world× the sharded memory, defeating ZeRO-1's point at the
             # model scales it targets.
             #
-            # With a bucket plan the master copy is kept in BUCKET form:
-            # LeaderState.param_shards leaves are per-bucket [world, ss]
-            # stacks, so the step's psum_scatter of a flat bucket lands
+            # With a bucket plan (a payload codec) the master copy is kept
+            # in BUCKET form: LeaderState.param_shards leaves are per-bucket
+            # [world, ss] stacks, so the step's scatter of a flat bucket lands
             # directly on the shard the optimizer owns — no re-slicing
             # between the wire layout and the state layout. The update is
             # elementwise (SGD/Adam; adafactor is rejected in leader mode
@@ -1093,32 +1073,23 @@ class MPI_PS:
     def _bucketed_encode_aggregate_update(self, params, opt_state,
                                           codec_state, grads, rng):
         """Flat-bucket lowering of the encode → aggregate → update seam
-        (``_bucket_plan`` is set: bucketable codec, pure-DP layout). The
+        (``_bucket_plan`` is set: a payload codec, pure-DP layout). The
         codec is stateless by the ``bucketable`` contract, so
         ``codec_state`` passes through untouched."""
         plan = self._bucket_plan
-        lowering = self._leader_lowering()
-        if lowering in ("psum_scatter", "dense_scatter"):
-            if lowering == "psum_scatter":
-                to_scatter = flatten_into_buckets(plan, grads)
-                wire = self.comm_dtype if self.comm_dtype is not None else (
-                    getattr(self.code, "wire_dtype", None)
-                )
-            else:
-                # decode the own-bucket payload to the codec-filtered
-                # dense bucket, then reduce_scatter that (numerics match
-                # the gather form exactly as in the per-leaf path)
-                buckets = flatten_into_buckets(plan, grads)
-                payloads = _encode_buckets(
-                    self.code, buckets, rng, self.axis_name
-                )
-                to_scatter = [
-                    self.code.decode(p, b.shape, b.dtype)
-                    for b, p in zip(buckets, payloads)
-                ]
-                wire = self.comm_dtype
+        if self._leader_lowering() == "dense_scatter":
+            # decode the own-bucket payload to the codec-filtered dense
+            # bucket, then reduce_scatter that (numerics match the gather
+            # form exactly as in the per-leaf path)
+            buckets = flatten_into_buckets(plan, grads)
+            payloads = _encode_buckets(self.code, buckets, rng, self.axis_name)
+            to_scatter = [
+                self.code.decode(p, b.shape, b.dtype)
+                for b, p in zip(buckets, payloads)
+            ]
             grad_shards = leader_scatter_shards(
-                to_scatter, self.axis_name, self.size, wire, self.average
+                to_scatter, self.axis_name, self.size, self.comm_dtype,
+                self.average,
             )
             if self.clip_norm:
                 # bucket shards partition the aggregated gradient exactly
@@ -1135,7 +1106,7 @@ class MPI_PS:
         # shared update path (which re-buckets for the leader slice)
         summed = bucketed_aggregate(
             self.code, grads, plan, self.axis_name, self.average, self.size,
-            self.comm_dtype, rng,
+            rng,
         )
         new_params, new_opt_state = self._update(params, opt_state, summed)
         return new_params, new_opt_state, codec_state
@@ -1445,15 +1416,12 @@ class MPI_PS:
         that every path gets the same ``compiler_options`` — those of
         ``comms.async_allreduce_options`` on more than one TPU, none
         anywhere else (the program and its compile-cache entry are then
-        what they were without this helper), and none where the exchange
-        is already in flat buckets of this optimizer's own making
-        (``bucket_mb`` > 0): there the options were a loss, the
-        ``bucket_mb`` 16 step of BERT-base on four v5e chips taking
-        62.18 ms with them and 60.06 without (by the wall, my chip runs
-        X3 and R1, PR 28; 48.0 per leaf), so a bucketed program is
-        compiled as it always was. ``.lower(...).compile()``
-        of the result carries the options too, so
-        :meth:`step_memory_analysis` describes the program that runs.
+        what they were without this helper), and none where a payload
+        codec's exchange runs on a bucket plan (``_bucket_plan``): such a
+        program is compiled as it always was, since no chip run has timed
+        it with them.
+        ``.lower(...).compile()`` of the result carries the options too,
+        so :meth:`step_memory_analysis` describes the program that runs.
         ``donate`` donates params / optimizer state / codec state
         (arguments 0-2) where ``donate_buffers`` is set: the outputs
         re-use their buffers, cutting peak HBM by one params+opt-state
@@ -1462,7 +1430,7 @@ class MPI_PS:
             jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False),
             donate_argnums=(0, 1, 2) if donate and self.donate_buffers else (),
-            compiler_options=None if self.bucket_mb > 0 else
+            compiler_options=None if self._bucket_plan is not None else
             comms.async_allreduce_options(self.mesh, self._agg_axes),
         )
 
@@ -1556,13 +1524,6 @@ class MPI_PS:
 
         def sum_spmd(grads_stacked):
             grads = jax.tree.map(lambda x: x[0], grads_stacked)
-            if self._bucket_plan is not None:
-                # measure the same launch-fused collective topology the
-                # fused step runs (one psum per bucket, not per leaf)
-                return bucketed_aggregate(
-                    self.code, grads, self._bucket_plan, axis, False,
-                    self.size, self.comm_dtype,
-                )
             return aggregate(
                 self.code, grads, None, axis, False, self.size, self.comm_dtype
             )
@@ -1649,9 +1610,9 @@ class MPI_PS:
             )
         else:
             # the staged encode/gather stages run PER LEAF even when a
-            # bucket plan is active (only the psum stage is bucketed), so
-            # the reported bytes/launches must describe the per-leaf
-            # topology actually measured — not the fused step's buckets
+            # bucket plan is active, so the reported bytes/launches must
+            # describe the per-leaf topology actually measured — not the
+            # fused step's buckets
             data["wire_lowering"] = "payload_gather_staged"
             data["wire_bytes_per_worker"] = (
                 (w - 1) * self._payload_bytes_per_leaf
@@ -2212,12 +2173,21 @@ class MPI_PS:
         return jax.tree.unflatten(treedef, out)
 
     def load_state_dict(self, sd: Dict[str, Any]) -> None:
-        self.params = self._decommit_restored(sd["params"])
-        self.opt_state = self._map_adam(
+        """Restore a :meth:`state_dict`. An optimizer state laid out for
+        another optimizer (a bucket-form leader state where this one is
+        per leaf, another mode or optimizer) raises ValueError."""
+        opt_state = self._map_adam(
             lambda s: s if self.hyper.amsgrad else s._replace(
                 max_exp_avg_sq=()),
-            type(self.opt_state)(
-                *self._decommit_restored(tuple(sd["opt_state"]))))
+            type(self.opt_state)(*tuple(sd["opt_state"])))
+        layout = lambda t: (jax.tree.structure(t),
+                            [np.shape(x) for x in jax.tree.leaves(t)])
+        if layout(opt_state) != layout(self.opt_state):
+            raise ValueError(
+                "load_state_dict: opt_state does not fit this optimizer "
+                "(saved with another mode, optimizer or bucket layout?)")
+        self.params = self._decommit_restored(sd["params"])
+        self.opt_state = self._decommit_restored(opt_state)
         self.codec_state = self._decommit_restored(sd["codec_state"])
         self.aux_state = self._decommit_restored(sd.get("aux_state"))
         self._place_state()  # or the next step is compiled again

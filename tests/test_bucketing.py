@@ -1,14 +1,14 @@
 """Flat-bucket gradient aggregation (pytorch_ps_mpi_tpu/bucketing.py).
 
-Parity discipline: bucketing is a wire-layout change, not a numerics
-change — for identity/cast codecs the bucketed step must be BIT-EXACT
-against the per-leaf step in both topologies (buckets are a
-permutation-into-concatenation and every collective/update is
-elementwise). Global-norm clipping is compared to a tight tolerance
-(the sum-of-squares accumulates in a different grouping order). The
-launch-count tests assert the actual point of the feature: the lowered
-program's collective op count drops from one-per-leaf to
-one-per-bucket.
+Only a payload codec's device exchange is bucketed
+(``bucketing.exchange_plan``): for the psum codecs (identity, cast,
+``comm_dtype``) ``bucket_mb`` changes nothing — no plan, the per-leaf
+step's lowered text, bit-equal parameters. For int8 the bucketed step's
+global-norm clip on bucket shards (leader) is compared with the
+allgather form to a tight tolerance (the sum-of-squares accumulates in
+a different grouping order). The launch-count tests assert the point of
+the feature: the lowered program's collective op count drops from
+one-per-leaf to one-per-bucket.
 """
 
 import jax
@@ -138,99 +138,116 @@ def test_plan_rejects_dtype_drift():
 
 
 # ---------------------------------------------------------------------------
-# Step parity: bucketed vs per-leaf
+# Psum codecs: bucket_mb does not apply to their device exchange
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("mode", ["allgather", "leader"])
+def _sds(tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.asarray(x).dtype),
+        tree)
+
+
+def _linear_loss(p, b):
+    x, y = b
+    return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
+
+
+_LINEAR_BATCH = (
+    jax.random.normal(jax.random.key(5), (16, 64)),
+    jax.random.normal(jax.random.key(6), (16, 4)),
+)
+
+def _sgd(p, **kw):
+    return SGD(p, lr=0.05, momentum=0.9, **kw)
+
+
+def _adam(p, **kw):
+    return Adam(p, lr=0.01, **kw)
+
+
+def _bf16(p, **kw):
+    return SGD(p, lr=0.05, code=get_codec("bf16"), **kw)
+
+
+def _narrow(p, **kw):
+    return Adam(p, lr=0.01, average=True, comm_dtype=jnp.bfloat16, **kw)
+
+
+# id -> (optimizer factory, its keywords, bucket_mb, steps); every case
+# but "loss-fn" runs the grads-only step on mixed_tree()
+_PSUM_CASES = {
+    "identity-sgd-allgather": (_sgd, dict(mode="allgather"), 0.02, 3),
+    "identity-sgd-leader": (_sgd, dict(mode="leader"), 0.02, 3),
+    "identity-adam-allgather": (_adam, dict(mode="allgather"), 0.02, 3),
+    "identity-adam-leader": (_adam, dict(mode="leader"), 0.02, 3),
+    "adafactor-allgather": (Adafactor, {}, 0.02, 3),
+    "bf16-allgather": (_bf16, dict(mode="allgather"), 0.02, 2),
+    "bf16-leader": (_bf16, dict(mode="leader"), 0.02, 2),
+    "comm-dtype-average-allgather": (_narrow, dict(mode="allgather"), 0.02, 2),
+    "comm-dtype-average-leader": (_narrow, dict(mode="leader"), 0.02, 2),
+    "loss-fn": (lambda p, **kw: SGD(p, lr=0.05, **kw), {}, 0.001, 3),
+}
+
+
+@pytest.mark.parametrize("case", list(_PSUM_CASES))
+def test_psum_codecs_ignore_bucket_mb(mesh8, case):
+    """With a psum codec (identity, the bf16 cast, ``comm_dtype``) the
+    optimizer built with ``bucket_mb`` > 0 has no plan, lowers to the
+    step of ``bucket_mb`` 0 and steps to bit-equal parameters, in both
+    topologies and on the fused ``loss_fn`` path."""
+    make, kw, bucket_mb, steps = _PSUM_CASES[case]
+    loss_path = case == "loss-fn"
+    params = ({"w": jnp.ones((64, 4)), "b": jnp.zeros((4,))} if loss_path
+              else mixed_tree())
+    per_leaf = make(fresh(params), **kw)
+    asked = make(fresh(params), bucket_mb=bucket_mb, **kw)
+    assert asked._bucket_plan is None
+
+    def lowered(opt):
+        if loss_path:
+            fn, data = opt._build_grad_step(_linear_loss), _LINEAR_BATCH
+        else:
+            fn, data = opt._build_grads_only_step(), _sds(grads_for(params))
+        return fn.lower(opt.params, opt.opt_state, opt.codec_state, data,
+                        jax.random.key(0)).as_text()
+
+    assert lowered(asked) == lowered(per_leaf)
+    for _ in range(steps):
+        if loss_path:
+            l1, _ = per_leaf.step(loss_fn=_linear_loss, batch=_LINEAR_BATCH)
+            l2, _ = asked.step(loss_fn=_linear_loss, batch=_LINEAR_BATCH)
+            np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
+        else:
+            per_leaf.step(grads=grads_for(params))
+            asked.step(grads=grads_for(params))
+    assert_trees_equal(per_leaf.params, asked.params)
+
+
+# ---------------------------------------------------------------------------
+# Payload codecs: the bucketed exchange
+# ---------------------------------------------------------------------------
+
 @pytest.mark.parametrize("make", [
     lambda p, **kw: SGD(p, lr=0.05, momentum=0.9, **kw),
     lambda p, **kw: Adam(p, lr=0.01, **kw),
-])
-def test_bucketed_step_bit_exact_identity(mesh8, mode, make):
-    params = mixed_tree()
+], ids=["sgd", "adam"])
+def test_bucketed_global_norm_clip_parity(mesh8, make):
+    # int8 on buckets: leader mode (dense_scatter) clips bucket SHARDS,
+    # allgather mode the summed buckets; a tight clip so the scale
+    # engages, and the sum-of-squares groups differently, hence allclose.
+    # float32 leaves only: dense_scatter sums a bf16 leaf's decoded
+    # contributions in bf16, where decode_sum adds them in float32
+    params = {k: v for k, v in mixed_tree().items() if k != "h"}
     grads = grads_for(params)
-    o1 = make(fresh(params), mode=mode)
-    o2 = make(fresh(params), mode=mode, bucket_mb=0.02)
-    assert o2._bucket_plan is not None
-    assert o2._bucket_plan.num_buckets < o2._bucket_plan.num_leaves
-    for _ in range(3):
-        o1.step(grads=grads)
-        o2.step(grads=grads)
-    assert_trees_equal(o1.params, o2.params)
-
-
-def test_bucketed_adafactor_allgather_bit_exact(mesh8):
-    params = mixed_tree()
-    grads = grads_for(params)
-    o1 = Adafactor(fresh(params))
-    o2 = Adafactor(fresh(params), bucket_mb=0.02)
-    for _ in range(3):
-        o1.step(grads=grads)
-        o2.step(grads=grads)
-    assert_trees_equal(o1.params, o2.params)
-
-
-@pytest.mark.parametrize("mode", ["allgather", "leader"])
-def test_bucketed_cast_codec_bit_exact(mesh8, mode):
-    params = mixed_tree()
-    grads = grads_for(params)
-    o1 = SGD(fresh(params), lr=0.05, mode=mode, code=get_codec("bf16"))
-    o2 = SGD(fresh(params), lr=0.05, mode=mode, code=get_codec("bf16"),
-             bucket_mb=0.02)
-    for _ in range(2):
-        o1.step(grads=grads)
-        o2.step(grads=grads)
-    assert_trees_equal(o1.params, o2.params)
-
-
-@pytest.mark.parametrize("mode", ["allgather", "leader"])
-def test_bucketed_comm_dtype_and_average_bit_exact(mesh8, mode):
-    params = mixed_tree()
-    grads = grads_for(params)
-    kw = dict(lr=0.01, mode=mode, average=True, comm_dtype=jnp.bfloat16)
-    o1 = Adam(fresh(params), **kw)
-    o2 = Adam(fresh(params), bucket_mb=0.02, **kw)
-    for _ in range(2):
-        o1.step(grads=grads)
-        o2.step(grads=grads)
-    assert_trees_equal(o1.params, o2.params)
-
-
-@pytest.mark.parametrize("mode", ["allgather", "leader"])
-def test_bucketed_global_norm_clip_parity(mesh8, mode):
-    # tight clip so the scale actually engages; sum-of-squares grouping
-    # differs between bucket and leaf accumulation, hence allclose
-    params = mixed_tree()
-    grads = grads_for(params)
-    o1 = SGD(fresh(params), lr=0.05, mode=mode, clip_norm=0.5)
-    o2 = SGD(fresh(params), lr=0.05, mode=mode, clip_norm=0.5,
-             bucket_mb=0.02)
+    kw = dict(code=get_codec("int8"), clip_norm=0.5, bucket_mb=0.02)
+    o1 = make(fresh(params), mode="allgather", **kw)
+    o2 = make(fresh(params), mode="leader", **kw)
+    assert o2._leader_lowering() == "dense_scatter"
+    assert o1._bucket_plan is not None and o2._bucket_plan is not None
     for _ in range(3):
         o1.step(grads=grads)
         o2.step(grads=grads)
     assert_trees_close(o1.params, o2.params)
-
-
-def test_bucketed_loss_fn_path_bit_exact(mesh8):
-    # the fused grad+aggregate+update step (not just grads-only)
-    params = {"w": jnp.ones((64, 4)), "b": jnp.zeros((4,))}
-    batch = (
-        jax.random.normal(jax.random.key(5), (16, 64)),
-        jax.random.normal(jax.random.key(6), (16, 4)),
-    )
-
-    def loss_fn(p, b):
-        x, y = b
-        pred = x @ p["w"] + p["b"]
-        return jnp.mean((pred - y) ** 2)
-
-    o1 = SGD(fresh(params), lr=0.05)
-    o2 = SGD(fresh(params), lr=0.05, bucket_mb=0.001)
-    for _ in range(3):
-        l1, _ = o1.step(loss_fn=loss_fn, batch=batch)
-        l2, _ = o2.step(loss_fn=loss_fn, batch=batch)
-    np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
-    assert_trees_equal(o1.params, o2.params)
 
 
 @pytest.mark.parametrize("codec", [
@@ -277,17 +294,32 @@ def test_per_tensor_codec_keeps_per_leaf_path(mesh8):
 def test_bucketed_leader_state_dict_roundtrip(mesh8):
     params = mixed_tree()
     grads = grads_for(params)
-    o1 = Adam(fresh(params), lr=0.01, mode="leader", bucket_mb=0.02)
+    kw = dict(lr=0.01, mode="leader", code=get_codec("int8"), bucket_mb=0.02)
+    o1 = Adam(fresh(params), **kw)
     o1.step(grads=grads)
     sd = o1.state_dict()
-    o2 = Adam(fresh(params), lr=0.01, mode="leader", bucket_mb=0.02)
+    o2 = Adam(fresh(params), **kw)
     o2.load_state_dict(sd)
     o1.step(grads=grads)
     o2.step(grads=grads)
     assert_trees_equal(o1.params, o2.params)
 
 
+def test_bucket_form_leader_state_is_refused_where_state_is_per_leaf(mesh8):
+    # the bucket-form state a leader optimizer with a psum codec and
+    # bucket_mb > 0 once saved: that optimizer now keeps its state per
+    # leaf, and the load says so instead of stepping on a wrong layout
+    params = mixed_tree()
+    kw = dict(lr=0.01, mode="leader", bucket_mb=0.02)
+    bucket_form = Adam(fresh(params), code=get_codec("int8"), **kw)
+    per_leaf = Adam(fresh(params), **kw)
+    with pytest.raises(ValueError, match="does not fit"):
+        per_leaf.load_state_dict(bucket_form.state_dict())
+
+
 def test_functional_dp_bucketed_bit_exact(mesh8):
+    # the functional step and MPI_PS, both on int8 buckets, agree bit
+    # for bit: one plan decision (bucketing.exchange_plan) for both
     from pytorch_ps_mpi_tpu.parallel.dp import make_sync_train_step
 
     params = {"w": jnp.ones((32, 4)), "b": jnp.zeros((4,))}
@@ -300,20 +332,22 @@ def test_functional_dp_bucketed_bit_exact(mesh8):
         x, y = b
         return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
 
-    outs = []
-    for mb in (0.0, 0.0005):
-        init_fn, step_fn = make_sync_train_step(
-            loss_fn, mesh8, lr=0.1, bucket_mb=mb, donate=False
+    init_fn, step_fn = make_sync_train_step(
+        loss_fn, mesh8, lr=0.1, code=get_codec("int8"), bucket_mb=0.0005,
+        donate=False,
+    )
+    p = fresh(params)
+    opt_state, codec_state = init_fn(p)
+    opt = SGD(fresh(params), mesh=mesh8, lr=0.1, code=get_codec("int8"),
+              bucket_mb=0.0005)
+    assert opt._bucket_plan is not None
+    for _ in range(3):
+        p, opt_state, codec_state, loss = step_fn(
+            p, opt_state, codec_state, batch, jax.random.key(0)
         )
-        p = fresh(params)
-        opt_state, codec_state = init_fn(p)
-        for _ in range(3):
-            p, opt_state, codec_state, loss = step_fn(
-                p, opt_state, codec_state, batch, jax.random.key(0)
-            )
-        outs.append((p, loss))
-    assert_trees_equal(outs[0][0], outs[1][0])
-    np.testing.assert_array_equal(np.asarray(outs[0][1]), np.asarray(outs[1][1]))
+        opt_loss, _ = opt.step(loss_fn=loss_fn, batch=batch)
+    assert_trees_equal(p, opt.params)
+    np.testing.assert_array_equal(np.asarray(loss), np.asarray(opt_loss))
 
 
 def test_bucket_mb_rejects_model_parallel():
@@ -331,15 +365,16 @@ def test_bucket_mb_rejects_model_parallel():
 def test_bucket_telemetry_fields(mesh8):
     params = mixed_tree()
     grads = grads_for(params)
-    o = SGD(fresh(params), lr=0.05, bucket_mb=0.02)
+    o = SGD(fresh(params), lr=0.05, code=get_codec("int8"), bucket_mb=0.02)
     _, data = o.step(grads=grads)
     assert data["bucket_count"] == o._bucket_plan.num_buckets
     assert data["agg_launches"] == o._bucket_plan.num_buckets
     assert data["bucket_bytes_total"] == o._bucket_plan.total_bytes
-    o0 = SGD(fresh(params), lr=0.05)
-    _, data0 = o0.step(grads=grads)
-    assert data0["bucket_count"] == 0.0
-    assert data0["agg_launches"] == len(jax.tree.leaves(params))
+    for kw in ({}, dict(bucket_mb=16)):  # identity: per leaf either way
+        o0 = SGD(fresh(params), lr=0.05, **kw)
+        _, data0 = o0.step(grads=grads)
+        assert data0["bucket_count"] == 0.0
+        assert data0["agg_launches"] == len(jax.tree.leaves(params))
 
 
 # ---------------------------------------------------------------------------
@@ -430,36 +465,42 @@ def test_codec_wire_per_tensor_codec_ignores_bucket_mb():
 # Launch-count assertions (the CPU-backend smoke of the actual win)
 # ---------------------------------------------------------------------------
 
-def _launch_counts(params, grads, bucket_mb, mode="allgather"):
-    opt = SGD(fresh(params), lr=0.1, mode=mode, bucket_mb=bucket_mb)
-    fn = opt._build_grads_only_step()
-    sds = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.asarray(x).dtype),
-        grads,
-    )
+def _launch_counts(bucket_mb, mode="allgather", codec="int8"):
+    # 40-leaf tree, one dtype
+    params = {f"p{i}": jnp.zeros((1000,), jnp.float32) for i in range(40)}
+    grads = {k: jnp.zeros((WORLD, 1000), jnp.float32) for k in params}
+    opt = SGD(params, lr=0.1, mode=mode, code=get_codec(codec),
+              bucket_mb=bucket_mb)
     return lowered_collective_counts(
-        fn, opt.params, opt.opt_state, opt.codec_state, sds, jax.random.key(0)
+        opt._build_grads_only_step(), opt.params, opt.opt_state,
+        opt.codec_state, _sds(grads), jax.random.key(0)
     )
 
 
 def test_launch_count_reduced_5x_allgather(mesh8):
-    # 40-leaf tree, one dtype: per-leaf = 40 all-reduces, bucketed = 1
-    params = {f"p{i}": jnp.zeros((1000,), jnp.float32) for i in range(40)}
-    grads = {k: jnp.zeros((WORLD, 1000), jnp.float32) for k in params}
-    per_leaf = _launch_counts(params, grads, 0)
-    bucketed = _launch_counts(params, grads, 16)
-    assert per_leaf["all_reduce"] >= 40
-    assert bucketed["all_reduce"] * 5 <= per_leaf["all_reduce"]
+    # int8: an all-gather of the payload and of its scale a leaf, the
+    # same two for the one bucket
+    per_leaf = _launch_counts(0)
+    bucketed = _launch_counts(16)
+    assert per_leaf["all_gather"] >= 40
+    assert bucketed["all_gather"] * 5 <= per_leaf["all_gather"]
 
 
 def test_launch_count_reduced_5x_leader(mesh8):
-    params = {f"p{i}": jnp.zeros((1000,), jnp.float32) for i in range(40)}
-    grads = {k: jnp.zeros((WORLD, 1000), jnp.float32) for k in params}
-    per_leaf = _launch_counts(params, grads, 0, mode="leader")
-    bucketed = _launch_counts(params, grads, 16, mode="leader")
-    # ZeRO-1: reduce_scatter in, all_gather out — both collapse
+    per_leaf = _launch_counts(0, mode="leader")
+    bucketed = _launch_counts(16, mode="leader")
+    # ZeRO-1 (dense_scatter): reduce_scatter in, all_gather out — both
+    # collapse
     assert per_leaf["total"] >= 80
     assert bucketed["total"] * 5 <= per_leaf["total"]
+
+
+def test_launch_count_of_a_psum_codec_ignores_bucket_mb(mesh8):
+    # identity keeps one all-reduce a leaf: the compiler groups them
+    per_leaf = _launch_counts(0, codec="identity")
+    asked = _launch_counts(16, codec="identity")
+    assert per_leaf["all_reduce"] >= 40
+    assert asked == per_leaf
 
 
 def test_count_collectives_parses_both_spellings():

@@ -912,21 +912,24 @@ def test_a_call_that_needs_its_own_step_waits_for_it(mesh8, waits, asks):
 
 # -- the one place a step program is handed to the compiler (PR 28) ----------
 
-@pytest.mark.parametrize("bucket_mb, want", [
-    (0.0, {"an_option": "true"}),
-    (16.0, None),
-], ids=["per-leaf", "flat-buckets"])
-def test_jit_spmd_hands_jit_what_comms_decides(monkeypatch, mesh8, bucket_mb,
-                                               want):
+@pytest.mark.parametrize("codec, bucket_mb, want", [
+    ("identity", 0.0, {"an_option": "true"}),
+    ("identity", 16.0, {"an_option": "true"}),
+    ("int8", 16.0, None),
+], ids=["per-leaf", "psum-codec-bucket-mb", "flat-buckets"])
+def test_jit_spmd_hands_jit_what_comms_decides(monkeypatch, mesh8, codec,
+                                               bucket_mb, want):
     """The decision is ``comms.async_allreduce_options``' alone, on this
     optimizer's mesh and aggregation axes (None on this backend), and
-    ``jax.jit`` gets it as it is; a program whose exchange is in flat
-    buckets is compiled without, whatever the mesh."""
+    ``jax.jit`` gets it as it is; a program whose exchange runs on a
+    bucket plan (a payload codec's) is compiled without, whatever the
+    mesh. A psum codec has no plan, whatever ``bucket_mb`` says."""
     from jax.sharding import PartitionSpec as P
 
     from pytorch_ps_mpi_tpu import comms
 
-    opt = Adam(make_params(), mesh=mesh8, lr=0.05, bucket_mb=bucket_mb)
+    opt = Adam(make_params(), mesh=mesh8, lr=0.05, code=get_codec(codec),
+               bucket_mb=bucket_mb)
     assert comms.async_allreduce_options(opt.mesh, opt._agg_axes) is None
     asked, seen, real = [], {}, jax.jit
 
