@@ -19,11 +19,12 @@ from chipbench.reference import xing as reference
 
 def release_freed_heap() -> None:
     """Hand the heap's freed pages back to the system. The streamed
-    comparison compiles seven large programs in a row beside six host
+    comparison compiles its large programs in a row beside the host
     copies of the parameters on a machine of 40 GiB; glibc keeps what a
     compile freed (3.9 GB after one of the reference's gradient programs:
-    6.05 -> 2.14 GB resident after ``malloc_trim``, PERF.md section 6,
-    PR 33) and the run's peak read 37.7 GB with it, over 40 GiB once."""
+    6.05 -> 2.14 GB resident after ``malloc_trim``, PERF.md section 6)
+    and the run's peak read 37.7 GB with it, over 40 GiB once, when the
+    comparison still held six host copies."""
     try:
         ctypes.CDLL("libc.so.6").malloc_trim(0)
     except (OSError, AttributeError):

@@ -190,6 +190,7 @@ def run(ctx) -> dict:
     total_applied = len(server.published) - 1  # the first publish is p0
     flat = np.concatenate([np.ravel(np.asarray(a, np.float32))
                            for a in jax.tree.leaves(params)])
+    first_update = {}  # the first applied update's two readings
     checks = {
         # an interrupt can land between a push's acknowledgement and the
         # worker's count of it, so the server may hold one more a worker
@@ -209,7 +210,8 @@ def run(ctx) -> dict:
             and not np.array_equal(flat, server.first_flats[0]),
         "first_update": first_update_ok(
             server.first_flats, params0, cfg["hyper"]["lr"],
-            os.path.join(ctx.scratch, "g_ref.npy"), config["tolerances"]),
+            os.path.join(ctx.scratch, "g_ref.npy"), config["tolerances"],
+            first_update),
         "no_compile_in_window": compiled_in_window == 0,
         "workers_exited_cleanly": codes == [0] * n_workers,
     }
@@ -238,8 +240,14 @@ def run(ctx) -> dict:
             for e in load_jsonl(path)[1]:
                 if "dur" in e and o["wall"] <= e["wall"] <= c["wall"]:
                     spans.setdefault(e["name"], []).append(e)
+    tol = config["tolerances"]
+    compared = {k: {"value": v, "limit": tol[k]}
+                for k, v in first_update.items()}
+    compared["compiles_in_window"] = {"value": compiled_in_window, "limit": 0}
+    compared["failed"] = {"value": failed, "limit": 0}
     return {
         "correct": all(checks.values()) and failed == 0,
+        "compared": compared,
         "attempted": consumed, "failed": failed,
         "end_to_end": {f"{fam.unit}_per_s": cycle_s and batch / cycle_s,
                        "staleness_mean": staleness_mean,
@@ -281,10 +289,12 @@ def reap(procs, patience: float = 120.0):
 
 
 def first_update_ok(first_flats, template, lr: float, g_ref_path: str,
-                    tol: dict) -> bool:
+                    tol: dict, readings: dict) -> bool:
     """The first applied update against ``-lr * int8(g_ref)``, leaf by
     leaf (the codec scales each leaf by its own largest magnitude).
-    Momentum's buffer starts at the gradient, so step 1 is plain SGD."""
+    Momentum's buffer starts at the gradient, so step 1 is plain SGD.
+    The two numbers compared go into ``readings`` under their limits'
+    names."""
     import jax
     import numpy as np
 
@@ -307,5 +317,7 @@ def first_update_ok(first_flats, template, lr: float, g_ref_path: str,
     whole = float(np.linalg.norm(step - want) / np.linalg.norm(want))
     say(check="first_update", rel_l2=whole, worst_leaf_rel_l2=worst,
         tolerances=tol)
+    readings.update(first_update_rel_l2=whole,
+                    first_update_worst_leaf_rel_l2=worst)
     return (whole <= tol["first_update_rel_l2"]
             and worst <= tol["first_update_worst_leaf_rel_l2"])

@@ -3,8 +3,10 @@ devices, data-parallel, fed by a prefetching input pipeline.
 
 One run: parameters from the seed in one jitted call on the device ->
 warm-up until a step compiles nothing (the first three steps are kept
-for the reference) -> the comparison with the float32 reference ->
-the window -> on several chips, the equality of the parameter copies.
+for the reference) -> the window -> on several chips, the equality of
+the parameter copies, and the step's memory analysis -> the system is
+released: no array of it is left on the device -> the comparison with
+the float32 reference, on a chip the system has left.
 
 The window is a row of ``fit`` calls of ``steps_per_fit`` steps, each
 timed by the host clock (``fit`` returns with its last loss fetched, so
@@ -15,7 +17,9 @@ percents where the median call stays within 0.6 % (PERF.md section 6,
 call G). The mean is printed beside it in the row ``"check": "window"``.
 ``setup_s`` leaves out the seconds inside the first ``jax.devices()``,
 the runtime attaching to the chip (5.8-9.1 s from run to run): nothing a
-change to the repo moves.
+change to the repo moves. The comparison comes after the window, so its
+seconds are the benchmark's and not in ``setup_s``; the device's peak is
+read before it, so its memory is not in ``memory_peak_bytes``.
 """
 
 from __future__ import annotations
@@ -74,8 +78,10 @@ def run(ctx) -> dict:
 
     params = jax.jit(fam.init)(jax.random.key(ctx.seed))
     # the reference's copies wait on the host: nothing of the benchmark's
-    # stays on the device while the system's peak memory is read
-    p0 = jax.device_get(params)
+    # stays on the device while the system's peak memory is read. The
+    # comparison takes them out of this dict, so that it holds the only
+    # reference and can drop each leaf once it is read
+    kept = {"p0": jax.device_get(params)}
     mark("init")
     n_params = sum(a.size for a in jax.tree.leaves(params))
     mesh = make_mesh(devices=devices)
@@ -110,13 +116,13 @@ def run(ctx) -> dict:
     stream = fed(pipeline)
 
     # -- warm-up: steps 1-3 are the ones the reference repeats ---------------
-    losses, p1 = [], None
+    losses = []
     for i in range(MAX_WARM_STEPS):
         before = compiles.count
         losses.append(trainer.fit(stream, 1)["final_loss"])
         compiled = compiles.count - before
         if i == 0:
-            p1 = jax.device_get(jax.tree.map(
+            kept["p1"] = jax.device_get(jax.tree.map(
                 lambda a: a.addressable_shards[0].data, opt.params))
         mark(f"step{i + 1}")
         if i >= 2 and not compiled:
@@ -127,12 +133,6 @@ def run(ctx) -> dict:
     buffers_peak = peak_bytes(devices)
     mark("warm")
 
-    verdict = against_reference(fam, config, optimizer["lr"], devices,
-                                p0, p1, first, losses[:3])
-    say(check="reference", **verdict)
-    del p0, p1
-    mark("reference")
-
     # -- the window ---------------------------------------------------------------
     rec = telemetry.configure() if ctx.trace else None
     trace_dir = os.path.join(ctx.scratch, "trace")
@@ -140,6 +140,7 @@ def run(ctx) -> dict:
     t_open_wall, t_open_mono = time.time(), time.monotonic()
     t_open = time.perf_counter()
     setup_s = t_open - ctx.t0 - attach_s
+    misses = cache.misses  # set-up's, as ``setup_s``: not the comparison's
     say(check="setup", seconds_since_start=timeline, attach_s=attach_s,
         process_start_to_window_s=t_open - ctx.t0)
     steps = failed = 0
@@ -179,6 +180,7 @@ def run(ctx) -> dict:
     memory = opt.step_memory_analysis(
         fam.loss_fn, jax.device_put(first[0], sharding))
     system_peak = buffers_peak + memory["temp_size_in_bytes"]
+    wire = wire_bytes(opt, code, n_params, len(devices))
     say(check="memory", buffers_peak=buffers_peak, step_program=memory,
         runtime=[d.memory_stats() for d in devices[:1]])
     finite = all(math.isfinite(l) for l in losses)
@@ -189,6 +191,13 @@ def run(ctx) -> dict:
         rate_over_window=steps * rows * fam.units_per_row / elapsed,
         call_s={f"p{q}": percentile(calls, q) for q in (0, 25, 50, 75, 100)},
         calls_ms=[round(1e3 * c, 2) for c in calls])
+
+    # -- the system leaves the chip, and the reference has it ---------------
+    del opt, trainer, pipeline, stream
+    left = system_left()
+    verdict = against_reference(fam, config, optimizer["lr"], devices,
+                                kept, first, losses[:3], budget=system_peak)
+    say(check="reference", system_left_on_device=left, **verdict)
 
     peaks = None
     if ctx.trace and devices[0].platform == "tpu":
@@ -206,9 +215,15 @@ def run(ctx) -> dict:
                                  if e["name"] == "trainer.step"
                                  and e["ts"] >= t_open_mono]
         telemetry.disable()
+    tol = config["tolerances"]
+    compared = {k: {"value": verdict[k], "limit": tol[k]}
+                for k in tol if k in verdict}
+    compared["compiles_in_window"] = {"value": in_window, "limit": 0}
+    compared["failed"] = {"value": failed, "limit": 0}
     return {
         "correct": bool(verdict["ok"] and finite and copies_equal
                         and in_window == 0 and failed == 0),
+        "compared": compared,
         "attempted": steps, "failed": failed,
         "end_to_end": {f"{fam.unit}_per_s": per_call / percentile(calls, 50),
                        "setup_s": setup_s},
@@ -217,15 +232,29 @@ def run(ctx) -> dict:
         "spans": spans,
         "counters": {
             "compiles_in_window": in_window,
-            "cache_misses": cache.misses, "peak_hbm_bytes": system_peak,
+            "cache_misses": misses, "peak_hbm_bytes": system_peak,
             "steps": steps, "chips": len(devices),
-            "wire_bytes_per_update": wire_bytes(opt, code, n_params,
-                                                len(devices)),
+            "wire_bytes_per_update": wire,
         },
         "shape": dict(fam.shape, rows=rows, head_dim=fam.head_dim,
                       dtype_bytes=fam.dtype_bytes),
         "peaks": peaks,
     }
+
+
+def system_left() -> dict:
+    """The arrays still on a device once the job has dropped the
+    system (its optimizer, trainer, pipeline and batches): what the
+    comparison would share the chip with. Each is named by its shape and
+    type; ``bytes`` counts them all."""
+    import gc
+
+    import jax
+
+    gc.collect()
+    left = jax.live_arrays()
+    return {"arrays": [f"{a.dtype}{list(a.shape)}" for a in left[:16]],
+            "count": len(left), "bytes": sum(a.nbytes for a in left)}
 
 
 def replicas_equal(params, devices) -> bool:
@@ -263,21 +292,25 @@ def wire_bytes(opt, code, n_params: int, chips: int):
                for a in jax.tree.leaves(opt.params)) // 8
 
 
-def against_reference(fam, config, lr, devices, p0, p1, first, losses) -> dict:
+def against_reference(fam, config, lr, devices, kept, first, losses,
+                      budget=None) -> dict:
     """Steps 1-3 of the system against the plain reference on one device:
     the three losses, and step 1's parameter change against the
     reference's own Adam update (see the configuration's ``guarantees``
     and ``tolerances``). Data-parallel training means: every chip takes
     the loss of its own rows (its own masked mean), and losses and
     gradients are averaged over the chips — so the reference does that,
-    chip by chip, on the same rows in the same order."""
+    chip by chip, on the same rows in the same order. ``kept`` holds the
+    host copies of the parameters before and after step 1 (``p0``,
+    ``p1``), which this takes out of it; ``budget`` (the system's peak
+    bytes) sizes the streamed comparison and is not needed here."""
     import jax
     import jax.numpy as jnp
 
     from chipbench.reference.transformer import Adam, BlockedLoss
 
     tol = config["tolerances"]
-    p0, p1 = jax.device_put((p0, p1), devices[0])
+    p0, p1 = jax.device_put((kept.pop("p0"), kept.pop("p1")), devices[0])
     ref = BlockedLoss(fam.reference_terms, fam.reference_block_rows)
     adam = Adam(p0, lr)
     p, ref_losses, g1, p1_ref = p0, [], None, None

@@ -5,14 +5,17 @@ are ``sync_train.run``'s own statements — this module calls it with
 comparison with the plain reference differs.
 
 ``sync_train.against_reference`` keeps about eleven float32 copies of the
-parameters on the first device beside the system's three. Here the
-system's state stays where it is, untouched, and beside it the device
-holds at most ONE float32 copy of the parameters (the reference's,
-step by step), one group of leaves' gradient (``GROUP_BYTES``) and one
-leaf's Adam state at a time: every other copy (``p0``, ``p1``, the
-reference's Adam moments, its next parameters) waits on the host and is
-streamed leaf by leaf; the gradient is taken group by group (one forward
-and backward pass a group), the rows of a batch one at a time.
+parameters on the first device. Here the comparison runs, as every
+comparison does, after the window on a chip the system has left, and
+holds no more of it than the system's own peak (``budget``): ONE float32
+copy of the parameters (the reference's, kept on the device through the
+three steps and written leaf by leaf by its Adam) and the gradient of
+one group of leaves: all of them, one forward and backward pass a row,
+where that fits beside the program's temporaries. Adam's moments wait
+on the host, leaf by leaf. ``p0`` and ``p1`` are taken from the caller
+and each leaf is dropped once it is on the device or read; a step's
+gradient waits on the host only where it took several groups. The rows
+of a batch are taken one at a time.
 
 It decides the guarantees of the other synchronous cells — the loss of
 steps 1-3, and step 1's parameter change against the reference's own Adam
@@ -36,7 +39,7 @@ set against (PERF.md section 4).
 
 from __future__ import annotations
 
-GROUP_BYTES = 0.8e9  # of gradient leaves taken in one backward pass
+import os
 
 
 def run(ctx) -> dict:
@@ -47,25 +50,27 @@ def run(ctx) -> dict:
     importlib.import_module(f"chipbench.families.{ctx.config['family']}")
     from chipbench.jobs import sync_train
 
-    counters, unscoped = {}, {}
+    counters, text = {}, {}
 
-    def compare(fam, config, lr, devices, p0, p1, first, losses):
-        verdict, more = against_reference(fam, config, lr, devices, p0, p1,
-                                          first, losses)
+    def compare(fam, config, lr, devices, kept, first, losses, budget=None):
+        if ctx.trace:
+            counters["scopes"] = instruction_scopes(
+                text.pop("step"), getattr(fam, "unscoped", {}))
+        verdict, more = against_reference(fam, config, lr, devices, kept,
+                                          first, losses, budget)
         counters.update(more)
-        unscoped.update(getattr(fam, "unscoped", {}))
         return verdict
 
     # the step program's text, taken where sync_train asks the optimizer
-    # for its memory analysis (after the window): a trace event names its
-    # instruction, the text names the instruction's scope
+    # for its memory analysis (after the window, before the comparison):
+    # a trace event names its instruction, the text names the
+    # instruction's scope
     from pytorch_ps_mpi_tpu import MPI_PS
 
     def analysis(opt, *args, **kw):
         out = memory_analysis(opt, *args, **kw)
         if ctx.trace:
-            counters["scopes"] = instruction_scopes(
-                opt.step_program_text(), unscoped)
+            text["step"] = opt.step_program_text()
         return out
 
     memory_analysis, MPI_PS.step_memory_analysis = (
@@ -103,7 +108,7 @@ def instruction_scopes(text: str | None, unscoped: dict) -> dict:
     return scopes
 
 
-def leaf_groups(leaves, limit: float = GROUP_BYTES) -> list[list[int]]:
+def leaf_groups(leaves, limit: float) -> list[list[int]]:
     """Consecutive leaves packed into groups of at most ``limit`` bytes
     (a larger leaf is a group of its own)."""
     groups, size = [[]], 0
@@ -116,10 +121,32 @@ def leaf_groups(leaves, limit: float = GROUP_BYTES) -> list[list[int]]:
     return groups
 
 
-def against_reference(fam, config, lr, devices, p0, p1, first, losses):
-    """(verdict, counters). ``p0`` / ``p1`` are host copies of the
-    system's parameters before and after step 1, ``first`` the batches of
-    steps 1-3, ``losses`` the system's losses of those steps."""
+def host_rss() -> int:
+    """Bytes of this process resident in host memory now; 0 where
+    ``/proc`` does not say."""
+    try:
+        with open("/proc/self/statm") as f:
+            return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+    except (OSError, ValueError, IndexError):
+        return 0
+
+
+def bytes_in_use(device):
+    """Bytes the device holds now; None where its backend keeps no
+    count (XLA:CPU)."""
+    stats = device.memory_stats()
+    return stats.get("bytes_in_use", 0) if stats else None
+
+
+def against_reference(fam, config, lr, devices, kept, first, losses,
+                      budget=None):
+    """(verdict, counters). ``kept`` holds host copies of the system's
+    parameters before and after step 1 (``p0``, ``p1``): this takes them
+    out of it and drops each leaf once it is on the device or read.
+    ``first`` holds the batches of steps 1-3, ``losses`` the system's
+    losses of those steps, ``budget`` the system's own peak bytes on the
+    device (None or 0: no limit), under which the comparison sizes its
+    leaf groups."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -128,20 +155,53 @@ def against_reference(fam, config, lr, devices, p0, p1, first, losses):
 
     tol, ref, rcfg = config["tolerances"], fam.reference, fam.reference_cfg
     device = devices[0]
-    host, treedef = jax.tree.flatten(p0)
-    after = jax.tree.leaves(p1)
+    if bytes_in_use(device) is None:
+        budget = None
+    p0 = kept.pop("p0")
     paths = [jax.tree_util.keystr(k)
              for k, _ in jax.tree_util.tree_leaves_with_path(p0)]
-    groups = leaf_groups(host)
+    host, treedef = jax.tree.flatten(p0)
+    after = jax.tree.leaves(kept.pop("p1"))
+    del p0
     put = lambda tree: jax.device_put(tree, device)
+
+    in_use, rss = {}, {}  # the most at the comparison's points
+    rss_before, peak = host_rss(), {"bytes": 0}
+
+    def held(what, temp=0):
+        """Device bytes in use and host bytes resident now; ``temp``:
+        what the program that just ran held beside its arguments and
+        outputs (its temporaries), for the device's peak."""
+        now = bytes_in_use(device) or 0
+        in_use[what] = max(in_use.get(what, 0), now)
+        rss[what] = max(rss.get(what, 0), host_rss())
+        peak["bytes"] = max(peak["bytes"], now + temp)
+
+    # the reference's parameters: ONE float32 copy, on the device through
+    # the three steps; step 1's Adam writes it leaf by leaf
+    leaves = []
+    for j, a in enumerate(host):
+        leaves.append(put(a))
+        host[j] = None
+    del host, a
+    param_bytes = sum(a.nbytes for a in leaves)
+    held("the reference's parameters")
 
     def rows_of(batch):
         n = jax.tree.leaves(batch)[0].shape[0]
         return [put(jax.tree.map(lambda a: a[r:r + 1], batch))
                 for r in range(n)]
 
-    terms = jax.jit(lambda leaves, sub: ref.terms(
-        jax.tree.unflatten(treedef, leaves), sub, rcfg))
+    def compiled(fn, *args):
+        """``fn`` compiled for ``args``, and the bytes it needs beside
+        them: its temporaries, and its outputs that take no argument's
+        place."""
+        program = jax.jit(fn).lower(*args).compile()
+        m = program.memory_analysis()
+        if m is None:
+            return program, 0, 0
+        return (program, m.temp_size_in_bytes,
+                m.output_size_in_bytes - m.alias_size_in_bytes)
 
     def grad_of(group):
         def total(part, leaves, sub):
@@ -150,26 +210,55 @@ def against_reference(fam, config, lr, devices, p0, p1, first, losses):
                 leaves[j] = a
             return ref.terms(jax.tree.unflatten(treedef, leaves), sub, rcfg)
 
-        return jax.jit(jax.value_and_grad(total, has_aux=True))
+        return compiled(jax.value_and_grad(total, has_aux=True),
+                        [leaves[j] for j in group], leaves, row)
 
-    grads_of = [grad_of(g) for g in groups]
+    row = rows_of(first[0])[0]
+    summed = jax.tree.leaves(first[0])[0].shape[0] > 1  # rows' gradients
+    base = bytes_in_use(device) or 0
+
+    def plan(limit):
+        """Leaf groups of at most ``limit`` bytes, their compiled
+        gradients, and the most bytes one group's gradient needs beside
+        the parameters: its program's temporaries and outputs, and the
+        sum over the rows."""
+        groups = leaf_groups(leaves, limit)
+        grads = [grad_of(g) for g in groups]
+        need = max(t + o for _, t, o in grads) + summed * max(
+            sum(leaves[j].nbytes for j in g) for g in groups)
+        return groups, grads, need
+
+    def group_limit(temp):
+        """The most gradient bytes a group may have where its program
+        needs ``temp`` beside the parameters and the gradient: all of
+        them where the budget holds that, else what it leaves, in at
+        most about eight groups (each is a compile of its own, and the
+        chip holds more than the system's peak)."""
+        room = (budget - base - temp) / (1 + summed) if budget else param_bytes
+        return param_bytes if room >= param_bytes else max(
+            room, param_bytes / 8)
+
+    # the loss alone (step 3's) is compiled first: its temporaries are
+    # fewer than a gradient program's, so they tell beforehand where one
+    # group cannot fit, and that group is not compiled
+    terms = compiled(lambda leaves, sub: ref.terms(
+        jax.tree.unflatten(treedef, leaves), sub, rcfg), leaves, row)
+    groups, grads_of, need = plan(group_limit(terms[1]))
+    if budget and len(groups) == 1 and base + need > budget:
+        groups, grads_of, need = plan(group_limit(grads_of[0][1]))
     add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=0)
     scale = jax.jit(lambda g, d: jax.tree.map(lambda x: x / d, g),
                     donate_argnums=0)
-    in_use = {}  # the most bytes on the device at the comparison's points
-
-    def held(what):
-        now = (device.memory_stats() or {}).get("bytes_in_use", 0)
-        in_use[what] = max(in_use.get(what, 0), now)
 
     # the reference's Adam: its jitted update and its constants; its
-    # moments are kept here, on the host, leaf by leaf (None: zeros)
+    # moments per leaf, on the host (None: zeros)
     adam = Adam((), lr)
-    moments = [None] * len(host)
+    moments = [None] * len(leaves)
 
     def adam_leaf(j, p, g, t):
         size = adam.lr * (1 - adam.b2 ** t) ** 0.5 / (1 - adam.b1 ** t)
-        m, v = moments[j] or (jnp.zeros_like(p), jnp.zeros_like(p))
+        m, v = (put(moments[j]) if moments[j] is not None
+                else (jnp.zeros_like(p), jnp.zeros_like(p)))
         p, m, v = adam._step(p, g, m, v, jnp.float32(size))
         moments[j] = jax.device_get((m, v))
         return p
@@ -198,46 +287,64 @@ def against_reference(fam, config, lr, devices, p0, p1, first, losses):
 
     has_router = hasattr(fam, "router_loads")
     if has_router:
-        sys_loads_of = jax.jit(lambda leaves, b: fam.router_loads(
-            jax.tree.unflatten(treedef, leaves), b))
-        ref_loads_of = jax.jit(lambda leaves, sub: ref.router_loads(
-            jax.tree.unflatten(treedef, leaves), sub, rcfg))
+        sys_loads_of = compiled(lambda leaves, b: fam.router_loads(
+            jax.tree.unflatten(treedef, leaves), b), leaves, put(first[0]))
+        ref_loads_of = compiled(lambda leaves, sub: ref.router_loads(
+            jax.tree.unflatten(treedef, leaves), sub, rcfg), leaves, row)
+    del row
+
+    def call(what, program, *args):
+        out = program[0](*args)
+        jax.block_until_ready(out)
+        held(what, program[1])  # its outputs are in use now
+        return out
 
     stats = {}           # leaf path -> update_stats of step 1, on the host
     ref_losses, sys_loads, ref_loads = [], [], []
     for i, batch in enumerate(first):
         subs = rows_of(batch)
-        held("the system's state alone")
-        leaves = put(host)                       # the one float32 copy
         if has_router:
-            sys_loads.append(np.asarray(sys_loads_of(leaves, put(batch))))
-            ref_loads.append(np.asarray(sum(ref_loads_of(leaves, s)
-                                            for s in subs)))
+            sys_loads.append(np.asarray(call(
+                "the program's router", sys_loads_of, leaves, put(batch))))
+            ref_loads.append(np.asarray(sum(
+                call("the reference's router", ref_loads_of, leaves, s)
+                for s in subs)))
         if i == 2:
-            total, count = map(sum, zip(*[terms(leaves, s) for s in subs]))
+            total, count = map(sum, zip(*[
+                call("the reference's loss", terms, leaves, s)
+                for s in subs]))
             ref_losses.append(float(total / count))
             continue
-        new_host = list(host)
+        # every group's gradient before any parameter moves: on the
+        # device where it is one group, else on the host
+        grads = [None] * len(leaves)
         for group, grad in zip(groups, grads_of):
             part = [leaves[j] for j in group]
             total = count = g = None
             for s in subs:
-                (t, c), gs = grad(part, leaves, s)
-                held("with the reference's copy and a group's gradient")
+                (t, c), gs = call("a group's gradient", grad, part, leaves, s)
                 total, count, g = ((t, c, gs) if g is None else
                                    (total + t, count + c, add(g, gs)))
                 del gs
             g = scale(g, count)
+            if len(groups) > 1:
+                g = jax.device_get(g)
             for j, gj in zip(group, g):
-                new = adam_leaf(j, leaves[j], gj, i + 1)
-                if i == 0:
-                    stats[paths[j]] = jax.device_get(update_stats(
-                        leaves[j], put(after[j]), new, gj))
-                new_host[j] = jax.device_get(new)
+                grads[j] = gj
             del g, part
         ref_losses.append(float(total / count))
-        host = new_host
-        del leaves
+        for j in range(len(leaves)):
+            gj = put(grads[j])
+            grads[j] = None
+            new = adam_leaf(j, leaves[j], gj, i + 1)
+            if i == 0:
+                stats[paths[j]] = jax.device_get(update_stats(
+                    leaves[j], put(after[j]), new, gj))
+                after[j] = None
+            leaves[j] = new
+            del new, gj
+        held("after Adam")
+    del leaves, moments
 
     def read(rows, tag=""):
         """(share of equal signs, relative L2 error) over ``rows`` of
@@ -287,7 +394,16 @@ def against_reference(fam, config, lr, devices, p0, p1, first, losses):
                    "worst_expert_sign_share": bf16_expert[0],
                    "worst_expert_rel_l2": bf16_expert[1]},
                "leaf_groups": len(groups),
-               "device_bytes_in_use": in_use}
+               "device_bytes_in_use": in_use,
+               # what the device held beside the reference's parameters
+               # when the comparison began: what the system left there
+               "device_beside_params_bytes": base and base - param_bytes,
+               "device_peak_bytes": peak["bytes"],
+               "budget_bytes": budget,
+               "host_rss_bytes": rss,
+               "host_rss_before_bytes": rss_before,
+               "host_bytes_per_param_above_before": (
+                   max(rss.values()) - rss_before) / (param_bytes / 4)}
     counters = {}
     if has_router:
         sys_loads, ref_loads = np.stack(sys_loads), np.stack(ref_loads)

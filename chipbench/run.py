@@ -8,7 +8,9 @@ mix ``traffic/<mix>.json``, the job driver ``jobs/<job>.py`` the mix
 names, the model family ``families/<family>.py`` the configuration
 names, and one reader ``layer_metrics/<metric>.py`` per per-layer
 metric. The last line of standard output is the result; earlier lines
-are JSON rows of what was checked.
+are JSON rows of what was checked. The result's last key, ``compared``,
+holds each number the run compared with its limit, and the last lines of
+standard error say the same, one a line.
 
 Without a TPU, or with fewer chips than the cell asks for, a cell of
 ``BENCHMARK.json`` exits non-zero and prints no result. ``main(argv,
@@ -153,6 +155,11 @@ def main(argv=None, manifest: str | None = None) -> int:
         for m in man.metrics("end_to_end", cell["name"]):
             line["metrics"][m["name"]] = {
                 "value": result["end_to_end"][m["name"]], "unit": m["unit"]}
+    # each number the run compared, beside its limit, last on both streams
+    line["compared"] = result["compared"]
+    for name, c in line["compared"].items():
+        print(f"compared {name} {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

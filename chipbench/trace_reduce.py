@@ -106,7 +106,7 @@ def load(path: str) -> Trace | None:
     return read_xplane(max(files, key=os.path.getmtime)) if files else None
 
 
-# -- interval arithmetic (copied from pytorch_ps_mpi_tpu/utils/tracing.py) ----
+# -- interval arithmetic over [start, end) pairs ----------------------------
 
 def union(intervals):
     """Merge [start, end) intervals into a disjoint sorted list."""

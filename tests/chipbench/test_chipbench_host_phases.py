@@ -17,9 +17,29 @@ from chipbench.run import ROOT, Manifest
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 MS = 1_000_000  # nanoseconds
 
+# the per-layer metrics the benchmark began with
+FIRST = {"loop.step_ms_p50", "loop.step_ms_p95", "step.device_ms",
+        "step.compiles_in_window", "model.mfu_pct", "attn.kernel_ms",
+        "flash_attention_roofline", "attn.roofline_pct", "coll.time_ms",
+        "coll.exposed_ms", "wire.mb_per_update", "server.update_ms_p50",
+        "server.updates_per_s", "worker.grad_share_pct", "worker.push_ms_p50",
+        "cache.misses", "device.idle_pct", "device.peak_hbm_gb"}
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    _BEFORE = 18  # per-layer metrics of the benchmark as PR 22 defined it
-    NEW_METRICS = [m for m in json.load(_f)["per_layer"][_BEFORE:]]
+    _DOC = json.load(_f)
+    NEW_METRICS = [m for m in _DOC["per_layer"] if m["name"] not in FIRST]
+# the idle split and the spans' metrics this file's readers brought
+SYNC_IDLE = ["trainer.data", "ps.prepare", "ps.dispatch", "ps.wait",
+             "trainer.loss_fetch", "ps.step", "trainer.step", "in_program",
+             "outside"]
+ASYNC_IDLE = ["worker.read_params", "worker.batch", "worker.grad_dispatch",
+              "worker.grad_wait", "wire.encode", "wire.send", "worker.step",
+              "worker.in_program", "worker.outside"]
+SPAN_METRICS = {"step.dispatch_ms_p50": "fused step",
+                "worker.read_ms_p50": "async worker",
+                "worker.batch_ms_p50": "async worker",
+                "wire.encode_ms_p50": "codec / wire",
+                "wire.send_ms_p50": "codec / wire"}
+ASYNC_CELL = "resnet18-cifar.async1"
 
 
 def hand_made():
@@ -199,14 +219,35 @@ def test_read_xplane_finds_the_programs_spans(tmp_path):
 # -- the new readers -----------------------------------------------------------
 
 def test_the_new_entries_are_the_issues():
-    names = [m["name"] for m in NEW_METRICS]
-    assert len(names) == len(set(names)) == 23
-    idle = [m for m in NEW_METRICS if m["name"].startswith("idle.")]
-    assert len(idle) == 18
-    assert all(m["source"] == "device_trace" and m["layer"] == "device"
-               and m["unit"] == "ms" and m["better"] == "lower" for m in idle)
-    assert all(m["source"] == "program_span" for m in NEW_METRICS
-               if m not in idle)
+    """Each entry this file's readers brought, by name: its fields, its
+    reader, and the kind of cell that reads it (the async worker's for
+    ``idle.worker.*`` / ``idle.wire.*`` and the worker's and wire's
+    spans, the synchronous step's for the rest)."""
+    by_name = {m["name"]: m for m in _DOC["per_layer"]}
+    sync = {c["name"] for c in _DOC["workloads"] if c["name"] != ASYNC_CELL}
+    idle = ([f"idle.{s}_ms" for s in SYNC_IDLE]
+            + [f"idle.{s}_ms" for s in ASYNC_IDLE])
+    for name in idle + list(SPAN_METRICS):
+        m = by_name[name]
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert m["unit"] == "ms" and m["better"] == "lower"
+        if name in idle:
+            assert m["source"] == "device_trace" and m["layer"] == "device"
+        else:
+            assert m["source"] == "program_span"
+            assert m["layer"] == SPAN_METRICS[name]
+        on_worker = name.startswith(("idle.worker.", "idle.wire.",
+                                     "worker.", "wire."))
+        assert m["moves"] == ("staleness_mean" if on_worker
+                              else "tokens_per_s"), name
+        if on_worker:
+            assert ASYNC_CELL in m["workloads"]
+        else:
+            assert ASYNC_CELL not in m["workloads"]
+            assert set(m["workloads"]) & sync
+        assert callable(Manifest(os.path.join(ROOT, "BENCHMARK.json"))
+                        .reader(name))
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in NEW_METRICS])

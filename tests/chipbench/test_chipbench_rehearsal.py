@@ -15,7 +15,8 @@ CELLS = {"tiny-bert.mlm": ("tiny-bert", "tiny-mlm", 1),
          "tiny-bert.mlm.dp4": ("tiny-bert", "tiny-mlm", 4),
          "tiny-gpt.lm": ("tiny-gpt", "tiny-lm", 1),
          "tiny-resnet.async": ("tiny-resnet", "tiny-async", 1)}
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 
 
 def rehearsal_manifest(directory, extra_paths=(), extra_cells=None,
@@ -88,6 +89,31 @@ def test_rehearsal(cell, tmp_path, capfd):
                 <= w["elapsed_s"])
     if cell.endswith("dp4"):
         assert line["metrics"]["wire.mb_per_update"]["value"] > 0
+
+
+@pytest.mark.parametrize("cell, names", [
+    ("tiny-gpt.lm", {"loss_rel", "update_sign_share", "update_rel_l2",
+                     "compiles_in_window", "failed"}),
+    ("tiny-resnet.async", {"first_update_rel_l2",
+                           "first_update_worst_leaf_rel_l2",
+                           "compiles_in_window", "failed"})])
+def test_the_numbers_compared_close_both_streams(cell, names, tmp_path,
+                                                 capfd):
+    """Each number a run compared, with its limit: the result's last key,
+    and the last lines of standard error."""
+    from chipbench.run import main
+
+    manifest, _ = rehearsal_manifest(str(tmp_path))
+    assert main(["--workload", cell, "--seed", "7", "--seconds", "1",
+                 "--trace", "0"], manifest=manifest) == 0
+    out, err = capfd.readouterr()
+    line = json.loads(out.splitlines()[-1])
+    assert list(line)[-1] == "compared" and set(line["compared"]) == names
+    for c in line["compared"].values():
+        assert set(c) == {"value", "limit"}
+    tail = err.splitlines()[-len(names):]
+    assert [l.split()[1] for l in tail] == list(line["compared"])
+    assert all(l.startswith("compared ") and " limit " in l for l in tail)
 
 
 def test_untraced_line_off_the_chip_has_no_rate(tmp_path, capfd):

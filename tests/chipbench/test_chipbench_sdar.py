@@ -179,13 +179,20 @@ NEW_READERS = ["model.moe_mfu_pct", "attn.bd_kernel_ms", "attn.bd_roofline_pct",
 def test_the_cell_and_its_metrics_are_appended():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         doc = json.load(f)
-    assert doc["workloads"][-1]["name"] == "sdar-30b-a3b.bd4k"
-    assert doc["configs"][-1]["reduced"] == [
+    cell = next(c for c in doc["workloads"]
+                if c["name"] == "sdar-30b-a3b.bd4k")
+    assert cell == dict(cell, config="sdar-30b-a3b", traffic="bd4k", chips=1)
+    config = next(c for c in doc["configs"] if c["name"] == "sdar-30b-a3b")
+    assert config["reduced"] == [
         "num_hidden_layers", "num_experts", "vocab_size"]
-    assert [m["name"] for m in doc["per_layer"][-7:]] == NEW_READERS
-    for m in doc["per_layer"][-7:]:
-        assert m["workloads"] == ["sdar-30b-a3b.bd4k"]
+    assert config["file"] == "chipbench/configs/sdar-30b-a3b.json"
+    by_name = {m["name"]: m for m in doc["per_layer"]}
+    for name in NEW_READERS:
+        m = by_name[name]
+        assert "sdar-30b-a3b.bd4k" in m["workloads"]
         assert m["moves"] == "tokens_per_s"
+        assert callable(Manifest(os.path.join(ROOT, "BENCHMARK.json"))
+                        .reader(name))
     for name in ("model.mfu_pct", "attn.kernel_ms", "flash_attention_roofline",
                  "attn.roofline_pct"):
         m = next(m for m in doc["per_layer"] if m["name"] == name)
