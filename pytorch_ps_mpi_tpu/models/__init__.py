@@ -12,9 +12,11 @@ one module a family, each read from its source ``config.json``'s key names
 (``<module>.<Config>.from_source``, ``init``, ``apply``, a loss,
 ``router_loads``): ``sdar_moe`` (SDAR-30B-A3B-Chat), ``sambay``
 (Phi-4-mini-flash-reasoning), ``xing`` (Xing4.0-29B-A4B, and on one plain
-residual stream JoyAI-LLM-Flash) and ``lfm2`` (LFM2-24B-A2B). They are
-imported by module, ``from pytorch_ps_mpi_tpu.models import lfm2``, and
-only ``lfm2``'s config class is exported by name beside the flax modules'.
+residual stream JoyAI-LLM-Flash) and ``lfm2`` (LFM2-24B-A2B; its pair
+decoder also runs ``solar``, Solar-Open2-250B, whose module holds the
+configuration and ``init`` only). They are imported by module, ``from
+pytorch_ps_mpi_tpu.models import lfm2``, and only ``lfm2``'s config class
+is exported by name beside the flax modules'.
 """
 
 from pytorch_ps_mpi_tpu.models.mlp import MLP

@@ -1,15 +1,21 @@
-"""Config-driven decoder of the ``lfm2_moe`` family (LFM2-24B-A2B): gated
-short-convolution layers three to one with grouped-query attention, and
-two kinds of feed-forward by published index. Read from the source
-``config.json``'s key names. A layer is a PAIR, (operator kind,
-feed-forward kind): the operator from ``layer_types`` and the feed-forward
-from ``num_dense_layers``, both at the layer's PUBLISHED index, and the
-two vary independently (a dense ``conv`` layer, expert ``conv`` layers,
-expert attention layers). Nothing here is a mechanism of its own:
+"""Config-driven pair decoder: the ``lfm2_moe`` family (LFM2-24B-A2B:
+gated short-convolution layers three to one with grouped-query attention,
+and two kinds of feed-forward by published index), and the layers of the
+``solar_open2`` family (Solar-Open2-250B: gated delta-rule layers three
+to one with gated NoPE attention, every feed-forward an expert layer
+with a shared expert; its configuration and parameters are
+``models/solar.py``'s). Read from the source ``config.json``'s key names.
+A layer is a PAIR, (operator kind, feed-forward kind): the operator from
+``layer_types`` and the feed-forward from ``num_dense_layers``, both at
+the layer's PUBLISHED index, and the two vary independently (a dense
+``conv`` layer, expert ``conv`` layers, expert attention layers). Head
+counts are the HELD ones: a chip's share of a layer whose heads are
+spread over several. Nothing here is a mechanism of its own:
 ``rms_norm``, ``rotary`` and the grouped-query attention with its
-per-head q/k norm are ``models/sdar_moe.py``'s, the SwiGLU is
-``models/xing.py``'s, the routed experts ``parallel/dropless.py``'s, the
-convolution ``ops/short_conv.py``'s, the loss ``models/gpt.py``'s.
+per-head q/k norm or its output gate are ``models/sdar_moe.py``'s, the
+SwiGLU is ``models/xing.py``'s, the routed experts
+``parallel/dropless.py``'s, the convolutions ``ops/short_conv.py``'s,
+the delta rule ``ops/delta_rule.py``'s, the loss ``models/gpt.py``'s.
 
 With ``x [b, T, hidden]``, every norm an RMSNorm with a gain at
 ``norm_eps``:
@@ -21,21 +27,34 @@ With ``x [b, T, hidden]``, every norm an RMSNorm with a gain at
   convolution of ``conv_L_cache`` taps a channel, zero before the row's
   first position (``ops/short_conv.py``).
 - *``full_attention``.* q as ``num_attention_heads`` heads, k and v as
-  ``num_key_value_heads``, of ``hidden / heads`` each; q and k through a
+  ``num_key_value_heads``, of ``head_dim`` each; q and k through a
   per-head RMSNorm, then rotary over the whole head at ``rope_theta``;
-  causal ``softmax(q k^T / sqrt(head)) v``; ``W_o``.
+  causal ``softmax(q k^T / sqrt(head)) v``; ``W_o``. In ``solar``: no
+  rotary (``use_rope`` false), no q/k norm, and the heads' output times
+  ``sigmoid(a W_g)`` before ``W_o`` (``use_gqa_gate``).
+- *``linear_attention``* (``solar``; ``ops/delta_rule.py``), for each
+  held head of ``dk = dv = head_dim``: ``q, k, v = silu(conv(a W_{q,k,v}))``
+  with ``conv`` the causal depthwise convolution of ``short_conv_kernel_size``
+  taps; q and k L2-normalised a head (eps 1e-6), q times ``dk^-1/2``;
+  ``beta = 2 sigmoid(a W_b)`` (``kda_allow_neg_eigval``); the log-decay
+  ``g = -exp(A_log_h) softplus(a W_fa W_fb + dt_bias)`` a key channel;
+  ``S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T``
+  from a zero state, ``o_t = S_t^T q_t``; ``out = [RMSNorm(o_h) *
+  sigmoid(a W_ga W_gb + b_gb)_h]_h W_o``.
 - *Feed-forward.* Published index under ``num_dense_layers``: a SwiGLU of
   ``intermediate_size``. Any other: ``s = sigmoid(a W_r)`` over all
   ``num_experts`` (float32), the ``num_experts_per_tok`` chosen by
   ``top_k(s + expert_bias)``, gates ``s / sum(s) *
   routed_scaling_factor`` over the chosen (no epsilon in the sum:
   ``dropless.route``); ``y = sum over the chosen AND held of gate_e
-  expert_e(a)``, no shared expert. ``expert_bias`` is a leaf of zeros
-  behind ``stop_gradient``: its gradient is exactly zero and no step moves
-  it (the load-driven update of the source family is non-gradient state
-  this package does not carry).
-- After the last layer one more RMSNorm (``embedding_norm``) and the head,
-  which is the embedding's transpose (tied): float32 logits.
+  expert_e(a)``, plus ``shared(a)`` where the layer has a ``shared``
+  SwiGLU (``solar``; ``lfm2`` has none). ``expert_bias``, where a layer
+  has it, is a leaf of zeros behind ``stop_gradient``: its gradient is
+  exactly zero and no step moves it (the load-driven update of the source
+  family is non-gradient state this package does not carry).
+- After the last layer one more RMSNorm (``embedding_norm``) and the head:
+  the embedding's transpose (tied), or ``lm_head`` where the parameters
+  have one (untied): float32 logits.
 
 Parameters are a plain pytree (float32). The operators' leaves carry the
 names this package's functions read: attention ``q_proj, k_proj, v_proj,
@@ -44,9 +63,12 @@ o_proj, q_norm, k_norm`` (the source: ``out_proj``, ``q_layernorm``,
 ``w1, w3, w2``), the router ``router`` (the source: ``gate``), the taps
 ``conv [d, taps]`` (the source: ``conv.weight [d, 1, taps]``). The compute
 dtype is ``cfg.dtype``; norms, the router, the convolution's gates and tap
-sum, the softmaxes and the logits are float32. Named scopes for a device
-trace: ``conv.proj``, ``conv.mix``, ``attn.gqa_proj``, ``attn.gqa``,
-``mlp.swiglu``, ``moe.route|dispatch|experts|combine``, ``loss.head``.
+sum, the softmaxes, the decays and betas (their products at full
+precision), the delta rule's state and the logits are float32. Named
+scopes for a device trace: ``conv.proj``, ``conv.mix``, ``kda.proj``,
+``kda.conv``, ``kda.scan``, ``attn.gqa_proj``, ``attn.gqa``,
+``mlp.swiglu``, ``moe.shared``, ``moe.route|dispatch|experts|combine``,
+``loss.head``.
 With ``remat`` a layer is a checkpoint that keeps the flash kernels'
 output and per-row logsumexp and the expert layer's plan and router
 choice (``ops/_common.checkpoint_layer``) and recomputes the rest: the
@@ -65,7 +87,8 @@ from pytorch_ps_mpi_tpu.models.gpt import causal_lm_loss as next_token_loss
 from pytorch_ps_mpi_tpu.models.sdar_moe import gqa_attention, rms_norm
 from pytorch_ps_mpi_tpu.models.xing import swiglu
 from pytorch_ps_mpi_tpu.ops._common import checkpoint_layer
-from pytorch_ps_mpi_tpu.ops.short_conv import gated_short_conv
+from pytorch_ps_mpi_tpu.ops.delta_rule import HIGHEST, delta_rule
+from pytorch_ps_mpi_tpu.ops.short_conv import gated_short_conv, silu_short_conv
 from pytorch_ps_mpi_tpu.parallel.dropless import dropless_moe
 
 OPERATORS = ("conv", "full_attention")
@@ -218,9 +241,11 @@ def init(key, cfg: Lfm2Config, scale: float = 0.02):
     return params
 
 
-def param_count(cfg: Lfm2Config) -> int:
-    """Parameters of ``init(key, cfg)``, from its shapes alone."""
-    shapes = jax.eval_shape(lambda key: init(key, cfg), jax.random.key(0))
+def param_count(cfg, init_fn=None) -> int:
+    """Parameters of ``init_fn(key, cfg)`` (this module's ``init`` by
+    default), from its shapes alone."""
+    shapes = jax.eval_shape(lambda key: (init_fn or init)(key, cfg),
+                            jax.random.key(0))
     return sum(a.size for a in jax.tree.leaves(shapes))
 
 
@@ -234,9 +259,45 @@ def short_conv_operator(u, p, cfg: Lfm2Config):
         return mixed @ p["out_proj"].astype(cfg.dtype)
 
 
+def l2_normalize(x, eps: float = 1e-6):
+    x32 = x.astype(jnp.float32)
+    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
+
+
+def delta_rule_operator(u, p, cfg):
+    """``u [b, T, d]`` -> ``[b, T, d]``: the gated delta rule over the
+    held heads between its projections (the module's docstring)."""
+    c = cfg
+    b, s, _ = u.shape
+    h, hd, dt = c.linear_num_heads, c.linear_head_dim, c.dtype
+    heads = lambda a: a.reshape(b, s, h, hd)
+    with jax.named_scope("kda.proj"):
+        q, k, v = (u @ p[f"{n}_proj"].astype(dt) for n in "qkv")
+    q, k, v = (heads(silu_short_conv(a, p[f"{n}_conv"], scope="kda.conv"))
+               for a, n in ((q, "q"), (k, "k"), (v, "v")))
+    with jax.named_scope("kda.proj"):
+        q = (l2_normalize(q) * hd ** -0.5).astype(dt)
+        k = l2_normalize(k).astype(dt)
+        a = u.astype(jnp.float32)
+        beta = jax.nn.sigmoid(jnp.dot(a, p["b_proj"], precision=HIGHEST))
+        if c.kda_allow_neg_eigval:
+            beta = 2.0 * beta
+        f = jnp.dot(jnp.dot(a, p["f_a_proj"], precision=HIGHEST),
+                    p["f_b_proj"], precision=HIGHEST)
+        g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
+            heads(f + p["dt_bias"]))
+    o = delta_rule(q, k, v, g, beta)
+    with jax.named_scope("kda.proj"):
+        gate = jax.nn.sigmoid(((u @ p["g_a_proj"].astype(dt))
+                               @ p["g_b_proj"].astype(dt)).astype(jnp.float32)
+                              + p["g_b_bias"])
+        o = rms_norm(o, p["o_norm"], c.rms_norm_eps) * heads(gate)
+        return o.reshape(b, s, h * hd).astype(dt) @ p["o_proj"].astype(dt)
+
+
 def expert_ffn(u, lp, cfg: Lfm2Config):
-    """``u [b, T, d]`` -> (this share's routed part, pairs per held
-    expert ``[count]``)."""
+    """``u [b, T, d]`` -> (this share's routed part, plus the shared
+    expert where the layer has one; pairs per held expert ``[count]``)."""
     c = cfg
     b, s, d = u.shape
     ex = lp["experts"]
@@ -247,7 +308,10 @@ def expert_ffn(u, lp, cfg: Lfm2Config):
         capacity_factor=c.capacity_factor, norm_topk_prob=c.norm_topk_prob,
         scoring="sigmoid", router_bias=lp.get("expert_bias"),
         routed_scaling_factor=c.routed_scaling_factor)
-    return routed.reshape(b, s, d), loads
+    routed = routed.reshape(b, s, d)
+    if "shared" in lp:
+        routed = swiglu(u, lp["shared"], c.dtype, "moe.shared") + routed
+    return routed, loads
 
 
 def decoder_layer(x, lp, cfg: Lfm2Config, positions, kind: str, dense: bool):
@@ -257,6 +321,8 @@ def decoder_layer(x, lp, cfg: Lfm2Config, positions, kind: str, dense: bool):
     u = rms_norm(x, lp["operator_norm"], c.norm_eps)
     if kind == "conv":
         x = x + short_conv_operator(u, lp["conv"], c)
+    elif kind == "linear_attention":
+        x = x + delta_rule_operator(u, lp["linear_attn"], c)
     else:
         x = x + gqa_attention(u, lp["self_attn"], c, positions, "causal",
                               scope="attn.gqa", proj_scope="attn.gqa_proj")
@@ -287,10 +353,13 @@ def hidden_states(params, tokens, cfg: Lfm2Config):
 
 
 def logits_of(params, x, cfg: Lfm2Config):
-    """The final norm and the tied head: float32 logits."""
+    """The final norm and the head (``lm_head`` where there is one, else
+    the embedding's transpose): float32 logits."""
     with jax.named_scope("loss.head"):
         x = rms_norm(x, params["embedding_norm"], cfg.norm_eps)
-        return jnp.dot(x, params["embed_tokens"].T.astype(cfg.dtype),
+        head = (params["lm_head"] if "lm_head" in params
+                else params["embed_tokens"].T)
+        return jnp.dot(x, head.astype(cfg.dtype),
                        preferred_element_type=jnp.float32)
 
 

@@ -163,6 +163,17 @@ def rotary(x, positions, theta: float, freq=None):
                            axis=-1).astype(x.dtype)
 
 
+def prepare_qk(a, gain, cfg, positions):
+    """q or k ``[b, s, heads, head_dim]`` through the per-head RMSNorm
+    (where ``gain`` is not None), then rotary (unless ``cfg.use_rope`` is
+    False)."""
+    if gain is not None:
+        a = rms_norm(a, gain, cfg.rms_norm_eps)
+    if getattr(cfg, "use_rope", True):
+        a = rotary(a, positions, cfg.rope_theta)
+    return a
+
+
 def gqa_attention(x, lp, cfg, positions, mask, block=None, half=None, *,
                   scope=None, proj_scope=None):
     """Grouped-query attention over ``x [b, s, hidden]`` under ``mask``
@@ -170,10 +181,15 @@ def gqa_attention(x, lp, cfg, positions, mask, block=None, half=None, *,
     ``half``). ``cfg`` is this family's, or another's with the same
     fields (``models/lfm2.py``: ``num_attention_heads``,
     ``num_key_value_heads``, ``head_dim``, ``rms_norm_eps``,
-    ``rope_theta``, ``attention``, ``dtype``). ``scope`` names the
-    attention itself (``attn.bd`` under the block-diffusion mask, else
-    ``attn``); ``proj_scope`` the four projections with the q/k norm and
-    the rotary embedding, which carry no scope without it."""
+    ``rope_theta``, ``attention``, ``dtype``; ``use_rope``, where a
+    configuration has it, False: no rotary embedding). The leaves say
+    the rest: q and k take a per-head RMSNorm where ``lp`` has ``q_norm``
+    and ``k_norm``, and the heads' output an elementwise gate
+    ``sigmoid(x W_g)`` (float32) before ``W_o`` where it has ``g_proj``.
+    ``scope`` names the attention itself (``attn.bd`` under the
+    block-diffusion mask, else ``attn``); ``proj_scope`` the projections
+    with the q/k norm, the rotary embedding and the gate, which carry no
+    scope without it."""
     from pytorch_ps_mpi_tpu.ops import attention_pallas as ap
 
     c = cfg
@@ -188,10 +204,8 @@ def gqa_attention(x, lp, cfg, positions, mask, block=None, half=None, *,
             b, s, c.num_key_value_heads, c.head_dim)
         v = (x @ lp["v_proj"].astype(dt)).reshape(
             b, s, c.num_key_value_heads, c.head_dim)
-        q = rotary(rms_norm(q, lp["q_norm"], c.rms_norm_eps), positions,
-                   c.rope_theta)
-        k = rotary(rms_norm(k, lp["k_norm"], c.rms_norm_eps), positions,
-                   c.rope_theta)
+        q, k = (prepare_qk(a, lp.get(norm), c, positions)
+                for a, norm in ((q, "q_norm"), (k, "k_norm")))
     if c.attention not in ("full", "flash", "einsum"):
         raise ValueError(f"unknown attention={c.attention!r}")
     # as models/bert.py: 'flash' is always the kernel, 'full' takes it
@@ -208,7 +222,12 @@ def gqa_attention(x, lp, cfg, positions, mask, block=None, half=None, *,
                 q, k, v, 0, 0, ap._mask_spec(False, mask, block, half),
                 c.head_dim ** -0.5)
     with projections():
-        return out.reshape(b, s, -1) @ lp["o_proj"].astype(dt)
+        out = out.reshape(b, s, -1)
+        if "g_proj" in lp:
+            gate = jax.nn.sigmoid((x @ lp["g_proj"].astype(dt)).astype(
+                jnp.float32))
+            out = (out.astype(jnp.float32) * gate).astype(dt)
+        return out @ lp["o_proj"].astype(dt)
 
 
 def decoder_layer(x, lp, cfg: SdarMoeConfig, positions, mask, block, half):
